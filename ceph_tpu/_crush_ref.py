@@ -1,6 +1,6 @@
 """ctypes bindings to the REFERENCE CRUSH C (libcrush_ref.so).
 
-The shared library is built by csrc/Makefile from the reference's own
+The shared library is built by `make -C csrc ref` from the reference's own
 kernel-frozen sources (/root/reference/src/crush/{mapper,hash,crush,
 builder}.c, compiled in place) behind csrc/crush_ref_shim.c.  It is the
 ground truth the jit mapper and the re-derived C++ oracle are pinned
@@ -33,7 +33,7 @@ def _build() -> None:
     import subprocess
 
     csrc = os.path.join(os.path.dirname(__file__), os.pardir, "csrc")
-    proc = subprocess.run(["make", "-C", csrc, "-s"],
+    proc = subprocess.run(["make", "-C", csrc, "-s", "ref"],
                           capture_output=True, text=True)
     if proc.returncode != 0:
         # surface the compiler diagnostics as the OSError available()

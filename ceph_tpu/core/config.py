@@ -242,13 +242,6 @@ def _opts() -> List[Option]:
           "WARN; much tighter than the total-signature threshold "
           "because a declared cold ladder never counts here — "
           "undeclared shape churn is a bug regardless of volume"),
-        O("tpu_compile_cache_dir", str, "",
-          "persistent on-disk XLA compilation cache directory "
-          "(jax_compilation_cache_dir): a restarted/failed-over "
-          "daemon re-reads compiled executables instead of re-paying "
-          "the compile wall (osd.N.xla cache_persist_hits counts the "
-          "cross-process hits); empty disables (vstart defaults it "
-          "under the cluster run dir)", runtime=False),
         O("tpu_warmup_budget_s", float, 30.0,
           "wall-clock budget for the boot-time DeviceWarmup pass "
           "that compiles every registered kernel family against its "

@@ -395,10 +395,10 @@ class ClayCodec(ErasureCode):
             rows = self.full_generator[np.asarray(unknown)]
             M = gf.matmul(rows, R)
             self._solve_cache[key] = M
-        return np.asarray(
-            gf256_swar.gf_matmul_bytes(M, U_known[: self.kk],
-                                       family="gf256_clay")
-        )
+        # M depends on the erasure signature: passed as data, so one
+        # program per width serves every signature
+        return gf256_swar.gf_matmul_bytes(
+            M, U_known[: self.kk], family="gf256_clay", operand=True)
 
     # -- general decode (multi-erasure, layered IS ordering) ---------------
     def decode_array(

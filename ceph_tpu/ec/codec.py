@@ -128,7 +128,8 @@ class RSMatrixCodec(ErasureCode):
             stacked = np.stack(
                 [np.asarray(available[i], dtype=np.uint8) for i in survivors]
             )
-            data = np.asarray(gf256_swar.gf_matmul_bytes(rec, stacked))
+            # per-signature matrix: passed as data, not compiled in
+            data = gf256_swar.gf_matmul_bytes(rec, stacked, operand=True)
         for i in want_data:
             out[i] = available[i] if i in available else data[i]
         if want_coding:

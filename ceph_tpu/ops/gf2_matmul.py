@@ -34,11 +34,6 @@ from jax.experimental import pallas as pl
 from ceph_tpu.tpu.devwatch import (instrumented_jit,
                                    instrumented_pallas_call)
 
-try:  # pallas TPU backend (absent on CPU-only test runs)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
 
 # ---------------------------------------------------------------------------
 # jnp reference path
@@ -131,17 +126,11 @@ def gf2_matmul_bytes_pallas(
     )(mbits, x)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:  # pragma: no cover
-        return False
-
-
 def gf2_matmul_bytes(mbits: jax.Array, x: jax.Array, *, tile_n: int = 2048):
-    """Dispatch: fused Pallas kernel on TPU, XLA reference elsewhere."""
+    """Dispatch by backend and shape: the fused Pallas kernel on a TPU
+    when n is a whole number of tiles, the XLA graph otherwise."""
     n = x.shape[1]
-    if _on_tpu() and pltpu is not None and n % tile_n == 0:
+    if jax.default_backend() == "tpu" and n % tile_n == 0:
         return gf2_matmul_bytes_pallas(mbits, x, tile_n=tile_n)
     return _ref_jit(mbits, x)
 

@@ -184,6 +184,9 @@ class OSDService(Dispatcher):
                            "peer heartbeat grace overruns observed")
         pc.add_u64_counter("marked_down_while_alive",
                            "osdmaps that marked this live daemon down")
+        pc.add_u64_counter("heartbeat_compile_holds",
+                           "grace overruns not reported because a "
+                           "compile ran in this process meanwhile")
         self.perf = pc
         # pipelined-write-engine counters (registered once, like the
         # osd.N.store set): shared by every PG of this daemon
@@ -329,11 +332,11 @@ class OSDService(Dispatcher):
         # persistent on-disk XLA compile cache (shape-bucket ABI): a
         # restarted daemon re-reads compiled executables instead of
         # re-paying the compile wall; process-wide and idempotent like
-        # the watcher itself (empty conf disables)
+        # the watcher itself.  Placed from outside: where
+        # JAX_COMPILATION_CACHE_DIR says, else <repo>/.jax_cache
         from ceph_tpu.tpu import shapebucket as _sb
 
-        _sb.setup_compile_cache(
-            str(ctx.conf.get("tpu_compile_cache_dir") or ""))
+        _sb.setup_compile_cache()
         # boot-time warmup pass (built lazily: the codec and crush
         # items resolve against the osdmap, which arrives with boot)
         self._warmup = None
@@ -628,11 +631,19 @@ class OSDService(Dispatcher):
             # non-leaders, and a live osd spuriously marked down must
             # re-assert itself — so keep watching the map and re-boot
             # whenever it shows us down (reference OSD::start_boot +
-            # the "wrongly marked me down" path of handle_osd_map)
+            # the "wrongly marked me down" path of handle_osd_map) —
+            # or up at ANOTHER address: a restarted cluster's durable
+            # mon still holds the previous incarnation as up, and when
+            # that map reached us before this loop's first pass we
+            # never announced ourselves; every op to our PGs then sat
+            # out its 30 s timeout (the load flake of
+            # test_vstart_blockstore_backed_cluster)
             last_stats = 0.0
             while self.up:
                 m_ = self.osdmap
-                if m_ is None or not m_.is_up(self.whoami):
+                if (m_ is None or not m_.is_up(self.whoami)
+                        or tuple(m_.osd_addrs.get(self.whoami, ()))
+                        != tuple(self.msgr.addr)):
                     self.monc.send_boot(self.whoami,
                                         hb_addr=self.hb_msgr.addr)
                 self._maybe_renew_ticket()
@@ -1181,9 +1192,19 @@ class OSDService(Dispatcher):
         self._notify_cbs.pop(notify_id, None)
 
     def ms_handle_reset(self, conn) -> None:
-        # a watcher's session died: its watches die with it
+        # a watcher's session died: its watches die with it.  The
+        # prune takes the pg lock, and this runs ON the messenger
+        # loop: a write may hold that lock across a sync remote read
+        # (_read_state_sync) whose reply only this loop can dispatch
+        # — the op then sat out its 30 s timeout (PR 22 thread dump).
+        # So the prune rides each PG's own work-queue shard, behind
+        # that PG's ops, and the loop never waits for a pg lock.
         for pg in list(self.pgs.values()):
-            pg.prune_watchers(conn)
+            try:
+                self.wq.queue(
+                    pg.pgid, lambda pg=pg: pg.prune_watchers(conn))
+            except RuntimeError:
+                return  # work queue stopped: the daemon is going down
 
     def new_tid(self) -> int:
         with self._tid_lock:
@@ -1615,6 +1636,15 @@ class OSDService(Dispatcher):
                 fuse = (grace if osd_id in self.hb_replied
                         else 3 * grace) * stretch
                 if now - last > fuse:
+                    if self._devwatch.compile_activity_since(
+                            time.monotonic() - fuse):
+                        # a compile ran in THIS process inside the
+                        # window: our own handling of ping replies was
+                        # starved, so the silence says nothing about
+                        # the peer.  Judge it a full fuse after the
+                        # compile ends.
+                        self.perf.inc("heartbeat_compile_holds")
+                        continue
                     self.perf.inc("heartbeat_misses")
                     if self.on_failure_report:
                         self._log(1, f"heartbeat: osd.{osd_id} silent "

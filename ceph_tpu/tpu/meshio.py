@@ -37,25 +37,12 @@ from ceph_tpu.tpu.devwatch import instrumented_jit
 
 
 def _shard_map():
+    import functools
+
     import jax
 
-    try:
-        from jax import shard_map
-
-        sm = jax.shard_map if hasattr(jax, "shard_map") else shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as sm
-
-    import functools
-    import inspect
-
-    params = inspect.signature(sm).parameters
     # replication of all_gather results can't be statically inferred
-    if "check_vma" in params:  # jax >= 0.7 renamed check_rep
-        return functools.partial(sm, check_vma=False)
-    if "check_rep" in params:
-        return functools.partial(sm, check_rep=False)
-    return sm
+    return functools.partial(jax.shard_map, check_vma=False)
 
 
 class MeshCompute:

@@ -318,10 +318,13 @@ class StripeBatchQueue:
                     np.asarray(rec, dtype=np.uint8), stacked)
             from ceph_tpu.ops import gf256_swar
 
-            # the stacked buffer is freshly built per batch: donate it
+            # the recovery matrix rides as an OPERAND: one program per
+            # width serves every survivor signature (a baked matrix is
+            # a compile per signature, in line on this one worker).
+            # The planes are freshly uploaded per batch: donate them
             # so live HBM stays ~one batch deep through the pipeline
-            return np.asarray(gf256_swar.gf_matmul_bytes(
-                rec, stacked, donate=True))
+            return gf256_swar.gf_matmul_bytes(
+                rec, stacked, donate=True, operand=True)
         coding_mat = getattr(codec, "coding", None)
         if self.mesh is not None and coding_mat is not None:
             self.mesh_batches += 1

@@ -20,7 +20,8 @@ so that
   by ``tpu_warmup_budget_s`` and resumable on demand
   (``ceph daemon osd.N device warmup``), and
 - a persistent on-disk XLA compilation cache
-  (:func:`setup_compile_cache`, conf ``tpu_compile_cache_dir``) makes
+  (:func:`setup_compile_cache`; ``JAX_COMPILATION_CACHE_DIR`` or a
+  fixed ``<repo>/.jax_cache``) makes
   a SECOND process pay ~zero compile wall for any family a previous
   process warmed — restart/failover/backfill never re-pay the wall.
 
@@ -50,6 +51,7 @@ batch dispatch goes through :func:`covering`.
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -215,49 +217,67 @@ def _on_jax_event(event: str, **kw) -> None:  # pragma: no cover - thin
         devwatch.watch().note_persist(hit=False)
 
 
-def setup_compile_cache(path: str) -> bool:
-    """Point jax's persistent compilation cache at ``path`` (conf
-    ``tpu_compile_cache_dir``; empty string disables) and install the
+# the one fixed place the cache lives when nobody says otherwise: a
+# path inside the checkout (listed in .gitignore).  The directory is
+# part of the cache key, so a path that moves (a temporary data dir)
+# never hits.
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def resolve_compile_cache_dir(environ=os.environ) -> Tuple[str, bool]:
+    """The one resolver: ``(directory, set_in_code)``.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own reading of it
+    stands and no code sets a directory; else ``<repo>/.jax_cache``."""
+    env = environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env, False
+    return _REPO_CACHE_DIR, True
+
+
+def setup_compile_cache(path: Optional[str] = None) -> bool:
+    """Turn on jax's persistent compilation cache and install the
     monitoring listener that splits on-disk cache hits
     (``cache_persist_hits`` — a compile this process never paid
     because a PREVIOUS process did) from in-process trace-cache hits.
+
+    With no argument (OSD daemons, chip_smoke.py) the directory comes
+    from :func:`resolve_compile_cache_dir`.  An explicit ``path`` is
+    for tests (a ``tmp_path``); the empty string disables.
     Idempotent; returns True when the cache is live.  Thresholds are
     zeroed so every kernel persists — this repo's kernels are small
     and the wall they save is the whole point."""
     global _cache_dir, _listener_installed
+    import jax
+
+    set_in_code = True
+    if path is None:
+        path, set_in_code = resolve_compile_cache_dir()
     if not path:
         return False
     with _cache_lock:
         if _cache_dir == path:
             return True
-        try:
-            import jax
-
+        if set_in_code:
             jax.config.update("jax_compilation_cache_dir", str(path))
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            # jax initializes its cache object AT MOST ONCE, on the
-            # first compile: any import-time jit before this call
-            # would freeze the cache in its disabled (no-dir) state
-            # and the config updates above would never take.  Reset
-            # so the next compile re-initializes against `path`.
-            from jax._src import compilation_cache as _cc
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes", -1)
+        # jax initializes its cache object AT MOST ONCE, on the first
+        # compile: any import-time jit before this call would freeze
+        # the cache in its disabled (no-dir) state and the config
+        # updates above would never take.  Reset so the next compile
+        # re-initializes against `path`.
+        from jax.experimental.compilation_cache import compilation_cache
 
-            _cc.reset_cache()
-        except Exception:  # pragma: no cover — jax absent / too old
-            return False
+        compilation_cache.reset_cache()
         if not _listener_installed:
-            try:
-                from jax._src import monitoring
+            from jax import monitoring
 
-                monitoring.register_event_listener(_on_jax_event)
-                _listener_installed = True
-            # cephlint: disable=silent-except — jax monitoring API
-            # drift: the cache still works, only the split counter dies
-            except Exception:  # pragma: no cover
-                pass
+            monitoring.register_event_listener(_on_jax_event)
+            _listener_installed = True
         _cache_dir = path
         return True
 
@@ -277,6 +297,12 @@ def compile_cache_dir() -> Optional[str]:
 # 32768 is load-bearing: a 64KiB object at k=2 chunks to exactly that
 # width, and the bench's armed steady guard caught it missing.
 WARM_COLS = (4096, 16384, 32768, 65536)
+# the flat decode matmul takes its recovery matrix as an operand (one
+# program per width serves every survivor signature), so its whole
+# compile surface is finite and small: every width the queue can
+# dispatch, 512 B (the narrowest Pallas row) up to its 1 Mi-column
+# batch ceiling.  Degraded reads after an OSD loss then compile nothing.
+WARM_DECODE_COLS = tuple(1 << j for j in range(9, 21))
 # crc row-batch geometry: J coalesced jobs (pow2) x C padded columns.
 # The row count the kernel sees is pow2(J) x (k+m); depth-16 client
 # concurrency coalesces up to 8 jobs per batch in practice, so warm
@@ -342,7 +368,7 @@ class DeviceWarmup:
                 items.append(_WarmItem(
                     "gf256", f"encode cols~{c}",
                     lambda c=c: self._warm_encode(c)))
-            for c in self._cols:
+            for c in sorted(set(self._cols) | set(WARM_DECODE_COLS)):
                 items.append(_WarmItem(
                     "gf256", f"decode cols~{c}",
                     lambda c=c: self._warm_decode(c)))
@@ -390,6 +416,8 @@ class DeviceWarmup:
             return False
         get_subs = getattr(codec, "get_sub_chunk_count", None)
         gran = max(1, int(get_subs())) if get_subs is not None else 1
+        if gran > 1 and cols not in self._cols:
+            return True  # the full ladder is the flat decode's
         if gran > 1 and hasattr(codec, "repair_planes"):
             # array codec (clay): warm the batched single-erasure
             # repair AND the general decode at the queue's covering
@@ -409,19 +437,19 @@ class DeviceWarmup:
         if gran > 1 or getattr(codec, "recovery_matrix", None) is None:
             return True  # no flat decode matmul to warm
         n = codec.k + codec.m
-        # one representative survivor signature: first m shards
-        # erased (the most common failure pattern); other signatures
-        # share the matrix-digest machinery and column buckets
+        # any survivor signature warms them all: the matrix is an
+        # operand of the program, not part of it
         sig = list(range(codec.m, n))[: codec.k]
         rec, _bits = codec.recovery_matrix(sig)
         from ceph_tpu.ops import gf256_swar
 
-        # donate=True matches the queue's decode dispatch — donation
-        # is a compile-time property, so a non-donating warm would
-        # leave the real path cold
+        # donate=True / operand=True match the queue's decode
+        # dispatch — both are compile-time properties, so a warm
+        # without them would leave the real path cold
         gf256_swar.gf_matmul_bytes(
             np.asarray(rec, np.uint8),
-            np.zeros((codec.k, covering(cols)), np.uint8), donate=True)
+            np.zeros((codec.k, covering(cols)), np.uint8),
+            donate=True, operand=True)
         return True
 
     def _warm_crush(self) -> bool:

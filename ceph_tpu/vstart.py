@@ -69,12 +69,6 @@ class VStartCluster:
         }
         if warmup:
             merged.setdefault("tpu_boot_warmup", True)
-        # durable clusters persist XLA binaries next to the object data:
-        # a SECOND process over the same dir pays ~zero compile wall
-        # (cache_persist_hits on osd.N.xla proves it)
-        if data_dir is not None:
-            merged.setdefault("tpu_compile_cache_dir",
-                              os.path.join(data_dir, "xla_cache"))
         self.ctx = Context("vstart", merged)
         self.keyring = None
         if keyring:

@@ -1,8 +1,10 @@
 """Test harness: 8-device virtual CPU mesh + x64, native lib autobuild.
 
-Tests always run on CPU (fast, deterministic, and multi-device via
-xla_force_host_platform_device_count) regardless of any attached TPU;
-bench.py is the TPU entry point.
+Tests always run on the CPU backend (fast, deterministic, and
+multi-device via xla_force_host_platform_device_count).  No product
+entry point enables x64; the chip entry point is chip_smoke.py, and
+tests/test_chip_compile.py compiles the main path's kernels for a
+described v5e with x64 off.
 """
 
 import os
@@ -16,6 +18,10 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
 import jax
 
 jax.config.update("jax_enable_x64", True)
+# The persistent compilation cache stays ON, as in the product (every
+# OSD's init calls shapebucket.setup_compile_cache(): <repo>/.jax_cache
+# unless JAX_COMPILATION_CACHE_DIR is set).  A test that counts
+# compiles pins it off for itself.
 
 from ceph_tpu import _native
 

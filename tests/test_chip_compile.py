@@ -1,0 +1,198 @@
+"""The main path's kernels, compiled for a DESCRIBED TPU v5e at the
+widths the queue sends — no chip attached, nothing runs.
+
+Interpret mode (every other Pallas test here) checks neither Mosaic's
+block-shape rule nor the scoped-VMEM limit; the chip's own compiler,
+which is installed in this sandbox, does.  A compile that passes is
+not a chip run and says nothing about results or times — it guards
+against kernels the chip would refuse (the tile ladder of PR 22).
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described inside a module-scoped fixture, never while a module is
+imported; the fixture is not autouse and lives here, so only the
+xdist worker handed this file loads the TPU library; every compile
+happens in the test's own process; all such tests stay in THIS file.
+Shapes are steered here, not through an option of the program.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+
+import jax
+import jax.numpy as jnp
+
+from ceph_tpu.ec import matrices
+from ceph_tpu.ops import gf256_pallas, gf256_swar
+
+LANES = gf256_pallas.LANES
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a described-chip executable is written to the persistent cache
+    # but cannot be read back without a chip (the next run would warn
+    # and recompile): keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip(one_chip):
+    """The described chip's sharding, with the dtypes production has:
+    tests/conftest.py turns jax_enable_x64 on, no product entry point
+    does (and Mosaic refuses the i64 block indices x64 would trace)."""
+    with jax.enable_x64(False):
+        yield one_chip
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile_planes(one_chip, matrix, T, donate=False):
+    """Lower + compile the product's Pallas encode for (k, T, 128)
+    planes with the tile gf_matmul_bytes would pick."""
+    matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
+    tile, T_pad = gf256_swar.pallas_tile(T)
+    fn = gf256_pallas._compiled(matrix.tobytes(), matrix.shape, tile,
+                                False, False, donate)
+    compiled = fn.jitted.lower(
+        _spec(one_chip, (matrix.shape[1], T_pad, LANES), jnp.uint32),
+        _spec(one_chip, (1,), jnp.uint32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _k8m4():
+    return matrices.isa_cauchy(8, 4)
+
+
+def _k8_decode():
+    from ceph_tpu.ec.codec import RSMatrixCodec
+
+    rec, _ = RSMatrixCodec(8, 4, _k8m4()).recovery_matrix(
+        [0, 2, 3, 5, 6, 7, 8, 11])
+    return rec
+
+
+# T = 256: a 1 MiB object's 128 KiB chunk; T = 2048: a full
+# erasure_code_batch_cols batch (1 MiB columns)
+@pytest.mark.parametrize("T", [256, 2048])
+def test_encode_k8m4_at_queue_widths(chip, T):
+    _compile_planes(chip, _k8m4(), T)
+
+
+@pytest.mark.parametrize("T", [256, 2048])
+def test_square_decode_k8_donated_at_queue_widths(chip, T):
+    # a square matrix compiled in, input aliased to the output
+    _compile_planes(chip, _k8_decode(), T, donate=True)
+
+
+# T = 1: a 4 KiB object's 512 B chunk, the narrowest Pallas row
+@pytest.mark.parametrize("T", [1, 256, 2048])
+def test_operand_decode_k8_at_queue_widths(chip, T):
+    """What the queue's decode dispatches: the recovery matrix as an
+    SMEM operand (one program per width for every survivor
+    signature), the uploaded planes donated."""
+    tile, T_pad = gf256_swar.pallas_tile(T)
+    fn = gf256_pallas._compiled_operand(8, 8, tile, False, True)
+    compiled = fn.jitted.lower(
+        _spec(chip, (8 * 8 * 8,), jnp.uint32),
+        _spec(chip, (8, T_pad, LANES), jnp.uint32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_clay_pair_matrix(chip):
+    """clay's 1x2 pair transform over raveled node x layer subsets:
+    widths like 27 * 512 (T = 27, no legal power-of-two tile)."""
+    pair = np.array([[1, 2]], dtype=np.uint8)
+    for T in (27, 64):
+        _compile_planes(chip, pair, T)
+
+
+@pytest.mark.parametrize("n", [1536, 3072, 6144, 13824, 513 * 512])
+def test_tile_ladder_regression(chip, n):
+    """n % 512 == 0 with T = n/512 lacking a divisor that is a
+    multiple of 8: the old ladder picked tile 4 / 1 here and the
+    lowering raised ValueError on a TPU.  513*512 takes the pad arm."""
+    _compile_planes(chip, _k8m4(), n // 512)
+
+
+def test_tile_1024_is_refused(chip):
+    """The measured fact behind _PALLAS_MAX_TILE: the v5e's scoped
+    VMEM does not hold a k=8 tile of 1024 sublane rows.  If a later
+    libtpu accepts it, this fails and the cap can be re-derived."""
+    m = np.ascontiguousarray(_k8m4(), dtype=np.uint8)
+    fn = gf256_pallas._compiled(m.tobytes(), m.shape, 1024, False)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        fn.jitted.lower(_spec(chip, (8, 2048, LANES), jnp.uint32),
+                        _spec(chip, (1,), jnp.uint32)).compile()
+
+
+def test_gf2_bitmatrix_kernel_k8m4(chip):
+    """gf2_matmul_bytes_pallas at tile 2048: what shec and
+    BitmatrixCodec._apply (ec/codec.py) dispatch on a TPU."""
+    from ceph_tpu.ops import gf2_matmul
+
+    bits = gf2_matmul.prepare_bitmatrix(_k8m4())
+    compiled = gf2_matmul.gf2_matmul_bytes_pallas.jitted.lower(
+        _spec(chip, bits.shape, jnp.int8),
+        _spec(chip, (8, 131072), jnp.uint8), tile_n=2048).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_crc32c_rows_kernel_at_queue_shape(chip):
+    """The fused crc pass for 8 jobs x 12 shards of 128 KiB chunks."""
+    from ceph_tpu.ops import crc32c_device
+
+    R, C = 96, 131072
+    crc32c_device._rows_kernel(R, C).jitted.lower(
+        _spec(chip, (R, C), jnp.uint8),
+        _spec(chip, (R,), jnp.int32),
+        _spec(chip, (R,), jnp.uint32)).compile()
+
+
+def test_crush_sweep_chunk_program(chip):
+    """sweep_device's stage-1 chunk program (one-shot fast pass, 2^19
+    ids) for the 1024-OSD straw2 map (BASELINE config 6).  The whole
+    three-stage sweep takes over a minute to compile and is left to
+    the chip run (chip_smoke.py)."""
+    from ceph_tpu.crush import map as cmap
+    from ceph_tpu.crush import mapper
+
+    m, root = cmap.build_flat_cluster(1024, hosts=64)
+    steps = [(cmap.OP_TAKE, root, 0),
+             (cmap.OP_CHOOSELEAF_FIRSTN, 3, 1),
+             (cmap.OP_EMIT, 0, 0)]
+    fast = mapper.compile_rule(m.flatten(), steps, 3, None,
+                               one_shot=True)
+    compiled = jax.jit(fast).lower(
+        _spec(chip, (1 << 19,), jnp.int32),
+        _spec(chip, (1024,), jnp.uint32)).compile()
+    # one program's temps fit the chip (16 GB HBM) with room to spare
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30

@@ -521,3 +521,28 @@ def test_crush_churn_storm_and_pow2_padding_steady(dw):
     assert not GUARD_VIOLATIONS, GUARD_VIOLATIONS
     assert len(dw.dump()["storms"]) == storms_before  # zero new storms
     assert got.shape == (300, 2)
+
+
+def test_heartbeat_holds_its_verdict_while_this_process_compiles(dw):
+    """A compile in this process starves the process's OWN handling of
+    ping replies (PR 22, first chip run: every OSD of a 12-OSD
+    in-process cluster marked down during the post-pool warmup), so a
+    grace overrun seen while one is live — or ended less than a fuse
+    ago — is not reported; it is counted (heartbeat_compile_holds) and
+    judged once the compile is a full fuse in the past."""
+    from ceph_tpu.vstart import VStartCluster
+
+    with VStartCluster(n_mons=1, n_osds=3, conf={
+            "osd_heartbeat_interval": 0.2,
+            "osd_heartbeat_grace": 1.0}) as c:
+        tok = dw.compile_begin("crush_mapper")  # a compile is live
+        try:
+            c.kill_osd(2)
+            time.sleep(3.0)  # three fuses of silence
+            assert c.leader().osdmap.is_up(2), "judged during a compile"
+            assert sum(o.perf.value("heartbeat_compile_holds")
+                       for o in c.osds.values() if o.up) > 0
+        finally:
+            dw.compile_end(tok, ())
+        c.wait_for(lambda: not c.leader().osdmap.is_up(2), timeout=15.0,
+                   what="osd.2 marked down once the compile had ended")

@@ -275,6 +275,44 @@ def test_setup_compile_cache_idempotent(tmp_path):
     assert not shapebucket.setup_compile_cache("")  # empty disables
 
 
+def test_compile_cache_dir_resolver():
+    """Placed from outside: the variable where set, else the fixed
+    path inside the checkout (a path that moves never hits)."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert shapebucket.resolve_compile_cache_dir({}) == (
+        os.path.join(repo, ".jax_cache"), True)
+    assert shapebucket.resolve_compile_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": "/x/y"}) == ("/x/y", False)
+    assert shapebucket.resolve_compile_cache_dir(
+        {"JAX_COMPILATION_CACHE_DIR": ""})[1] is True
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_setup_compile_cache_sets_no_dir_when_env_is_set(
+        monkeypatch, tmp_path, from_env):
+    """Where JAX_COMPILATION_CACHE_DIR is set, jax's own reading of it
+    stands: no code sets another directory."""
+    import jax
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setattr(shapebucket, "_cache_dir", None)
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "env"))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert shapebucket.setup_compile_cache()
+    dirs = [v for k, v in updates if k == "jax_compilation_cache_dir"]
+    if from_env:
+        assert dirs == []
+        assert shapebucket.compile_cache_dir() == str(tmp_path / "env")
+    else:
+        assert dirs == [shapebucket.compile_cache_dir()]
+        assert dirs[0].endswith(".jax_cache")
+
+
 _CHILD = r"""
 import os, sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")

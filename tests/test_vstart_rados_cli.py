@@ -299,3 +299,26 @@ def test_osd_df_and_status_pg_states():
         c.wait_for(ready, what="osd df + pg states")
         code, out = c.command({"prefix": "osd df"})
         assert all(n["total_bytes"] > 0 for n in out["nodes"])
+
+
+def test_osd_reasserts_itself_when_the_map_holds_another_address():
+    """A map that shows this OSD up at an address that is not its own
+    (a restarted cluster's durable mon holds the previous incarnation)
+    must make it boot again: it used to look only at the up bit, and
+    when the stale map arrived before its first boot it never
+    announced itself — ops to its PGs then timed out after 30 s."""
+    from ceph_tpu.vstart import VStartCluster
+
+    with VStartCluster(n_mons=1, n_osds=2) as c:
+        mon = c.leader()
+        own = tuple(c.osds[0].msgr.addr)
+        with mon.lock:
+            mon._mutate_map(
+                lambda nm: nm.osd_addrs.__setitem__(0, ("127.0.0.1", 1)))
+        c.wait_for(
+            lambda: tuple(c.leader().osdmap.osd_addrs.get(0, ())) == own,
+            timeout=10.0, what="osd.0 registered its own address again")
+        pool = c.create_pool("re", size=2)
+        io_ = c.client().ioctx(pool)
+        io_.write_full("obj", b"x" * 100)
+        assert io_.read("obj") == b"x" * 100

@@ -4,8 +4,9 @@
 Polls daemon admin sockets for `perf dump` (the osd.N.op per-stage
 histograms + the osd.N.tpuq queue-stage set) and the per-daemon
 `osd.N dump_historic_slow_ops` rings, merges them, and renders where
-a write spends its time — the live answer to "where does the tunnel
-tax land per op" that PRs 2-7 could only estimate from benchmarks.
+a write spends its time — the live answer to "where does the device
+round trip land per op" that PRs 2-7 could only estimate from
+benchmarks.
 
     python tools/cephtop.py --socket /run/a.sock [--socket /run/b.sock]
     python tools/cephtop.py --socket /run/a.sock --slow   # slow-op rings
