@@ -21,6 +21,11 @@ Two contracts, both rooted in PR 8's observability layer:
    stage too — free-form detail annotations use f-strings/variables,
    which are exempt.
 
+3. **Recorder span names come from their registry too.**  The name
+   given to ``tracing.span(...)`` must be a string literal declared in
+   ``tracing.SPANS``, the table that says which per-layer metric reads
+   each span: a typo'd name is a span no reader ever sums.
+
 Never baselineable: the observability layer ships with this check, so
 there is no accepted debt — like the failpoint-name registry.
 """
@@ -51,29 +56,34 @@ class SpanDiscipline(Check):
     name = "span-discipline"
     description = ("start_span must reach finish() on all paths; "
                    "mark_event/_op_stage/literal-annotate names must "
-                   "be declared in tracing.STAGES")
+                   "be declared in tracing.STAGES, tracing.span names "
+                   "in tracing.SPANS")
     scopes = ("ceph_tpu", "tools")
 
     def run(self, files: Sequence[SourceFile]) -> List[Violation]:
-        from ceph_tpu.core.tracing import STAGES
+        from ceph_tpu.core.tracing import SPANS, STAGES
 
         out: List[Violation] = []
         for f in files:
             if any(f.rel.endswith(s) for s in _SELF):
                 continue
-            out.extend(self._check_stage_names(f, STAGES))
+            out.extend(self._check_stage_names(f, STAGES, SPANS))
             out.extend(self._check_span_finish(f))
         return out
 
     # -- stage-name registry ------------------------------------------------
-    def _check_stage_names(self, f: SourceFile,
-                           stages) -> List[Violation]:
+    def _check_stage_names(self, f: SourceFile, stages,
+                           spans) -> List[Violation]:
         out: List[Violation] = []
         for node in ast.walk(f.tree):
             if not isinstance(node, ast.Call):
                 continue
             base = call_name(node).rsplit(".", 1)[-1]
-            if base == "mark_event" and node.args:
+            table, registry = stages, "tracing.STAGES"
+            if call_name(node).endswith("tracing.span") and node.args:
+                base, arg = "tracing.span", node.args[0]
+                table, registry = spans, "tracing.SPANS"
+            elif base == "mark_event" and node.args:
                 arg = node.args[0]
             elif base == "_op_stage" and len(node.args) >= 2:
                 # PG._op_stage(msg, "<stage>", ...) — stage is arg 2
@@ -93,19 +103,19 @@ class SpanDiscipline(Check):
                     check=self.name, path=f.rel, line=node.lineno,
                     scope=enclosing_scope(f.tree, node.lineno),
                     detail=f"{base}(<dynamic>)",
-                    message=(f"{base}() stage name must be a string "
+                    message=(f"{base}() name must be a string "
                              "literal — a dynamic name evades the "
                              "registry and every grep"),
                 ))
                 continue
-            if arg.value not in stages:
+            if arg.value not in table:
                 out.append(Violation(
                     check=self.name, path=f.rel, line=node.lineno,
                     scope=enclosing_scope(f.tree, node.lineno),
                     detail=f"{base}({arg.value!r})",
-                    message=(f"stage name {arg.value!r} is not declared "
-                             "in tracing.STAGES — a typo'd stage is a "
-                             "dead timeline row"),
+                    message=(f"name {arg.value!r} is not declared in "
+                             f"{registry} — a typo'd name is a dead "
+                             "timeline row that nothing reads"),
                 ))
         return out
 

@@ -69,13 +69,15 @@ class Context:
         def _dump_trace(c):
             if "trace_id" in c:
                 return self.trace.dump(int(str(c["trace_id"]), 16))
-            return self.trace.recent(int(c.get("count", 100)))
+            from ceph_tpu.core.tracing import recorder
 
-        a.register("dump_tracing", _dump_trace,
-                   "archived trace spans (blkin role)")
+            return recorder().dump(int(c.get("count", 1000)))
+
         a.register("dump_trace", _dump_trace,
-                   "spans of one trace: dump_trace trace_id=<hex> "
-                   "(without trace_id: the ring tail)")
+                   "the process's one span recorder: the ring's tail "
+                   "(stage spans, op timelines, the overwritten count; "
+                   "count=<n>), or with trace_id=<hex> the blkin spans "
+                   "of one trace")
 
         def _device_dump(c):
             # process-wide like the StripeBatchQueue: one device

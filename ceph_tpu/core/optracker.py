@@ -30,6 +30,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from ceph_tpu.core import tracing
 from ceph_tpu.core.lockdep import make_lock
 from ceph_tpu.core.tracing import STAGES
 
@@ -71,12 +72,17 @@ def declare_op_hists(pc) -> None:
 
 class TrackedOp:
     __slots__ = ("tracker", "desc", "start", "events", "done_at",
-                 "trace_ctx", "_last", "concluded", "_mu")
+                 "trace_ctx", "_last", "concluded", "_mu", "id", "reqid")
 
     def __init__(self, tracker: "OpTracker", desc: str,
-                 start: Optional[float] = None) -> None:
+                 start: Optional[float] = None, reqid: str = "") -> None:
         self.tracker = tracker
         self.desc = desc
+        self.reqid = reqid
+        # the recorder's id of this op: a `queue.batch` span lists the
+        # ids of the ops whose jobs it carried, and the op's own record
+        # (filed by unregister) bears it
+        self.id = tracing.recorder().next_id()
         # start may be the messenger's receive stamp: the first stage
         # delta then covers frame decode + dispatch, not just tracking
         self.start = time.monotonic() if start is None else start
@@ -175,9 +181,9 @@ class OpTracker:
         self.slow_ops = 0
         self.ops_leaked = 0
 
-    def create_op(self, desc: str,
-                  start: Optional[float] = None) -> TrackedOp:
-        op = TrackedOp(self, desc, start=start)
+    def create_op(self, desc: str, start: Optional[float] = None,
+                  reqid: str = "") -> TrackedOp:
+        op = TrackedOp(self, desc, start=start, reqid=reqid)
         with self._lock:
             self._in_flight[id(op)] = op
             self.ops_tracked += 1
@@ -198,6 +204,9 @@ class OpTracker:
             if stage:
                 op._mark_locked(stage, detail)
             op.done_at = time.monotonic()
+        # the concluded timeline, on the recorder's clock (monotonic,
+        # as `start` and `done_at` are): the one record of this layer
+        tracing.recorder().op(op)
         if self.perf is not None:
             self.perf.hinc("lat_op_us", (op.done_at - op.start) * 1e6)
         with self._lock:

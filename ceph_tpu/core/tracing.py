@@ -1,32 +1,46 @@
-"""Distributed trace spans (the blkin/Zipkin + LTTng tracepoint role).
+"""The one span recorder, and the blkin spans that archive into it.
 
-Reference: src/blkin/ (Zipkin-style trace/span/parent ids propagated
-with requests, annotations at interesting points) and the LTTng-UST
-tracepoints compiled into the daemons (src/tracing/*.tp).  Here:
+**The recorder** (`recorder()`, process-wide like `devwatch.watch()`,
+always on, no option) keeps one bounded in-memory ring of records on
+`time.monotonic_ns()`, the clock `OpTracker` and the stripe batch queue
+already use:
 
-- `Tracer.start_span(name, parent=...)` opens a span; `span.annotate()`
-  adds timestamped events; `span.finish()` archives it in a bounded
-  ring.
-- Wire propagation is by VALUE, not by magic: `span.context()` returns
-  (trace_id, span_id) to embed in a message (the client library puts it
-  in the op reqid; any carrier works), and the receiving daemon opens
-  its span with `parent=that_context` — the cross-daemon parent/child
-  chain of blkin.
-- `Tracer.dump(trace_id)` returns the archived spans of one trace,
-  `Tracer.recent()` the ring tail — the admin-socket surface.
+- `with tracing.span(name, **counts):` records name, start, end,
+  thread, the span open on this thread when it started (its parent),
+  the ids of what caused it where that is not the parent (`causes=`: a
+  batch lists its jobs' tracked-op ids), and integer counts or short
+  tags.  The same `with` opens a `jax.profiler.TraceAnnotation(name)`,
+  inert unless a profiler trace is being taken, so in a traced run the
+  span also lies on the xplane's host plane beside the device ops.  The
+  xplane counts from its session's start at the rate of this clock:
+  one matched pair (a ring span and its annotation) is the anchor
+  between the two.  Names come from `SPANS` below.
+- `OpTracker.unregister` files every concluded op's timeline as one
+  `OP_RECORD`, so the stage times the `lat_*_us` histograms sum can be
+  taken over a window.
+- The ring counts what it overwrote; `batch_window` (what the
+  benchmark's readers call) gives nothing for a range that reaches
+  back to where records were lost.
+- `dump_trace` on the admin socket writes the ring out.
 
-Tracepoint analog: `Tracer.event(subsys, name, **kw)` records a flat
-timestamped event in the same ring when tracing is enabled — the
-compiled-in, off-by-default tracepoint shape.
+**blkin spans** (reference: src/blkin/, Zipkin-style trace/span/parent
+ids propagated with requests) stay behind the `tracing` option:
+`Tracer.start_span(name, parent=...)` opens one, `span.annotate()` adds
+timestamped events, `span.finish()` files it in the same ring.  Wire
+propagation is by VALUE: `span.context()` returns (trace_id, span_id)
+to embed in a message, and the receiving daemon opens its span with
+`parent=that_context`.  `Tracer.dump(trace_id)` returns one trace's
+spans, `Tracer.recent()` this tracer's tail of the ring.
 """
 
 from __future__ import annotations
 
-import collections
-import random
+import itertools
+import os
 import threading
 import time
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import (Any, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 TraceContext = Tuple[int, int]  # (trace_id, span_id)
 
@@ -93,6 +107,291 @@ STAGES: Dict[str, str] = {
 }
 
 
+# -- span-name registry --------------------------------------------------------
+#
+# Every name a `tracing.span(...)` site opens, with the stem of the
+# per-layer metric of BENCHMARK.json that reads it ('' = none yet: the
+# span is in the ring and on the profiler's host plane only).  Like
+# STAGES, the name is the contract between the site and its readers;
+# cephlint's `span-discipline` check holds literal call-site names to
+# this table.  A metric is the SELF time of its spans (duration minus
+# what direct children cover), summed over a window's batches.
+SPANS: Dict[str, str] = {
+    # stripe batch queue: the worker thread's whole cycle
+    "queue.idle": "worker_idle_ms",       # blocked in get(), nothing queued
+    "queue.coalesce": "worker_idle_ms",   # first job taken -> batch closed
+    "queue.batch": "batch_self_ms",       # one dispatch; counts seq, kind,
+    #   jobs, cols, padded, bytes (and q, the queue's id, as idle and
+    #   coalesce); causes = the jobs' tracked-op ids; self time =
+    #   counters, note_batch, compile blame
+    "batch.stack": "batch_stack_ms",      # jobs copied into one padded array
+    "batch.encode": "batch_self_ms",      # the matmul call (encode or decode)
+    "batch.crc_layout": "batch_crc_layout_ms",  # concat + crc row relayout
+    "batch.crc": "batch_self_ms",         # the fused crc32c call
+    "batch.fanout": "batch_self_ms",      # results handed to the futures
+    # kernels, host side (children of whatever stage called them)
+    "dev.dispatch": "dev_dispatch_ms",    # instrumented_jit: upload of numpy
+    #   operands and enqueue; count: family
+    "dev.wait": "dev_wait_ms",            # devwatch.fetch: blocked until the
+    #   device is done, fetch included
+    # CRUSH sweep
+    "crush.sweep": "",                    # sweep_device's call, ids counted
+}
+
+# a concluded op's timeline, filed by OpTracker.unregister: read by
+# op_pre_encode_ms, op_commit_wait_ms and op_reply_ms
+OP_RECORD = "op"
+
+# -- the recorder --------------------------------------------------------------
+
+clock = time.monotonic_ns
+
+# a record: one tuple, indexed by these (SEQ: its place in the order
+# in which records were filed, i.e. closed)
+ID, NAME, T0, T1, THREAD, PARENT, CAUSES, COUNTS, SEQ = range(9)
+
+# Ring size.  Today's write cell makes 11 batches a second of 12 spans
+# (idle, coalesce, batch, five stages, two dispatches, two waits) and
+# 13 op records: 7,400 records in a 51 s window, and 640 op records in
+# the 13 s check after it.  Four times that is 32,200.
+RING = 1 << 16
+
+_annotation = None
+
+
+def _trace_annotation():
+    """jax's TraceAnnotation, imported at the first span: `core/` does
+    not import jax at import time, and every span site runs beside jax."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+class _Thread:
+    """Per-thread state: its id and the ids of the spans open on it."""
+    __slots__ = ("tid", "open")
+
+    def __init__(self) -> None:
+        self.tid = threading.get_ident()
+        self.open: List[int] = []
+
+
+class _OpenSpan:
+    """`with tracing.span("batch.stack", cols=n):` files one record in
+    the process's recorder when the block ends, however it ends; see
+    the module's docstring.  `name` is a literal from SPANS; `counts`
+    may be added to until the block ends (`sp.counts`)."""
+    __slots__ = ("rec", "name", "counts", "causes", "id", "t0", "t1",
+                 "_ann", "_th")
+
+    def __init__(self, name: str, causes: Tuple = (), **counts) -> None:
+        self.rec = _recorder
+        self.name = name
+        self.causes = causes
+        self.counts = counts
+
+    def __enter__(self) -> "_OpenSpan":
+        rec = self.rec
+        try:
+            th = rec._tls.th
+        except AttributeError:
+            th = rec._tls.th = _Thread()
+        self._th = th
+        self.id = i = next(rec._ids)
+        th.open.append(i)
+        # an annotation opened while no trace is taken is dropped by
+        # the profiler anyway: make one only under a trace (the check
+        # costs nothing, the object a third of a microsecond)
+        ann = _annotation or _trace_annotation()
+        if ann.is_enabled():
+            self._ann = ann = ann(self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = t1 = clock()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        th = self._th
+        opened = th.open
+        opened.pop()
+        rec = self.rec
+        seq = next(rec._seqs)   # Recorder._file, in line: the hot path
+        rec._ring[seq % rec.capacity] = (
+            self.id, self.name, self.t0, t1, th.tid,
+            opened[-1] if opened else 0, self.causes, self.counts, seq)
+
+    @property
+    def seconds(self) -> float:
+        return (self.t1 - self.t0) / 1e9
+
+
+span = _OpenSpan
+
+
+class Recorder:
+    def __init__(self, capacity: int = RING) -> None:
+        self.capacity = capacity
+        self._ring: List[Optional[Tuple]] = [None] * capacity
+        # filing takes no lock: `next` of a counter is one step of the
+        # interpreter, it hands every record a slot of its own, and a
+        # slot is written with one store
+        self._seqs = itertools.count()
+        self._ids = itertools.count(1)
+        self._tls = threading.local()
+        # upper half of the ids that cross the wire (blkin): set once,
+        # so that two processes' counters do not collide
+        self._salt = (int.from_bytes(os.urandom(4), "little") | 1) << 32
+        # one reading of both clocks, for a dump's reader who wants dates
+        self.anchor = (clock(), time.time_ns())
+
+    def next_id(self) -> int:
+        return next(self._ids)
+
+    def wire_id(self) -> int:
+        """A nonzero 63-bit id, unique across processes as far as 31
+        random bits make it."""
+        return (self._salt | next(self._ids)) & ((1 << 63) - 1)
+
+    def span(self, name: str, causes: Tuple = (), **counts) -> _OpenSpan:
+        """`tracing.span` into this recorder (the tests' own rings)."""
+        sp = _OpenSpan(name, causes, **counts)
+        sp.rec = self
+        return sp
+
+    def op(self, op) -> None:
+        """A concluded TrackedOp's timeline as one record; its events
+        are (seconds since the op's start, stage, detail)."""
+        self._file(op.id, OP_RECORD, int(op.start * 1e9),
+                   int(op.done_at * 1e9), threading.get_ident(), 0, (),
+                   {"desc": op.desc, "reqid": op.reqid,
+                    "events": tuple(op.events)})
+
+    def _file(self, id_: int, name: str, t0: int, t1: int, thread: int,
+              parent: int, causes: Tuple, counts: Dict[str, Any]) -> None:
+        seq = next(self._seqs)
+        self._ring[seq % self.capacity] = (
+            id_, name, t0, t1, thread, parent, causes, counts, seq)
+
+    def held(self) -> Tuple[List[Tuple], int]:
+        """(the records the ring holds, in the order they were filed;
+        `lost_until`: 0, or once the ring has wrapped the end of its
+        oldest record, which no overwritten record outlasted by more
+        than the moment between two threads' filing).  A range that
+        starts at or before `lost_until` may have lost records."""
+        recs = sorted((r for r in list(self._ring) if r is not None),
+                      key=lambda r: r[SEQ])
+        wrapped = bool(recs) and recs[0][SEQ] > 0
+        return recs, recs[0][T1] if wrapped else 0
+
+    @property
+    def overwritten(self) -> int:
+        """Records filed and since overwritten."""
+        recs, _ = self.held()
+        return recs[0][SEQ] if recs else 0
+
+    def dump(self, count: int = 1000) -> Dict[str, Any]:
+        """The ring's tail, for `dump_trace` on the admin socket."""
+        recs, lost_until = self.held()
+
+        def row(r: Tuple) -> Dict[str, Any]:
+            out = {"id": r[ID], "name": r[NAME], "start_ns": r[T0],
+                   "duration_ns": r[T1] - r[T0], "thread": r[THREAD],
+                   "parent": r[PARENT], "causes": list(r[CAUSES])}
+            out.update(r[COUNTS])
+            return out
+
+        tail = recs[-count:]
+        return {
+            "clock": "monotonic_ns",
+            "anchor": {"monotonic_ns": self.anchor[0],
+                       "unix_ns": self.anchor[1]},
+            "capacity": self.capacity,
+            "held": len(recs),
+            "overwritten": recs[0][SEQ] if recs else 0,
+            "lost_until_ns": lost_until,
+            "spans": [row(r) for r in tail if r[NAME] != OP_RECORD],
+            "ops": [row(r) for r in tail if r[NAME] == OP_RECORD],
+        }
+
+
+_recorder = Recorder()
+
+
+def recorder() -> Recorder:
+    return _recorder
+
+
+def self_ns(records: Sequence[Tuple]) -> Dict[int, int]:
+    """id -> a record's duration minus the part of it that its direct
+    children (records naming it as parent, so spans of its own thread)
+    cover, each stretch counted once."""
+    kids: Dict[int, List[Tuple[int, int]]] = {}
+    for r in records:
+        if r[PARENT]:
+            kids.setdefault(r[PARENT], []).append((r[T0], r[T1]))
+    out = {}
+    for r in records:
+        covered, edge = 0, r[T0]
+        for s, e in sorted(kids.get(r[ID], ())):
+            s, e = max(s, edge), min(e, r[T1])
+            if e > s:
+                covered += e - s
+                edge = e
+        out[r[ID]] = r[T1] - r[T0] - covered
+    return out
+
+
+class BatchWindow(NamedTuple):
+    """What the ring holds of the batches `seq_lo < seq <= seq_hi`."""
+    batches: int               # `queue.batch` spans in the range
+    self_ns: Dict[str, int]    # self time by span name, over those
+    #   spans, the idle and coalesce before each, and all below them
+    ops: List[Tuple]           # op records concluded while they ran
+
+
+def batch_window(seq_lo: int, seq_hi: int,
+                 rec: Optional[Recorder] = None) -> Optional[BatchWindow]:
+    """The join between a counter window and the ring: the queue's
+    `batches` before and after a window select the `queue.batch` spans
+    (and the `queue.idle` / `queue.coalesce` that led to each) by their
+    `seq` count.  None where a batch of the range is not in the ring,
+    or the range reaches back to a record the ring overwrote."""
+    recs, lost_until = (rec or _recorder).held()
+    counted = [r for r in recs
+               if seq_lo < r[COUNTS].get("seq", seq_lo) <= seq_hi]
+    # a process serves through one queue; where it made more (tests),
+    # the range is the newest one's
+    q = counted[-1][COUNTS].get("q") if counted else None
+    tree = {r[ID]: r for r in counted if r[COUNTS].get("q") == q}
+    roots = [r for r in tree.values() if r[NAME] == "queue.batch"]
+    if not roots or len(roots) != seq_hi - seq_lo:
+        return None
+    t0 = min(r[T0] for r in tree.values())
+    t1 = max(r[T1] for r in roots)
+    if t0 <= lost_until:
+        return None
+    # children close before their parents, so one pass from the newest
+    # record back finds every descendant
+    for r in reversed(recs):
+        if r[PARENT] in tree:
+            tree[r[ID]] = r
+    own = self_ns(list(tree.values()))
+    by_name: Dict[str, int] = {}
+    for r in tree.values():
+        by_name[r[NAME]] = by_name.get(r[NAME], 0) + own[r[ID]]
+    ops = [r for r in recs if r[NAME] == OP_RECORD and t0 <= r[T1] <= t1]
+    return BatchWindow(len(roots), by_name, ops)
+
+
+# -- blkin spans (behind the `tracing` option) -----------------------------------
+
 class Span:
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "start", "end", "annotations")
@@ -104,12 +403,12 @@ class Span:
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.start = time.time()
-        self.end = 0.0
-        self.annotations: List[Tuple[float, str]] = []
+        self.start = clock()
+        self.end = 0
+        self.annotations: List[Tuple[int, str]] = []
 
     def annotate(self, what: str) -> None:
-        self.annotations.append((time.time(), what))
+        self.annotations.append((clock(), what))
 
     def context(self) -> TraceContext:
         """The wire-propagatable identity of this span."""
@@ -117,7 +416,7 @@ class Span:
 
     def finish(self) -> None:
         if not self.end:
-            self.end = time.time()
+            self.end = clock()
             self.tracer._archive(self)
 
     def __enter__(self) -> "Span":
@@ -126,69 +425,61 @@ class Span:
     def __exit__(self, *exc) -> None:
         self.finish()
 
-    def to_dict(self) -> Dict:
-        return {
-            "name": self.name,
-            "trace_id": f"{self.trace_id:016x}",
-            "span_id": f"{self.span_id:016x}",
-            "parent_id": (f"{self.parent_id:016x}"
-                          if self.parent_id else None),
-            "start": self.start,
-            "duration_s": round((self.end or time.time()) - self.start, 6),
-            "annotations": [
-                {"at": at, "what": w} for at, w in self.annotations],
-        }
+
+def _unix_s(t_ns: int) -> float:
+    mono, unix = _recorder.anchor
+    return (t_ns - mono + unix) / 1e9
+
+
+def _span_dict(r: Tuple) -> Dict:
+    return {
+        "name": r[NAME],
+        "trace_id": f"{r[CAUSES][0]:016x}",
+        "span_id": f"{r[ID]:016x}",
+        "parent_id": f"{r[PARENT]:016x}" if r[PARENT] else None,
+        "start": _unix_s(r[T0]),
+        "duration_s": round((r[T1] - r[T0]) / 1e9, 6),
+        "annotations": [{"at": _unix_s(at), "what": w}
+                        for at, w in r[COUNTS]["annotations"]],
+    }
 
 
 class Tracer:
-    """Per-daemon span recorder; disabled tracers are near-free."""
+    """A daemon's handle on the recorder for blkin spans; a disabled
+    tracer files nothing."""
 
-    def __init__(self, name: str = "", enabled: bool = True,
-                 ring_size: int = 2048) -> None:
+    def __init__(self, name: str = "", enabled: bool = True) -> None:
         self.name = name
         self.enabled = enabled
-        self._ring: Deque[Span] = collections.deque(maxlen=ring_size)
-        self._lock = threading.Lock()
+        self._key = _recorder.next_id()   # marks this tracer's records
 
-    # -- spans -------------------------------------------------------------
     def start_span(self, name: str,
                    parent: Optional[TraceContext] = None) -> Span:
         if parent is not None:
             trace_id, parent_id = parent
         else:
-            trace_id, parent_id = random.getrandbits(63) | 1, 0
-        return Span(self, name, trace_id, random.getrandbits(63) | 1,
-                    parent_id)
+            trace_id, parent_id = _recorder.wire_id(), 0
+        return Span(self, name, trace_id, _recorder.wire_id(), parent_id)
 
     def _archive(self, span: Span) -> None:
-        if not self.enabled:
-            return
-        with self._lock:
-            self._ring.append(span)
-
-    # -- tracepoints -------------------------------------------------------
-    def event(self, subsys: str, name: str, **kw) -> None:
-        """Flat tracepoint (the LTTng .tp role): recorded only when
-        enabled, compiled in always."""
-        if not self.enabled:
-            return
-        s = Span(self, f"{subsys}:{name}", 0, 0, 0)
-        s.end = s.start
-        if kw:
-            s.annotations.append((s.start, repr(kw)))
-        with self._lock:
-            self._ring.append(s)
+        if self.enabled:
+            _recorder._file(
+                span.span_id, span.name, span.start, span.end,
+                threading.get_ident(), span.parent_id, (span.trace_id,),
+                {"tracer": self._key,
+                 "annotations": tuple(span.annotations)})
 
     # -- query (admin-socket surface) --------------------------------------
+    def _mine(self) -> List[Tuple]:
+        return [r for r in _recorder.held()[0]
+                if r[COUNTS].get("tracer") == self._key]
+
     def dump(self, trace_id: int) -> List[Dict]:
-        with self._lock:
-            spans = [s for s in self._ring if s.trace_id == trace_id]
-        return [s.to_dict() for s in sorted(spans, key=lambda s: s.start)]
+        spans = [r for r in self._mine() if r[CAUSES][0] == trace_id]
+        return [_span_dict(r) for r in sorted(spans, key=lambda r: r[T0])]
 
     def recent(self, n: int = 100) -> List[Dict]:
-        with self._lock:
-            tail = list(self._ring)[-n:]
-        return [s.to_dict() for s in tail]
+        return [_span_dict(r) for r in self._mine()[-n:]]
 
 
 def trace_id_of(reqid: str) -> int:
@@ -200,10 +491,3 @@ def trace_id_of(reqid: str) -> int:
 
     b = reqid.encode()
     return ((crc32c(b) << 32) | crc32c(b, 0xA5A5A5A5)) | 1
-
-
-_global = Tracer("global")
-
-
-def tracer() -> Tracer:
-    return _global

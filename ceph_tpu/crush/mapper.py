@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ceph_tpu.core import tracing
 from ceph_tpu.crush import hashes, ln
 from ceph_tpu.tpu import shapebucket
 from ceph_tpu.tpu.devwatch import instrumented_jit
@@ -1461,13 +1462,15 @@ def sweep_device(
         @functools.partial(instrumented_jit, family="crush_mapper")
         def run(xs2, w):
             def body(overflow, sub):
-                res, clean = fast(sub, w)
+                with jax.named_scope("crush.fast"):
+                    res, clean = fast(sub, w)
                 bad = jnp.nonzero(~clean, size=cap, fill_value=chunk)[0]
                 n_bad = jnp.sum(~clean)
                 # padding lanes (index==chunk) clamp to chunk-1 and
                 # recompute sub[chunk-1]; their scatter is dropped
                 bad_xs = sub[jnp.minimum(bad, chunk - 1)]
-                res2, clean2 = mid(bad_xs, w)
+                with jax.named_scope("crush.mid"):
+                    res2, clean2 = mid(bad_xs, w)
                 res = res.at[bad].set(res2, mode="drop")
                 # residual mask back in chunk shape (padding dropped);
                 # the exact full-program fixup runs ONCE over the whole
@@ -1484,7 +1487,8 @@ def sweep_device(
             n3 = jnp.sum(resid_all)
             b3 = jnp.nonzero(resid_all, size=cap2, fill_value=n)[0]
             xs3 = xs2[jnp.minimum(b3, n - 1)]
-            fixed = slow(xs3, w)
+            with jax.named_scope("crush.slow"):
+                fixed = slow(xs3, w)
             out = out.at[b3].set(fixed, mode="drop")
             return out, overflow | (n3 > cap2)
 
@@ -1492,4 +1496,5 @@ def sweep_device(
         if len(_compiled_rules) > 256:
             _compiled_rules.pop(next(iter(_compiled_rules)))
 
-    return run(xs, jnp.asarray(dev_weights, dtype=jnp.uint32))
+    with tracing.span("crush.sweep", ids=n, chunk=chunk):
+        return run(xs, jnp.asarray(dev_weights, dtype=jnp.uint32))

@@ -29,11 +29,12 @@ from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ceph_tpu.tpu.devwatch import instrumented_jit
+from ceph_tpu.tpu.devwatch import fetch, instrumented_jit
 
 _POLY = np.uint32(0x82F63B78)
 
@@ -70,6 +71,7 @@ def _rows_kernel(R: int, C: int):
     tables = jnp.asarray(_TABLES)
     W = C // 8
 
+    @jax.named_scope("ec.crc32c")
     def kernel(rows, lens, inits):
         c0 = inits ^ jnp.uint32(0xFFFFFFFF)
         nwords = lens // 8
@@ -119,9 +121,9 @@ def crc32c_lanes(rows: np.ndarray, lens, inits=None) -> np.ndarray:
         rows = np.concatenate(
             [rows, np.zeros((R, 8 - C % 8), dtype=np.uint8)], axis=1)
         C = int(rows.shape[1])
-    # cephlint: disable=no-d2h-on-hot-path — the digest fetch: 4 bytes
-    # per lane of METADATA crossing back, the point of the fused crc
-    return np.asarray(_rows_kernel(R, C)(rows, lens, inits))
+    # the digest fetch: 4 bytes per lane of METADATA crossing back, the
+    # point of the fused crc
+    return fetch(_rows_kernel(R, C)(rows, lens, inits))
 
 
 def crc32c_rows(full: np.ndarray, offs, lens, inits=None) -> np.ndarray:
@@ -133,16 +135,25 @@ def crc32c_rows(full: np.ndarray, offs, lens, inits=None) -> np.ndarray:
     ``s`` of job ``j`` — exactly what each shard's HashInfo wants,
     fetched as metadata (4 bytes/shard) instead of payload.
 
-    Rows are laid out (job-major) with both axes padded to pow2 so the
-    compile set stays bounded; the relayout is part of the same device
-    batch as the GF matmul (on CPU rigs it is a host move inside the
-    already-counted upload — no extra crossing)."""
+    Two steps, which the stripe batch queue takes one by one so that
+    each is a span of its own: the host relayout (`rows_layout`) and
+    the device pass over it (`crc32c_lanes`)."""
+    J, S = len(offs), int(full.shape[0])
+    if J == 0:
+        return np.empty((0, S), dtype=np.uint32)
+    rows, rlens, rinits = rows_layout(full, offs, lens, inits)
+    return crc32c_lanes(rows, rlens, rinits).reshape(-1, S)[:J]
+
+
+def rows_layout(full: np.ndarray, offs, lens, inits=None):
+    """The row relayout of `crc32c_rows`: -> (rows u8 [Jp*S, C], lens
+    i32, inits u32), job-major, both axes padded to pow2 so the compile
+    set stays bounded.  A host move (on CPU rigs inside the already-
+    counted upload — no extra crossing)."""
     # cephlint: disable=no-d2h-on-hot-path — column extents: metadata
     offs = np.asarray(offs, dtype=np.int64)
     lens = np.asarray(lens, dtype=np.int64)  # cephlint: disable=no-d2h-on-hot-path — metadata
     J, S = len(offs), int(full.shape[0])
-    if J == 0:
-        return np.empty((0, S), dtype=np.uint32)
     if inits is None:
         inits = np.zeros(J, dtype=np.uint32)
     else:
@@ -157,8 +168,7 @@ def crc32c_rows(full: np.ndarray, offs, lens, inits=None) -> np.ndarray:
         rows[j * S:(j + 1) * S, :ln] = full[:, o:o + ln]
         rlens[j * S:(j + 1) * S] = ln
         rinits[j * S:(j + 1) * S] = inits[j]
-    out = crc32c_lanes(rows, rlens, rinits)
-    return out.reshape(Jp, S)[:J]
+    return rows, rlens, rinits
 
 
 # pow2-bucketed single-buffer entry (tests, tools, ad-hoc checksums)

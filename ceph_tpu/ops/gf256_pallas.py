@@ -108,11 +108,12 @@ def _compiled(matrix_bytes: bytes, shape: Tuple[int, int], tile: int,
     # that keeps live HBM ~one batch deep)
     alias = {1: 0} if (donate and R == k and not interpret) else {}
 
+    @jax.named_scope("ec.encode")
     def run(words3: jax.Array, seed: jax.Array) -> jax.Array:
         kk, T, L = words3.shape
         assert kk == k and L == LANES and T % tile == 0, (kk, T, L)
         return instrumented_pallas_call(
-            kernel, family="gf256_pallas",
+            kernel, family="gf256_pallas", name="ec_encode",
             out_shape=jax.ShapeDtypeStruct((R, T, LANES), jnp.uint32),
             grid=(T // tile,),
             in_specs=[
@@ -131,6 +132,18 @@ def _compiled(matrix_bytes: bytes, shape: Tuple[int, int], tile: int,
     return (instrumented_jit(run, family="gf256_pallas",
                              donate_argnums=(0,)) if alias
             else instrumented_jit(run, family="gf256_pallas"))
+
+
+def _words_operand(words3):
+    """Host words go into the jitted call as they are, so that their
+    upload is part of the call's `dev.dispatch` span (a `jnp.asarray`
+    ahead of the call is a transfer of its own, outside it); anything
+    else becomes a u32 device array as before."""
+    if isinstance(words3, np.ndarray):
+        return words3.astype(np.uint32, copy=False)
+    # an h2d upload or a no-op, not a fetch back to host
+    # cephlint: disable=no-d2h-on-hot-path
+    return jnp.asarray(words3, dtype=jnp.uint32)
 
 
 def encode_planes(matrix: np.ndarray, words3, seed=None, *,
@@ -157,9 +170,7 @@ def encode_planes(matrix: np.ndarray, words3, seed=None, *,
     # is touched
     fn = _compiled(matrix.tobytes(), matrix.shape, tile, interpret,
                    mul_shift, donate, dimsem)
-    # sanctioned h2d upload of the pre-packed words, not a payload
-    # fetch back to host  # cephlint: disable=no-d2h-on-hot-path
-    return fn(jnp.asarray(words3, dtype=jnp.uint32), seed)
+    return fn(_words_operand(words3), seed)
 
 
 # ---------------------------------------------------------------------------
@@ -216,11 +227,12 @@ def _compiled_operand(R: int, k: int, tile: int, interpret: bool,
     # a square code's output aliases its input (see _compiled)
     alias = {1: 0} if (donate and R == k and not interpret) else {}
 
+    @jax.named_scope("ec.decode")
     def run(masks: jax.Array, words3: jax.Array) -> jax.Array:
         kk, T, L = words3.shape
         assert kk == k and L == LANES and T % tile == 0, (kk, T, L)
         return instrumented_pallas_call(
-            kernel, family="gf256_pallas",
+            kernel, family="gf256_pallas", name="ec_decode",
             out_shape=jax.ShapeDtypeStruct((R, T, LANES), jnp.uint32),
             grid=(T // tile,),
             in_specs=[
@@ -252,11 +264,7 @@ def apply_planes(matrix: np.ndarray, words3, *,
         interpret = jax.default_backend() != "tpu"
     R, k = matrix.shape
     fn = _compiled_operand(R, k, tile, interpret, donate)
-    # sanctioned h2d upload of the masks (metadata) and the pre-packed
-    # words, not a fetch  # cephlint: disable=no-d2h-on-hot-path
-    masks = jnp.asarray(matrix_masks(matrix))
-    # cephlint: disable=no-d2h-on-hot-path
-    return fn(masks, jnp.asarray(words3, dtype=jnp.uint32))
+    return fn(matrix_masks(matrix), _words_operand(words3))
 
 
 def pack_planes(x: np.ndarray) -> np.ndarray:
@@ -315,11 +323,12 @@ def _compiled_interleaved(matrix_bytes: bytes, shape: Tuple[int, int],
     kernel = _make_kernel_interleaved(matrix, mul_shift)
 
     @functools.partial(instrumented_jit, family="gf256_pallas")
+    @jax.named_scope("ec.encode")
     def run(words3: jax.Array, seed: jax.Array) -> jax.Array:
         T, kk, L = words3.shape
         assert kk == k and L == LANES and T % tile == 0, (T, kk, L)
         return instrumented_pallas_call(
-            kernel, family="gf256_pallas",
+            kernel, family="gf256_pallas", name="ec_encode_interleaved",
             out_shape=jax.ShapeDtypeStruct((T, R, LANES), jnp.uint32),
             grid=(T // tile,),
             in_specs=[
@@ -350,4 +359,4 @@ def encode_planes_interleaved(matrix: np.ndarray, words3, seed=None, *,
         seed = jnp.zeros((1,), jnp.uint32)
     fn = _compiled_interleaved(matrix.tobytes(), matrix.shape, tile,
                                interpret, mul_shift)
-    return fn(jnp.asarray(words3, dtype=jnp.uint32), seed)
+    return fn(_words_operand(words3), seed)

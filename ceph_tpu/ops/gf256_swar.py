@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ceph_tpu.tpu.devwatch import instrumented_jit
+from ceph_tpu.tpu.devwatch import fetch, instrumented_jit
 
 _native_rs = None  # None = unresolved, False = unavailable
 
@@ -83,6 +83,7 @@ def _build_network(matrix: np.ndarray) -> Callable[[jax.Array], jax.Array]:
             need_bits[j] |= c
     max_bit = [nb.bit_length() for nb in need_bits]
 
+    @jax.named_scope("ec.encode")
     def apply(words: jax.Array) -> jax.Array:
         acc = [None] * R
         for j in range(k):
@@ -130,6 +131,7 @@ def _compiled_words_operand(R: int, k: int, family: str) -> Callable:
     if fn is None:
         from ceph_tpu.ops.gf256_pallas import masked_network
 
+        @jax.named_scope("ec.decode")
         def run(masks: jax.Array, words: jax.Array) -> jax.Array:
             return jnp.stack(masked_network(
                 lambda idx: masks[idx], [words[j] for j in range(k)],
@@ -239,16 +241,13 @@ def gf_matmul_bytes(matrix: np.ndarray, x, donate: bool = False,
             out3 = gf256_pallas.encode_planes(
                 matrix, words3, tile=tile, donate=donate)
         # the fetch every consumer makes anyway
-        # cephlint: disable=no-d2h-on-hot-path
-        out32 = np.asarray(out3)[:, :T].reshape(R, -1)
+        out32 = fetch(out3)[:, :T].reshape(R, -1)
     elif operand:
         from ceph_tpu.ops import gf256_pallas
 
-        # cephlint: disable=no-d2h-on-hot-path
-        out32 = np.asarray(_compiled_words_operand(R, k, family)(
+        out32 = fetch(_compiled_words_operand(R, k, family)(
             gf256_pallas.matrix_masks(matrix), words))
     else:
-        # cephlint: disable=no-d2h-on-hot-path
-        out32 = np.asarray(_compiled_words(matrix, family)(words))
+        out32 = fetch(_compiled_words(matrix, family)(words))
     out = np.ascontiguousarray(out32).view(np.uint8)
     return out[:, :n] if pad else out

@@ -292,8 +292,6 @@ class OSDService(Dispatcher):
         # compute vs callback dispatch) — process-wide like the queue,
         # dumped under each daemon's context exactly like osd.N.tpu
         ctx.perf.register(f"osd.{whoami}.tpuq", _dq.perf)
-        # batch spans (job width / kind) ride this context's tracer
-        _dq.tracer = ctx.trace
         # apply the daemon's staging-pool geometry conf (the pool is
         # built before any Context exists, env-sized); a busy pool
         # refuses the resize — first idle daemon boot wins
@@ -1377,7 +1375,8 @@ class OSDService(Dispatcher):
             top = self.op_tracker.create_op(
                 f"osd_op({msg.src} tid={tid} {msg.oid} "
                 f"{'+'.join(str(o.op) for o in msg.ops)} pg={msg.pgid})",
-                start=getattr(msg, "_recv_stamp", None))
+                start=getattr(msg, "_recv_stamp", None),
+                reqid=getattr(msg, "reqid", ""))
             top.mark_event("queued_for_pg")
             # the tracked op rides the message through the PG pipeline
             # (local attribute, never encoded): every stage marks it

@@ -56,6 +56,9 @@ import threading
 import time
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
+import numpy as np
+
+from ceph_tpu.core import tracing
 from ceph_tpu.core.lockdep import make_lock
 from ceph_tpu.core.perf import PerfCounters
 
@@ -260,6 +263,10 @@ class DeviceWatch:
         # monotonic stamp of the last compile END (the blame fast
         # path's lock-free pre-check; 0.0 = never compiled)
         self.last_compile_end = 0.0
+        # cumulative seconds host threads spent blocked in fetch()
+        # (`dev.wait` spans): what the queue's device_busy_pct gauge
+        # is the rate of
+        self.wait_s = 0.0
         self._storm_last: Dict[str, float] = {}  # family -> last WARN t
         self.storms: List[Dict[str, Any]] = []   # bounded below
         self._steady = 0  # steady-state section depth
@@ -429,6 +436,10 @@ class DeviceWatch:
             fam.dispatches += 1
             self.perf.inc("cache_hits")
             self.perf.hinc(f"exec_{family}_us", dur_s * 1e6)
+
+    def note_wait(self, dur_s: float) -> None:
+        with self._lock:
+            self.wait_s += dur_s
 
     def note_trace(self, family: str) -> None:
         """A pallas_call construction ran — trace(-re)entry evidence
@@ -746,7 +757,13 @@ def watch() -> DeviceWatch:
 
 def instrumented_jit(fun: Optional[Callable] = None, *,
                      family: str, **jit_kwargs) -> Callable:
-    """``jax.jit`` with compile/dispatch attribution.
+    """``jax.jit`` with compile/dispatch attribution, for every family
+    at once: the traced function runs under ``jax.named_scope(family)``
+    (device ops carry the family in their op metadata and the trace's
+    name-scope line; the program itself and its compile-cache key are
+    what they were), and every call is a ``dev.dispatch`` span (upload
+    of numpy operands and enqueue; the device's work is awaited in
+    :func:`fetch`).
 
     Usable directly (``instrumented_jit(run, family="gf256_swar",
     donate_argnums=(0,))``) or as a decorator via ``functools.partial``
@@ -762,7 +779,12 @@ def instrumented_jit(fun: Optional[Callable] = None, *,
                                  **jit_kwargs)
     import jax
 
-    jitted = jax.jit(fun, **jit_kwargs)
+    @functools.wraps(fun)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(family):
+            return fun(*args, **kwargs)
+
+    jitted = jax.jit(scoped, **jit_kwargs)
     seen: set = set()
     # static args key by VALUE (a distinct static value is a distinct
     # compile in jax); everything else by shape/dtype/type
@@ -778,14 +800,15 @@ def instrumented_jit(fun: Optional[Callable] = None, *,
         sig = signature(args, kwargs, stat_nums, stat_names)
         w = _WATCH
         if sig in seen:
-            t0 = time.monotonic()
-            out = jitted(*args, **kwargs)
-            w.note_hit(family, time.monotonic() - t0)
+            with tracing.span("dev.dispatch", family=family) as sp:
+                out = jitted(*args, **kwargs)
+            w.note_hit(family, sp.seconds)
             return out
         tok = w.compile_begin(family)
         failed = True
         try:
-            out = jitted(*args, **kwargs)
+            with tracing.span("dev.dispatch", family=family):
+                out = jitted(*args, **kwargs)
             failed = False
         finally:
             w.compile_end(tok, sig, error=failed)
@@ -797,6 +820,19 @@ def instrumented_jit(fun: Optional[Callable] = None, *,
     # (tests/test_chip_compile.py compiles for a described chip)
     wrapper.jitted = jitted
     return wrapper
+
+
+def fetch(out):
+    """A device result as a host array: the ONE place the host blocks
+    until the device is done (the sanctioned batched d2h of the encode,
+    decode and crc paths), so the one site of the ``dev.wait`` span.
+    The time feeds :attr:`DeviceWatch.wait_s`."""
+    with tracing.span("dev.wait") as sp:
+        # cephlint: disable=no-d2h-on-hot-path — the engine's own
+        # batched fetch; callers say why theirs is sanctioned
+        host = np.asarray(out)
+    _WATCH.note_wait(sp.seconds)
+    return host
 
 
 def instrumented_pallas_call(kernel: Callable, *, family: str,

@@ -14,6 +14,7 @@ N, the worker is already collecting batch N+1.
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 import time
@@ -22,6 +23,7 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from ceph_tpu.core import tracing
 from ceph_tpu.core.perf import PerfCounters
 from ceph_tpu.tpu import devwatch, shapebucket
 from ceph_tpu.tpu.staging import DevPathStats, StagingPool
@@ -72,6 +74,9 @@ class StripeBatchQueue:
         self._started = False
         self._lock = threading.Lock()
         self.batches = 0       # perf: device dispatches
+        # this queue in the span ring: `batches` is the `seq` of its
+        # spans, and a process that made a second queue counts again
+        self._span_q = tracing.recorder().next_id()
         self.jobs = 0          # perf: logical encodes
         self.bytes_in = 0      # perf: plane bytes that rode the queue
         # jobs-per-batch histogram {width: batches}: the direct
@@ -99,7 +104,9 @@ class StripeBatchQueue:
         self.perf.add_histogram(
             "lat_encq_wait_us", "job enqueue -> batch start (us)")
         self.perf.add_histogram(
-            "lat_device_us", "device compute per coalesced batch (us)")
+            "lat_device_us",
+            "host clock around one batch: stack, dispatch, fetch, crc "
+            "layout, up to the fan-out; NOT device time (us)")
         self.perf.add_histogram(
             "lat_encq_dispatch_us",
             "batch result fan-out to futures (us)")
@@ -111,16 +118,17 @@ class StripeBatchQueue:
             "queue_depth", "jobs waiting in the stripe batch queue")
         self.perf.add_u64_gauge(
             "device_busy_pct",
-            "device compute wall-fraction over the sample window (%)")
+            "share of the sample window a host thread spent blocked "
+            "on the device (dev.wait spans) (%)")
         self.perf.add_u64_gauge(
             "staging_slots_used", "pinned staging pool slots in use")
-        self.device_time_s = 0.0  # cumulative device compute seconds
+        # cumulative host clock around the batches (what lat_device_us
+        # takes in): NOT device time, at saturation it grows a second
+        # a second whatever the device does
+        self.device_time_s = 0.0
         from ceph_tpu.core.perf import SnapshotRing
 
         self._gauge_ring = SnapshotRing(capacity=32)
-        # batch spans (width/kind per dispatch) ride this tracer when
-        # set AND enabled; bound by daemon init to its context's tracer
-        self.tracer = None
         # the batch the device worker is executing RIGHT NOW (kind,
         # jobs, shapes, start stamp) — the crash flight recorder's
         # "what was the device doing when we died" evidence; None when
@@ -141,9 +149,9 @@ class StripeBatchQueue:
         """Refresh the device-visibility gauges: called off the data
         path (the OSD stats tick, the bench) so `perf dump` and the
         Prometheus export show live queue depth, staging occupancy,
-        and the device-busy fraction derived from the cumulative
-        compute-time counter over the ring window."""
-        self._gauge_ring.push({"device_s": self.device_time_s})
+        and the device-busy fraction: the rate at which devwatch's
+        cumulative `dev.wait` seconds grew over the ring window."""
+        self._gauge_ring.push({"device_s": devwatch.watch().wait_s})
         busy = self._gauge_ring.rate("device_s", window_s)
         self.perf.set("device_busy_pct", int(round(min(1.0, busy) * 100)))
         self.perf.set("queue_depth", self._q.qsize())
@@ -261,42 +269,51 @@ class StripeBatchQueue:
 
     # -- worker -----------------------------------------------------------
     def _worker(self) -> None:
+        job = None   # a job taken that did not fit the batch before it
         while True:
-            job = self._q.get()
+            if job is None:
+                with tracing.span("queue.idle", q=self._span_q,
+                                  seq=self.batches + 1):
+                    job = self._q.get()
             if job is None:
                 return
-            batch = [job]
-            cols = job.planes.shape[1]
-            # greedy same-codec coalescing: drain whatever is queued,
-            # waiting at most one window for stragglers
-            waited = False
-            while cols < self.max_batch_cols:
-                try:
-                    nxt = self._q.get_nowait()
-                except queue.Empty:
-                    if waited:
-                        break
-                    waited = True
-                    try:
-                        nxt = self._q.get(timeout=self.window_s)
-                    except queue.Empty:
-                        break
-                if nxt is None:
-                    self._run_batch(batch)
-                    return
-                if (nxt.codec is not batch[0].codec
-                        or nxt.kind != batch[0].kind
-                        or nxt.sig != batch[0].sig
-                        or nxt.planes.shape[0] != batch[0].planes.shape[0]):
-                    # different codec: flush current, start fresh
-                    self._run_batch(batch)
-                    batch = [nxt]
-                    cols = nxt.planes.shape[1]
-                    waited = False
-                    continue
-                batch.append(nxt)
-                cols += nxt.planes.shape[1]
+            with tracing.span("queue.coalesce", q=self._span_q,
+                              seq=self.batches + 1):
+                batch, job, stop = self._coalesce(job)
             self._run_batch(batch)
+            if stop:
+                return
+
+    def _coalesce(self, first: _Job):
+        """Greedy same-codec coalescing: drain whatever is queued,
+        waiting at most one window for stragglers.  -> (the batch, the
+        job that ended it because it belongs to another batch or None,
+        whether the stop sentinel ended it)."""
+        batch = [first]
+        cols = first.planes.shape[1]
+        waited = False
+        while cols < self.max_batch_cols:
+            try:
+                nxt = self._q.get_nowait()
+            except queue.Empty:
+                if waited:
+                    break
+                waited = True
+                try:
+                    nxt = self._q.get(timeout=self.window_s)
+                except queue.Empty:
+                    break
+            if nxt is None:
+                return batch, None, True
+            if (nxt.codec is not first.codec
+                    or nxt.kind != first.kind
+                    or nxt.sig != first.sig
+                    or nxt.planes.shape[0] != first.planes.shape[0]):
+                # different codec: flush current, start fresh
+                return batch, nxt, False
+            batch.append(nxt)
+            cols += nxt.planes.shape[1]
+        return batch, None, False
 
     def _apply_matrix(self, codec, batch: List[_Job],
                       stacked: np.ndarray) -> np.ndarray:
@@ -343,7 +360,7 @@ class StripeBatchQueue:
         per-layer width is covering-padded to a pow2 so the flattened
         pair/solve matmul widths inside the codec stay in the declared
         gf256_clay buckets.  Returns (per-job outputs, per-job crcs or
-        None)."""
+        None, the padded width)."""
         Z = int(codec.get_sub_chunk_count())
         kind = batch[0].kind
         rows = batch[0].planes.shape[0]
@@ -352,12 +369,13 @@ class StripeBatchQueue:
         per_row = Z if kind in ("enc", "encp") else 1
         svec = [w // per_row for w in widths]
         s_pad = shapebucket.covering(sum(svec), 1)
-        stacked = np.zeros((rows, per_row, s_pad), dtype=np.uint8)
-        off = 0
-        for j, s in zip(batch, svec):
-            stacked[:, :, off:off + s] = j.planes.reshape(
-                rows, per_row, s)
-            off += s
+        with tracing.span("batch.stack"):
+            stacked = np.zeros((rows, per_row, s_pad), dtype=np.uint8)
+            off = 0
+            for j, s in zip(batch, svec):
+                stacked[:, :, off:off + s] = j.planes.reshape(
+                    rows, per_row, s)
+                off += s
         offs: List[int] = []
         o = 0
         for s in svec:
@@ -369,24 +387,27 @@ class StripeBatchQueue:
             lost = batch[0].sig[0]
             helpers = list(batch[0].sig[1:])
             layers = rows // len(helpers)
-            out = np.asarray(codec.repair_planes(
-                lost, helpers,
-                stacked.reshape(len(helpers), layers, s_pad)))
+            with tracing.span("batch.encode"):
+                out = np.asarray(codec.repair_planes(
+                    lost, helpers,
+                    stacked.reshape(len(helpers), layers, s_pad)))
             outs = [
                 np.ascontiguousarray(out[:, o:o + s]).reshape(-1)
                 for o, s in zip(offs, svec)]
         elif kind == "cdec":
             avail = list(batch[0].sig)
-            data = np.asarray(codec.decode_planes(
-                avail, stacked.reshape(len(avail), Z * s_pad)))
+            with tracing.span("batch.encode"):
+                data = np.asarray(codec.decode_planes(
+                    avail, stacked.reshape(len(avail), Z * s_pad)))
             d3 = data.reshape(codec.k, Z, s_pad)
             outs = [
                 np.ascontiguousarray(d3[:, :, o:o + s]).reshape(
                     codec.k, -1)
                 for o, s in zip(offs, svec)]
         else:
-            coding = np.asarray(codec.encode_array(
-                stacked.reshape(rows, per_row * s_pad)))
+            with tracing.span("batch.encode"):
+                coding = np.asarray(codec.encode_array(
+                    stacked.reshape(rows, per_row * s_pad)))
             c3 = coding.reshape(codec.m, Z, s_pad)
             outs = [
                 np.ascontiguousarray(c3[:, :, o:o + s]).reshape(
@@ -398,19 +419,35 @@ class StripeBatchQueue:
                 # so the relayout from the s-axis batch is rebuilt
                 # host-side; same device-rig honesty note as the flat
                 # encp path)
-                from ceph_tpu.ops.crc32c_device import crc32c_rows
+                boffs = list(itertools.accumulate(widths, initial=0))[:-1]
 
-                full = np.zeros((rows + codec.m, sum(widths)),
-                                dtype=np.uint8)
-                bo = 0
-                boffs: List[int] = []
-                for i, (j, w) in enumerate(zip(batch, widths)):
-                    full[:rows, bo:bo + w] = j.planes
-                    full[rows:, bo:bo + w] = outs[i]
-                    boffs.append(bo)
-                    bo += w
-                crcs = crc32c_rows(full, boffs, widths)
-        return outs, crcs
+                def full_planes() -> np.ndarray:
+                    full = np.zeros((rows + codec.m, sum(widths)),
+                                    dtype=np.uint8)
+                    for i, (j, bo, w) in enumerate(
+                            zip(batch, boffs, widths)):
+                        full[:rows, bo:bo + w] = j.planes
+                        full[rows:, bo:bo + w] = outs[i]
+                    return full
+
+                crcs = self._fused_crc(full_planes, boffs, widths)
+        return outs, crcs, per_row * s_pad
+
+    @staticmethod
+    def _fused_crc(full_planes, offs: List[int],
+                   widths: List[int]) -> np.ndarray:
+        """Per-(job, shard) crc32c of a batch's data planes stacked
+        over its coding planes (`full_planes()` builds them): the host
+        relayout and the device pass of `crc32c_rows`, each under its
+        span.  -> u32 [jobs, k+m]."""
+        from ceph_tpu.ops.crc32c_device import crc32c_lanes, rows_layout
+
+        with tracing.span("batch.crc_layout"):
+            full = full_planes()
+            rows, lens, inits = rows_layout(full, offs, widths)
+        with tracing.span("batch.crc"):
+            crcs = crc32c_lanes(rows, lens, inits)
+        return crcs.reshape(-1, full.shape[0])[:len(widths)]
 
     def _run_batch(self, batch: List[_Job]) -> None:
         # publish the in-flight batch BEFORE any dispatch work (incl.
@@ -433,6 +470,19 @@ class StripeBatchQueue:
         if fp.enabled("queue.batch.dispatch"):
             fp.failpoint("queue.batch.dispatch", jobs=len(batch),
                          kind=batch[0].kind)
+        # the batch's span: its children are the stages below, what
+        # none of them covers (counters, note_batch, compile blame) is
+        # its self time; `seq` is set once `batches` has counted it
+        with tracing.span(
+                "queue.batch",
+                causes=tuple(j.trop.id for j in batch
+                             if j.trop is not None),
+                q=self._span_q, kind=batch[0].kind,
+                jobs=len(batch)) as sp:
+            self._dispatch_stages(batch, shapes, sp.counts)
+
+    def _dispatch_stages(self, batch: List[_Job],
+                         shapes: List[List[int]], counts: Dict) -> None:
         t_start = time.monotonic()
         for j in batch:
             # queue wait: enqueue -> batch start; the coalescing
@@ -462,21 +512,25 @@ class StripeBatchQueue:
                 gran = max(1, int(get_subs()))
             codec = batch[0].codec
             if gran > 1:
-                outs, crcs = self._dispatch_array(codec, batch, widths)
+                outs, crcs, padded = self._dispatch_array(
+                    codec, batch, widths)
                 t_compute = time.monotonic()
-                for i, j in enumerate(batch):
-                    j.future.set_result(
-                        (outs[i], crcs[i]) if batch[0].kind == "encp"
-                        else outs[i])
+                with tracing.span("batch.fanout"):
+                    for i, j in enumerate(batch):
+                        j.future.set_result(
+                            (outs[i], crcs[i]) if batch[0].kind == "encp"
+                            else outs[i])
             else:
                 padded = shapebucket.covering(total, gran)
                 k = batch[0].planes.shape[0]
-                stacked = np.zeros((k, padded), dtype=np.uint8)
-                off = 0
-                for j, w in zip(batch, widths):
-                    stacked[:, off:off + w] = j.planes
-                    off += w
-                coding = self._apply_matrix(codec, batch, stacked)
+                with tracing.span("batch.stack"):
+                    stacked = np.zeros((k, padded), dtype=np.uint8)
+                    off = 0
+                    for j, w in zip(batch, widths):
+                        stacked[:, off:off + w] = j.planes
+                        off += w
+                with tracing.span("batch.encode"):
+                    coding = self._apply_matrix(codec, batch, stacked)
                 if batch[0].kind == "encp":
                     # fused per-shard crc32c: one more device pass over
                     # the SAME batch (data planes + fresh coding
@@ -488,28 +542,25 @@ class StripeBatchQueue:
                     # them as jnp ops on the resident batch or it pays
                     # an uncounted round-trip — that port is the
                     # device-rig follow-up, not a counter change
-                    from ceph_tpu.ops.crc32c_device import crc32c_rows
-
-                    full = np.concatenate(
-                        [stacked, np.asarray(coding)], axis=0)
-                    offs: List[int] = []
-                    o = 0
-                    for w in widths:
-                        offs.append(o)
-                        o += w
-                    crcs = crc32c_rows(full, offs, widths)
+                    crcs = self._fused_crc(
+                        lambda: np.concatenate(
+                            [stacked, np.asarray(coding)], axis=0),
+                        list(itertools.accumulate(widths, initial=0))[:-1],
+                        widths)
                     t_compute = time.monotonic()
-                    off = 0
-                    for i, (j, w) in enumerate(zip(batch, widths)):
-                        j.future.set_result(
-                            (coding[:, off:off + w], crcs[i]))
-                        off += w
+                    with tracing.span("batch.fanout"):
+                        off = 0
+                        for i, (j, w) in enumerate(zip(batch, widths)):
+                            j.future.set_result(
+                                (coding[:, off:off + w], crcs[i]))
+                            off += w
                 else:
                     t_compute = time.monotonic()
-                    off = 0
-                    for j, w in zip(batch, widths):
-                        j.future.set_result(coding[:, off:off + w])
-                        off += w
+                    with tracing.span("batch.fanout"):
+                        off = 0
+                        for j, w in zip(batch, widths):
+                            j.future.set_result(coding[:, off:off + w])
+                            off += w
             if batch[0].kind in ("encp", "dec", "cdec", "crep"):
                 # the ONE h2d upload of the device-resident path: the
                 # whole coalesced batch crosses together (stripe-tail
@@ -525,7 +576,10 @@ class StripeBatchQueue:
             if batch[0].kind in ("dec", "cdec", "crep"):
                 self.dec_batch_jobs[len(batch)] = (
                     self.dec_batch_jobs.get(len(batch), 0) + 1)
-            self.bytes_in += sum(j.planes.nbytes for j in batch)
+            nbytes = sum(j.planes.nbytes for j in batch)
+            self.bytes_in += nbytes
+            counts.update(seq=self.batches, cols=total, padded=padded,
+                          bytes=nbytes)
             t_done = time.monotonic()
             self.device_time_s += t_compute - t_start
             self.perf.hinc("lat_device_us",
@@ -563,14 +617,6 @@ class StripeBatchQueue:
                     if trk is not None and trk.perf is not None:
                         trk.perf.hinc("lat_compile_wait_us",
                                       wait * 1e6)
-            tr = self.tracer
-            if tr is not None and tr.enabled:
-                # batch span record: job width is THE coalescing
-                # evidence per dispatch (tracepoint, not a span — a
-                # batch serves many unrelated ops)
-                tr.event("tpu", "batch", jobs=len(batch),
-                         kind=batch[0].kind,
-                         cols=sum(j.planes.shape[1] for j in batch))
         except BaseException as e:  # noqa: BLE001 — propagate to callers
             for j in batch:
                 if not j.future.done():

@@ -160,7 +160,11 @@ def phase_ec(args, on_tpu: bool) -> None:
     hist = {str(k): v for k, v in sorted(q.batch_jobs.items())}
     dhist = {str(k): v for k, v in sorted(q.dec_batch_jobs.items())}
     emit("ec.queue", batches=q.batches, jobs=q.jobs,
-         bytes_in=q.bytes_in, device_time_s=round(q.device_time_s, 3),
+         bytes_in=q.bytes_in,
+         # host clock around the batches, and of it the time the
+         # worker was blocked on the device (dev.wait spans)
+         batch_host_s=round(q.device_time_s, 3),
+         dev_wait_s=round(dw.wait_s, 3),
          batch_jobs_hist=hist, dec_batch_jobs_hist=dhist,
          devpath=q.stats.snapshot(), families=fam_table(dw))
     require(q.batches > 0, "no batch ever reached the device queue")

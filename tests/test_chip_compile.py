@@ -84,7 +84,11 @@ def _compile_planes(one_chip, matrix, T, donate=False):
     compiled = fn.jitted.lower(
         _spec(one_chip, (matrix.shape[1], T_pad, LANES), jnp.uint32),
         _spec(one_chip, (1,), jnp.uint32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's op is named for what it does, and its metadata
+    # carries the family and the scope: stable names for a trace
+    assert "%ec_encode" in text and "gf256_pallas/ec.encode" in text
     return compiled
 
 
@@ -124,7 +128,9 @@ def test_operand_decode_k8_at_queue_widths(chip, T):
     compiled = fn.jitted.lower(
         _spec(chip, (8 * 8 * 8,), jnp.uint32),
         _spec(chip, (8, T_pad, LANES), jnp.uint32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%ec_decode" in text and "gf256_pallas/ec.decode" in text
 
 
 def test_clay_pair_matrix(chip):
@@ -171,10 +177,15 @@ def test_crc32c_rows_kernel_at_queue_shape(chip):
     from ceph_tpu.ops import crc32c_device
 
     R, C = 96, 131072
-    crc32c_device._rows_kernel(R, C).jitted.lower(
+    compiled = crc32c_device._rows_kernel(R, C).jitted.lower(
         _spec(chip, (R, C), jnp.uint8),
         _spec(chip, (R,), jnp.int32),
         _spec(chip, (R,), jnp.uint32)).compile()
+    # the loop that is 97 % of the write cell's device time names its
+    # family and scope in its op metadata (the `%while.N` of a trace)
+    loops = [ln for ln in compiled.as_text().splitlines()
+             if " while(" in ln and "op_name=" in ln]
+    assert loops and all("crc32c_device/ec.crc32c" in ln for ln in loops)
 
 
 def test_crush_sweep_chunk_program(chip):

@@ -491,6 +491,34 @@ def test_span_discipline_stage_registry(tmp_path):
     assert not ok
 
 
+def test_span_discipline_recorder_span_registry(tmp_path):
+    """tracing.span names are held to tracing.SPANS as stage names are
+    to STAGES."""
+    bad = _lint(tmp_path, (
+        "from ceph_tpu.core import tracing\n"
+        "def f():\n"
+        "    with tracing.span('batch.stak'):\n"  # typo'd span
+        "        pass\n"
+    ), "span-discipline")
+    assert len(bad) == 1 and "tracing.SPANS" in bad[0].message
+
+    dyn = _lint(tmp_path, (
+        "from ceph_tpu.core import tracing\n"
+        "def f(name):\n"
+        "    with tracing.span(name):\n"
+        "        pass\n"
+    ), "span-discipline")
+    assert len(dyn) == 1 and "<dynamic>" in dyn[0].detail
+
+    ok = _lint(tmp_path, (
+        "from ceph_tpu.core import tracing\n"
+        "def f(n):\n"
+        "    with tracing.span('batch.stack', cols=n):\n"
+        "        pass\n"
+    ), "span-discipline")
+    assert not ok
+
+
 def test_span_discipline_never_baseline(tmp_path):
     from ceph_tpu.analysis.framework import (Violation,
                                              violations_to_baseline)

@@ -10,6 +10,7 @@ prometheus modules.
 import re
 import time
 
+import numpy as np
 import pytest
 
 from ceph_tpu.core.config import Config
@@ -655,18 +656,49 @@ def test_progress_repair_events_track_scrub_errors():
 # -- device-visibility gauges -------------------------------------------------
 
 def test_tpuq_gauges_sampled():
-    import numpy as np
-
     from ceph_tpu.ec import codec_from_profile
     from ceph_tpu.tpu.queue import StripeBatchQueue
 
     q = StripeBatchQueue()
     codec = codec_from_profile("plugin=isa k=2 m=1 "
                                "technique=reed_sol_van")
+    q.sample()
     q.encode(codec, np.zeros((2, 1024), dtype=np.uint8))
     q.sample()
     dump = q.perf.dump()
     assert "queue_depth" in dump and "device_busy_pct" in dump
     assert dump["staging_slots_used"] == 0
+    # host clock around the batch: it moves with every batch, and is
+    # not what the gauge reads (the CPU's native encode waits on no
+    # device: busy 0 %)
     assert q.device_time_s > 0.0
+    assert dump["device_busy_pct"] == 0
     q.stop()
+
+
+def test_device_busy_gauge_follows_time_blocked_on_the_device():
+    """`device_busy_pct` is the rate of devwatch's `dev.wait` seconds
+    (host threads blocked in fetch()), not of the batches' host clock,
+    which at saturation grows a second a second whatever the device
+    does."""
+    import time
+
+    from ceph_tpu.tpu import devwatch
+    from ceph_tpu.tpu.queue import StripeBatchQueue
+
+    q = StripeBatchQueue()
+    q.sample()
+    time.sleep(0.05)
+    q.device_time_s += 0.05           # a saturated worker's host clock
+    q.sample()
+    assert q.perf.dump()["device_busy_pct"] == 0
+    t0 = time.monotonic()
+    devwatch.fetch(np.zeros(4))       # a dev.wait span, however short
+    before = devwatch.watch().wait_s
+    time.sleep(0.05)
+    devwatch.watch().note_wait(0.04)  # 40 ms blocked since then
+    assert devwatch.watch().wait_s == pytest.approx(before + 0.04)
+    q.sample()
+    busy = q.perf.dump()["device_busy_pct"]
+    assert 0.04 / (time.monotonic() - t0 + 0.1) * 100 - 1 <= busy <= 100
+    assert busy >= 10

@@ -25,7 +25,6 @@ def test_disabled_tracer_archives_nothing():
     tr = Tracer("t", enabled=False)
     with tr.start_span("x") as s:
         s.annotate("y")
-    tr.event("osd", "enqueue")
     assert tr.recent() == []
 
 
@@ -35,13 +34,26 @@ def test_trace_id_of_is_deterministic_correlator():
     assert trace_id_of("x") & 1  # never zero
 
 
-def test_tracepoint_events_and_ring_bound():
-    tr = Tracer("t", ring_size=16)
-    for i in range(40):
-        tr.event("osd", "tick", i=i)
-    got = tr.recent(100)
-    assert len(got) == 16  # bounded ring
-    assert got[-1]["name"] == "osd:tick"
+def test_blkin_spans_share_the_recorders_ring_and_clock():
+    """A finished blkin span is one record of the process's one ring,
+    on the recorder's clock, with ids from the recorder (no random
+    pair a span); each Tracer reads back only its own."""
+    from ceph_tpu.core import tracing
+
+    a, b = Tracer("a"), Tracer("b")
+    t0 = tracing.clock()
+    with a.start_span("a.op") as sa:
+        sa.annotate("sent")
+    with b.start_span("b.op", parent=sa.context()):
+        pass
+    t1 = tracing.clock()
+    assert [s["name"] for s in a.recent()] == ["a.op"]
+    assert [s["name"] for s in b.recent()] == ["b.op"]
+    assert b.dump(sa.trace_id)[0]["parent_id"] == f"{sa.span_id:016x}"
+    held, _lost = tracing.recorder().held()
+    mine = [r for r in held if r[tracing.ID] == sa.span_id]
+    assert len(mine) == 1 and t0 <= mine[0][tracing.T0] <= t1
+    assert sa.span_id and sa.trace_id and sa.span_id != sa.trace_id
 
 
 def test_stage_registry_sane():
