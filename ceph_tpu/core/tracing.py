@@ -135,7 +135,15 @@ SPANS: Dict[str, str] = {
     "dev.wait": "dev_wait_ms",            # devwatch.fetch: blocked until the
     #   device is done, fetch included
     # CRUSH sweep
-    "crush.sweep": "",                    # sweep_device's call, ids counted
+    "crush.sweep": "",                    # sweep_device's call; counts ids,
+    #   chunk, numrep, mode (firstn/indep) and the plan it ran: cap, cap2
+    #   (lanes the budgeted and the exact stage can take), budget
+    # the mapper's monotonic totals (mapper.sweep_totals(): counters, not
+    # spans, registered here so that their names are held to one table)
+    "crush.ids": "crush_mid_lanes_per_id",          # ids swept: the divisor
+    #   of both ratios
+    "crush.mid_lanes": "crush_mid_lanes_per_id",    # lanes into stage 2
+    "crush.slow_lanes": "crush_slow_lanes_per_id",  # lanes into stage 3
 }
 
 # a concluded op's timeline, filed by OpTracker.unregister: read by

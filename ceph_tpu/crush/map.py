@@ -248,9 +248,15 @@ class CrushMap:
         num: int = 0,
     ) -> int:
         """Equivalent of CrushWrapper::add_simple_rule
-        (reference: src/crush/CrushWrapper.h:1155): take root, then
-        choose/chooseleaf over the failure domain, then emit."""
-        steps: List[Tuple[int, int, int]] = [(OP_TAKE, root_id, 0)]
+        (reference: src/crush/CrushWrapper.h:1155, CrushWrapper.cc
+        add_simple_rule_at): take root, then choose/chooseleaf over the
+        failure domain, then emit; an indep rule first gives the leaf
+        recursion 5 tries and the choose 100, as upstream's does."""
+        steps: List[Tuple[int, int, int]] = []
+        if mode == "indep":
+            steps += [(OP_SET_CHOOSELEAF_TRIES, 5, 0),
+                      (OP_SET_CHOOSE_TRIES, 100, 0)]
+        steps.append((OP_TAKE, root_id, 0))
         op = (
             OP_CHOOSELEAF_FIRSTN if mode == "firstn" else OP_CHOOSELEAF_INDEP
         )
@@ -330,6 +336,38 @@ class CrushMap:
         )
 
 
+def build_layered_cluster(
+    n_osds: int,
+    layers: Sequence[Tuple[int, int]],
+    osd_weight: int = 0x10000,
+) -> Tuple[CrushMap, List[List[int]]]:
+    """`crushtool --build --num_osds N <name> straw2 <size> ...`
+    (reference: src/tools/crushtool.cc:112-218, the `--build` loop):
+    ``layers`` lists (type id, size) from the devices upward.  Each
+    layer groups the items of the one below, in order, into straw2
+    buckets of ``size`` items (the last may be short; size 0 puts all
+    of them into one bucket); a bucket's weight as an item is the sum
+    of its own items'.  Bucket ids are handed out as crushtool does:
+    -1, -2, ... in order of creation, lowest layer first.  Returns
+    (map, bucket ids of each layer); the root is ``ids[-1][0]`` when
+    the last layer's size is 0."""
+    m = CrushMap()
+    lower = list(range(n_osds))
+    lower_w = [osd_weight] * n_osds
+    ids: List[List[int]] = []
+    for type_id, size in layers:
+        size = size or len(lower)
+        made, made_w = [], []
+        for lo in range(0, len(lower), size):
+            ws = lower_w[lo: lo + size]
+            made.append(m.add_bucket(
+                ALG_STRAW2, type_id, lower[lo: lo + size], ws))
+            made_w.append(sum(ws))
+        ids.append(made)
+        lower, lower_w = made, made_w
+    return m, ids
+
+
 def build_flat_cluster(
     n_osds: int,
     osd_weight: int = 0x10000,
@@ -339,26 +377,10 @@ def build_flat_cluster(
 ) -> Tuple[CrushMap, int]:
     """Convenience builder: root straw2 bucket over osds (or over
     ``hosts`` straw2 host buckets of n_osds/hosts osds each).  Returns
-    (map, root_id).  The shape crushtool --build produces for benches
-    (reference: src/tools/crushtool.cc:112-218)."""
-    m = CrushMap()
-    if hosts:
-        per = n_osds // hosts
-        host_ids = []
-        for h in range(hosts):
-            osds = list(range(h * per, (h + 1) * per))
-            hid = m.add_bucket(
-                ALG_STRAW2, host_type, osds, [osd_weight] * per
-            )
-            host_ids.append(hid)
-        root = m.add_bucket(
-            ALG_STRAW2,
-            10,
-            host_ids,
-            [osd_weight * per] * hosts,
-        )
-    else:
-        root = m.add_bucket(
-            ALG_STRAW2, 10, list(range(n_osds)), [osd_weight] * n_osds
-        )
-    return m, root
+    (map, root_id).  The one- and two-layer case of
+    build_layered_cluster, with the root at type 10."""
+    layers = [(host_type, n_osds // hosts)] if hosts else []
+    m, ids = build_layered_cluster(
+        n_osds if not hosts else hosts * (n_osds // hosts),
+        layers + [(10, 0)], osd_weight)
+    return m, ids[-1][0]

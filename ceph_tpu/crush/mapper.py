@@ -31,7 +31,9 @@ tests/test_crush_vs_reference.py):
   prefix, reweight rejection via is_out, chooseleaf recursion with
   vary_r / stable.
 - indep: breadth-first rounds r' = rep + n*ftotal, positionally stable,
-  CRUSH_ITEM_NONE holes.
+  CRUSH_ITEM_NONE holes.  A round's descents do not read what the
+  round has placed, so they run as one block over a vector of slots and
+  only the accept logic stays in slot order (_choose_indep).
 - Supported bucket algs in the jit path: straw2 (the modern default).
   uniform/list/tree/straw maps fall back to the native oracle.
 
@@ -51,7 +53,8 @@ global flip advisory), and no 64-bit ops for XLA to emulate.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Sequence, Tuple
+import math
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +89,21 @@ def dataclasses_replace_weights(flat: FlatMap, weights: np.ndarray):
     return dataclasses.replace(flat, weights=weights)
 
 
+def _choose_arg_weights(flat: FlatMap, choose_args) -> np.ndarray:
+    """The map's bucket weights with a weight set laid over them."""
+    base_w = np.asarray(flat.weights).copy()
+    if choose_args:
+        algs_np = np.asarray(flat.algs)
+        for bid, ws in choose_args.items():
+            bno = -1 - bid
+            # the reference consults the weight set in straw2
+            # buckets only (bucket_straw2_choose's arg)
+            if (0 <= bno < base_w.shape[0]
+                    and algs_np[bno] == ALG_STRAW2):
+                base_w[bno, : len(ws)] = ws
+    return base_w
+
+
 # descend status codes
 _OK = 0
 _REJECT = 1  # empty bucket mid-descent: retry with higher ftotal
@@ -97,7 +115,8 @@ _MAX_DRAW_TABS = 64
 
 # mid-stage retry budget for the staged sweeps: real retry semantics
 # statically unrolled this many attempts (resolves ~97% of stage-1
-# unclean lanes; the rest hit the exact full program)
+# unclean lanes; the rest hit the exact full program).  The least a
+# sweep plan gives; a wide indep choose gets up to MAX_BUDGET rounds.
 MID_BUDGET = 3
 
 
@@ -113,17 +132,8 @@ class _DeviceMap:
         # choose_args ({bucket_id: [weights]}, reference
         # CrushWrapper.h:72 / crush_choose_arg) substitute the straw2
         # draw weights — balancer weight-set overrides
-        base_w = np.asarray(flat.weights).copy()
-        if choose_args:
-            algs_np = np.asarray(flat.algs)
-            for bid, ws in choose_args.items():
-                bno = -1 - bid
-                # the reference consults the weight set in straw2
-                # buckets only (bucket_straw2_choose's arg)
-                if (0 <= bno < base_w.shape[0]
-                        and algs_np[bno] == ALG_STRAW2):
-                    base_w[bno, : len(ws)] = ws
-        flat = dataclasses_replace_weights(flat, base_w)
+        flat = dataclasses_replace_weights(
+            flat, _choose_arg_weights(flat, choose_args))
         # magic reciprocals for the straw2 divide: weights are map
         # constants, so the exact truncating s64 division ln/w becomes
         # a 16-bit-limb mulhi + one correction, all in uint32 (TPU has
@@ -467,6 +477,78 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
     return items[jnp.argmax(sel)], no_ambig
 
 
+def _straw2_choose_slots(dm: _DeviceMap, bno, x, r, width=None,
+                         delta: int = 0):
+    """_straw2_choose for a vector of (bucket, r) pairs at once (an
+    indep round's slots): bno, r [S] -> (items [S], ambig [S]), each
+    entry what _straw2_choose gives for its pair.
+
+    One block of array code for all the slots of a round, where a
+    Python loop over twelve slots made twelve copies of the descent in
+    the program (and a compile of many minutes).  The hash runs over
+    one flat [S * width] axis; on the chip that measured the same as a
+    vmapped slot axis (PERF.md, PR 30: what a level costs there is its
+    table gathers, not its hash).  Draw-table and
+    fastcmp paths only; _bucket_choose maps the rest slot by slot."""
+    width = width or dm.max_size
+    items = dm.items[:, :width][bno]            # [S, width]
+    wts = dm.weights[:, :width][bno]
+    u = (hashes.hash32_3(
+        x.astype(jnp.uint32), items.reshape(-1).astype(jnp.uint32),
+        jnp.repeat(r.astype(jnp.uint32), width), xp=jnp,
+    ) & _U16).reshape(items.shape)
+    # flatten() pads a bucket's row with weight 0, so the weight alone
+    # says which entries are items that can win (no lookup of its size)
+    valid = wts > 0
+    rows = jnp.arange(items.shape[0])
+    if delta:
+        uv = jnp.where(valid, u.astype(jnp.int32), jnp.int32(-1))
+        u1 = jnp.max(uv, axis=-1)
+        sel1 = uv == u1[:, None]
+        i1 = jnp.argmax(sel1, axis=-1).astype(jnp.int32)
+        sel2 = (~sel1) & (uv >= 0)
+        u2 = jnp.max(jnp.where(sel2, uv, jnp.int32(-1)), axis=-1)
+        close2 = (u2 >= 0) & (u1 - u2 <= delta)
+        if not dm.table_mode:
+            return items[rows, i1], close2
+        # the runner-up is within delta in one slot of two thousand,
+        # and the four draw-table gathers of this comparison were 62 %
+        # of a one-shot pass when every slot made them (PERF.md, PR 30):
+        # only the first _CLOSE_SLOTS contested slots get their two true
+        # draws compared, a further one is left ambiguous
+        at, have = _first_true(close2, min(_CLOSE_SLOTS, items.shape[0]))
+        uva, u1a, u2a = uv[at], u1[at], u2[at]
+        i1a = i1[at]
+        i2a = jnp.argmax(
+            (uva != u1a[:, None]) & (uva == u2a[:, None]),
+            axis=-1).astype(jnp.int32)
+        wi = dm.w_idx[bno[at], jnp.minimum(i1a, width - 1)]
+        u2c = jnp.clip(u2a, 0, 0xFFFF)
+        q1h, q1l = dm.draw_hi[wi, u1a], dm.draw_lo[wi, u1a]
+        q2h, q2l = dm.draw_hi[wi, u2c], dm.draw_lo[wi, u2c]
+        two_wins = (q2h < q1h) | ((q2h == q1h) & (q2l < q1l))
+        q_tie = (q2h == q1h) & (q2l == q1l)
+        resolved = jnp.where(
+            q_tie, jnp.minimum(i1a, i2a), jnp.where(two_wins, i2a, i1a))
+        idx = i1.at[jnp.where(have, at, items.shape[0])].set(
+            resolved, mode="drop")
+        unresolved = close2 & (
+            jnp.cumsum(close2.astype(jnp.int32)) > at.shape[0])
+        u3 = jnp.max(jnp.where(sel2 & (uv != u2[:, None]), uv,
+                               jnp.int32(-1)), axis=-1)
+        return items[rows, idx], (
+            ((u3 >= 0) & (u1 - u3 <= delta)) | unresolved)
+    ui = u.astype(jnp.int32)
+    wi = dm.w_idx[:, :width][bno]
+    q_hi = jnp.where(valid, dm.draw_hi[wi, ui], _UMAX)
+    q_lo = jnp.where(valid, dm.draw_lo[wi, ui], _UMAX)
+    cand = q_hi == jnp.min(q_hi, axis=-1)[:, None]
+    min_lo = jnp.min(jnp.where(cand, q_lo, _UMAX), axis=-1)
+    sel = cand & (q_lo == min_lo[:, None])
+    return (items[rows, jnp.argmax(sel, axis=-1)],
+            jnp.zeros(items.shape[:1], jnp.bool_))
+
+
 def _umulhi32(a, b):
     """(u32 * u32) >> 32 exactly, via 16-bit limbs (no 64-bit ops)."""
     mask = _U16
@@ -576,10 +658,18 @@ def _bucket_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
     """Per-alg dispatch; straw2-only maps trace straight through the
     straw2 path with zero overhead.  `width` / `delta` are the static
     per-level bounds from the descent plan (straw2 only; the legacy
-    algs are rare enough to always run at full width).  Returns
+    algs are rare enough to always run at full width).  `bno` and `r`
+    may be vectors of one length (an indep round's slots, see
+    _straw2_choose_slots), the result is then a vector too.  Returns
     (item, ambig); delta > 0 implies the plan proved every reachable
     bucket at this level is straw2, so the legacy overrides below are
     per-lane no-ops then."""
+    if jnp.ndim(bno):
+        # a vector of slots (an indep round)
+        if dm.only_straw2 and (delta or dm.table_mode):
+            return _straw2_choose_slots(dm, bno, x, r, width, delta)
+        return jax.vmap(
+            lambda b, rr: _bucket_choose(dm, b, x, rr, width, delta))(bno, r)
     if dm.only_straw2:
         return _straw2_choose(dm, bno, x, r, width, delta)
     out, ambig = _straw2_choose(dm, bno, x, r, width, delta)
@@ -637,6 +727,10 @@ def _descend(
         if indep_numrep is None:
             return r_base
         numrep = indep_numrep
+        if ALG_UNIFORM not in dm.algs_present:
+            # no bucket to look up: a gather from even a tiny table
+            # costs the chip as much as hashing a bucket's items
+            return r_base + numrep * ftotal
         uniform = (dm.algs[bno] == ALG_UNIFORM) & (
             dm.sizes[bno] % jnp.maximum(numrep, 1) == 0
         )
@@ -941,40 +1035,67 @@ def _choose_firstn(
     return values, outpos, ambig_all
 
 
-def _leaf_indep(dm, dev_weights, bucket_item, x, numrep, parent_r,
-                recurse_tries: int, plan=None, unroll: int = 0):
-    """Recursive indep leaf choice: one slot, r' = parent_r + n*ftotal."""
-    bno = -1 - bucket_item
+def _leaf_indep_try(dm, dev_weights, bno, x, numrep, parent_r, ftotal,
+                    plan=None):
+    """One attempt of the recursive indep leaf choice under bucket
+    `bno`: r' = parent_r + numrep * ftotal.  bno, parent_r and ftotal
+    may be vectors (a round's slots).  Returns (device or ITEM_UNDEF,
+    ambig)."""
+    item, status, amb = _descend(
+        dm, bno, x, parent_r, 0,
+        indep_numrep=jnp.int32(numrep), ftotal=ftotal, plan=plan,
+    )
+    bad = status != _OK
+    outed = _is_out(dev_weights, dm.max_devices, item, x)
+    return jnp.where(bad | outed, ITEM_UNDEF, item), amb
 
-    def attempt(ftotal):
-        item, status, amb = _descend(
-            dm, bno, x, parent_r, 0,
-            indep_numrep=jnp.int32(numrep), ftotal=ftotal, plan=plan,
-        )
-        bad = status != _OK
-        outed = _is_out(dev_weights, dm.max_devices, item, x)
-        return jnp.where(bad | outed, ITEM_UNDEF, item), amb
+
+def _leaf_indep(dm, dev_weights, bno, x, numrep, parent_r,
+                recurse_tries: int, plan=None):
+    """Recursive indep leaf choice for a vector of slots: all
+    `recurse_tries` attempts in a rolled loop, the first device that
+    is in wins.  Returns (device or ITEM_UNDEF, ambig), vectors."""
 
     def body(ftotal, c):
         got, amb0 = c
-        nxt, amb = attempt(jnp.int32(ftotal))
+        nxt, amb = _leaf_indep_try(dm, dev_weights, bno, x, numrep,
+                                   parent_r, jnp.int32(ftotal), plan)
         return (jnp.where(got == ITEM_UNDEF, nxt, got),
                 amb0 | (amb & (got == ITEM_UNDEF)))
 
-    init = (jnp.int32(ITEM_UNDEF), jnp.asarray(False))
+    init = (jnp.full(jnp.shape(bno), ITEM_UNDEF, dtype=jnp.int32),
+            jnp.zeros(jnp.shape(bno), jnp.bool_))
     if recurse_tries == 1:
-        got, ambig = attempt(jnp.int32(0))
-    elif unroll:
-        c = init
-        for f in range(min(unroll, recurse_tries)):
-            c = body(f, c)
-        got, ambig = c
-        # budget < the exact program's tries and still unresolved:
-        # the exact result could differ — poison the lane
-        ambig = ambig | ((got == ITEM_UNDEF) & (unroll < recurse_tries))
-    else:
-        got, ambig = jax.lax.fori_loop(0, recurse_tries, body, init)
-    return jnp.where(got == ITEM_UNDEF, ITEM_NONE, got), ambig
+        return body(0, init)
+    return jax.lax.fori_loop(0, recurse_tries, body, init)
+
+
+def _leaf_indep_rest(dm, dev_weights, bno, x, numrep, parent_r,
+                     recurse_tries: int, plan=None):
+    """Attempts 1 .. recurse_tries-1 of a few slots (vectors bno,
+    parent_r) as ONE block of slots x tries: an attempt reads nothing
+    of another's, and the first device that is in wins."""
+    n, more = bno.shape[0], recurse_tries - 1
+    got, amb = _leaf_indep_try(
+        dm, dev_weights, jnp.repeat(bno, more), x, numrep,
+        jnp.repeat(parent_r, more),
+        jnp.tile(jnp.arange(1, recurse_tries, dtype=jnp.int32), n), plan)
+    got, amb = got.reshape(n, more), amb.reshape(n, more)
+    hit = got != ITEM_UNDEF
+    first = jnp.where(jnp.any(hit, axis=1), jnp.argmax(hit, axis=1), more)
+    val = jnp.where(first < more,
+                    got[jnp.arange(n), jnp.minimum(first, more - 1)],
+                    ITEM_UNDEF)
+    tried = jnp.arange(more) <= first[:, None]
+    return val, jnp.any(amb & tried, axis=1)
+
+
+def _first_true(mask, count: int):
+    """Positions of the first `count` set entries of a 1-d mask, in
+    order, and which of the `count` exist."""
+    rank = jnp.cumsum(mask.astype(jnp.int32)) - 1
+    hit = mask[None, :] & (rank[None, :] == jnp.arange(count)[:, None])
+    return jnp.argmax(hit, axis=1).astype(jnp.int32), jnp.any(hit, axis=1)
 
 
 def _choose_indep(
@@ -990,85 +1111,131 @@ def _choose_indep(
     recurse_to_leaf: bool,
     plan=None,
     leaf_plan=None,
-    unroll: int = 0,
+    rounds=None,
 ):
     """crush_choose_indep for one source bucket (positional, out_size
     slots).  Returns (values[left0], nslots, ambig) with
-    CRUSH_ITEM_NONE holes.  unroll bounds the retry rounds statically
-    (see _choose_firstn): unfilled slots after the budget leave NONE
-    holes, which the bounded-budget caller treats as unclean."""
+    CRUSH_ITEM_NONE holes.
+
+    A slot's descent in a round depends on (x, slot, ftotal) alone, and
+    so does its leaf recursion: only the collision check reads what the
+    round has placed so far.  So a round runs its descents as ONE
+    block over a vector of slots (_descend and _straw2_choose_slots
+    take vectors) and keeps just the accept logic in slot order (a few
+    selects a slot): the program holds one descent a round, not one a
+    slot, which is what lets a 12-slot rule compile.
+
+    rounds=None is the exact program: a while_loop of rounds over every
+    slot, vacant ones accepted, until all are filled or `tries` is
+    spent; the leaf recursion is a rolled loop of `recurse_tries`.
+
+    rounds=((slots, leaf_retries), ...) is the budgeted program (the
+    sweep's one-shot and mid stages): len(rounds) rounds, unrolled.
+    Round 0 runs every slot.  Round k > 0 descends only for the first
+    `slots` slots that are still vacant (after round 0 a lane has one
+    or two), and in every round only the first `leaf_retries` slots
+    whose first leaf attempt failed get the remaining
+    `recurse_tries - 1`.  A lane that needs more than its round
+    provides is marked ambig; a lane left with a vacant slot has NONE
+    there: both are unclean to the caller and go to the exact program.
+    Every other lane followed the exact program's attempts one for one.
+    """
     nslots = left0
+    slot_ids = jnp.arange(nslots, dtype=jnp.int32)
     out = jnp.full((nslots,), ITEM_UNDEF, dtype=jnp.int32)
     out2 = jnp.full((nslots,), ITEM_UNDEF, dtype=jnp.int32)
+    nrep = jnp.int32(numrep)
 
-    def round_body(c):
-        ftotal, out, out2, left, ambig = c
-        for rep in range(nslots):
-            # compute the slot unconditionally (under vmap a cond is a
-            # select anyway) and mask the update on slot-vacancy
-            vacant = out[rep] == ITEM_UNDEF
-            item, status, amb = _descend(
-                dm, bucket_bno, x, jnp.int32(rep), want_type,
-                indep_numrep=jnp.int32(numrep), ftotal=ftotal, plan=plan,
-            )
-            collide = jnp.any(out == item)
+    def one_round(ftotal, out, out2, ambig, reps, live, leaf_retries):
+        """`reps` [S]: the slots this round descends for, in slot
+        order; `live` [S]: which of them are real.  leaf_retries=None:
+        every slot's leaf recursion runs all its tries."""
+        items, statuses, ambs = _descend(
+            dm, jnp.broadcast_to(bucket_bno, reps.shape), x, reps,
+            want_type, indep_numrep=nrep, ftotal=ftotal, plan=plan)
+        poison = jnp.asarray(False)
+        if recurse_to_leaf:
+            is_bucket = items < 0
+            sub = -1 - jnp.minimum(items, -1)
+            # the recursion's r is rep + parent_r, parent_r being the r'
+            # at which the bucket was chosen (straw2-only => the
+            # per-level multiplier is always numrep)
+            leaf_rs = reps + reps + nrep * ftotal
+            if leaf_retries is None:
+                leaves, leaf_ambs = _leaf_indep(
+                    dm, dev_weights, sub, x, numrep, leaf_rs,
+                    recurse_tries, leaf_plan)
+            else:
+                leaves, leaf_ambs = _leaf_indep_try(
+                    dm, dev_weights, sub, x, numrep, leaf_rs,
+                    jnp.int32(0), leaf_plan)
+                need = (live & is_bucket & (statuses == _OK)
+                        & (leaves == ITEM_UNDEF))
+                if recurse_tries > 1:
+                    if leaf_retries:
+                        at, have = _first_true(need, leaf_retries)
+                        more, more_amb = _leaf_indep_rest(
+                            dm, dev_weights, sub[at], x, numrep,
+                            leaf_rs[at], recurse_tries, leaf_plan)
+                        at = jnp.where(have, at, reps.shape[0])
+                        leaves = leaves.at[at].set(more, mode="drop")
+                        leaf_ambs = leaf_ambs | jnp.zeros_like(
+                            leaf_ambs).at[at].set(more_amb, mode="drop")
+                    poison = jnp.sum(need) > leaf_retries
+            leaves = jnp.where(leaves == ITEM_UNDEF, ITEM_NONE, leaves)
+            ambs = ambs | (leaf_ambs & is_bucket)
+        if want_type == 0:
+            outed = (statuses == _OK) & _is_out(
+                dev_weights, dm.max_devices, items, x)
+        for k in range(reps.shape[0]):
+            rep, item, status = reps[k], items[k], statuses[k]
+            mine = slot_ids == rep
+            vacant = live[k] & jnp.any(mine & (out == ITEM_UNDEF))
             hard_fail = status == _SKIP
-            soft_fail = (status == _REJECT) | collide
+            soft_fail = (status == _REJECT) | jnp.any(out == item)
             leaf = item
             if recurse_to_leaf:
-                is_bucket = item < 0
-                # the recursion's slot r is rep + parent_r where
-                # parent_r is the r at which this bucket was chosen
-                # (straw2-only => the per-level multiplier is always
-                # numrep, so r_parent is the top-level r')
-                r_parent = jnp.int32(rep) + jnp.int32(numrep) * ftotal
-                leaf_val, leaf_amb = _leaf_indep(
-                    dm, dev_weights, jnp.minimum(item, -1), x,
-                    numrep, jnp.int32(rep) + r_parent, recurse_tries,
-                    leaf_plan, unroll,
-                )
-                leaf = jnp.where(is_bucket, leaf_val, item)
-                amb = amb | (leaf_amb & is_bucket)
+                leaf = jnp.where(is_bucket[k], leaves[k], item)
                 soft_fail = soft_fail | (
-                    is_bucket & (leaf == ITEM_NONE) & (status == _OK)
-                )
-            outed = jnp.where(
-                want_type == 0,
-                (status == _OK)
-                & _is_out(dev_weights, dm.max_devices, item, x),
-                False,
-            )
-            soft_fail = soft_fail | outed
+                    is_bucket[k] & (leaf == ITEM_NONE) & (status == _OK))
+            if want_type == 0:
+                soft_fail = soft_fail | outed[k]
             ok = (status == _OK) & (~soft_fail) & (~hard_fail)
-            new_item = jnp.where(
-                hard_fail, ITEM_NONE, jnp.where(ok, item, ITEM_UNDEF)
-            )
-            new_leaf = jnp.where(
-                hard_fail, ITEM_NONE, jnp.where(ok, leaf, ITEM_UNDEF)
-            )
-            placed = (ok | hard_fail) & vacant
-            out = jnp.where(placed, out.at[rep].set(new_item), out)
-            out2 = jnp.where(placed, out2.at[rep].set(new_leaf), out2)
-            left = left - placed.astype(jnp.int32)
-            ambig = ambig | (amb & vacant)
-        return ftotal + 1, out, out2, left, ambig
+            put = mine & (ok | hard_fail) & vacant
+            out = jnp.where(
+                put, jnp.where(hard_fail, ITEM_NONE, item), out)
+            out2 = jnp.where(
+                put, jnp.where(hard_fail, ITEM_NONE, leaf), out2)
+            ambig = ambig | (ambs[k] & vacant)
+        return out, out2, ambig | poison
 
-    def round_cond(c):
-        ftotal, _, _, left, _ = c
-        return (left > 0) & (ftotal < tries)
+    if rounds is None:
+        def round_body(c):
+            ftotal, out, out2, ambig = c
+            out, out2, ambig = one_round(
+                ftotal, out, out2, ambig, slot_ids,
+                jnp.ones((nslots,), jnp.bool_), None)
+            return ftotal + 1, out, out2, ambig
 
-    init = (jnp.int32(0), out, out2, jnp.int32(nslots), jnp.asarray(False))
-    if unroll:
-        c = init
-        for _ in range(min(unroll, tries)):
-            active = round_cond(c)
-            cn = round_body(c)
-            c = jax.tree.map(
-                lambda new, old: jnp.where(active, new, old), cn, c)
-        _, out, out2, _, ambig = c
+        def round_cond(c):
+            ftotal, out, _, _ = c
+            return jnp.any(out == ITEM_UNDEF) & (ftotal < tries)
+
+        _, out, out2, ambig = jax.lax.while_loop(
+            round_cond, round_body,
+            (jnp.int32(0), out, out2, jnp.asarray(False)))
     else:
-        _, out, out2, _, ambig = jax.lax.while_loop(
-            round_cond, round_body, init)
+        ambig = jnp.asarray(False)
+        for k, (width, leaf_retries) in enumerate(rounds[:tries]):
+            if k == 0 or width >= nslots:
+                reps, live = slot_ids, out == ITEM_UNDEF
+            else:
+                vacant = out == ITEM_UNDEF
+                reps, live = _first_true(vacant, width)
+                ambig = ambig | (jnp.sum(vacant) > width)
+            out, out2, ambig = one_round(
+                jnp.int32(k), out, out2, ambig, reps, live,
+                min(leaf_retries, reps.shape[0]))
     out = jnp.where(out == ITEM_UNDEF, ITEM_NONE, out)
     out2 = jnp.where(out2 == ITEM_UNDEF, ITEM_NONE, out2)
     return (out2 if recurse_to_leaf else out), jnp.int32(nslots), ambig
@@ -1108,6 +1275,7 @@ def compile_rule(
     choose_args=None,
     one_shot: bool = False,
     budget: Optional[int] = None,
+    rounds=None,
 ):
     """Build fn(xs[int32 N], device_weights[uint32 D]) -> int32 [N, result_max].
 
@@ -1133,7 +1301,10 @@ def compile_rule(
     semantics statically unrolled to N attempts per choose; lanes fully
     placed within the budget are bit-identical to the full program
     (deterministic attempt sequences), the rest stay unclean for the
-    exact full program.
+    exact full program.  For an `indep` choose the N rounds are shaped
+    by `rounds`, ((slots, leaf retries) a round, see _choose_indep and
+    sweep_plan, which is where the sweeps get theirs); without it every
+    round runs every slot with all its leaf tries.
 
     Compiled programs are cached process-wide by map content: rebuilding
     an identical map (common in tests and in OSDMap churn that leaves
@@ -1145,8 +1316,13 @@ def compile_rule(
     # the kill-switch is read at TRACE time (_level_fast_delta), so it
     # must key the compile cache or toggling it mid-process is inert
     no_fc = os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1"
+    if budget_val > 1 and rounds is not None:
+        rounds = tuple((int(a), int(b)) for a, b in rounds)[:budget_val]
+    else:
+        rounds = None
     digest = _rule_digest(flat, steps, result_max, choose_args) + (
         f":budget{budget_val}{':nofc' if no_fc else ''}"
+        f"{':rounds%r' % (rounds,) if rounds else ''}"
         if budget_val else "")
     cached = _compiled_rules.get(digest)
     if cached is not None:
@@ -1266,10 +1442,16 @@ def compile_rule(
                             )
                         step_clean = (cnt == numrep) & (~amb)
                     else:
+                        # budgeted traces unroll their rounds: the
+                        # one-shot pass is round 0 alone, the mid stage
+                        # takes the sweep plan's shape or, without
+                        # one, every slot and leaf try in every round
                         vals, cnt, amb = _choose_indep(
                             dm, dev_weights, bno_safe, x, numrep, numrep,
                             arg2, use_tries, use_recurse, recurse,
-                            plan, leaf_plan, use_unroll,
+                            plan, leaf_plan,
+                            (rounds or ((numrep, numrep),) * budget_val)
+                            if budget_val else None,
                         )
                         step_clean = jnp.all(vals != ITEM_NONE) & (~amb)
                     clean = clean & ((~active) | step_clean)
@@ -1319,6 +1501,266 @@ def compile_rule(
     return run
 
 
+class SweepPlan(NamedTuple):
+    """What the staged sweeps run for one (rule, map, device weights):
+    sweep_plan() reckons it, sweep() and sweep_device() follow it."""
+
+    bad_div: int   # stage-2 capacity is chunk // bad_div lanes; 1: no
+    #                one-shot pass, every lane takes the budgeted stage
+    bad2_div: int  # stage-3 capacity is n // bad2_div lanes (floor 2048)
+    budget: int    # attempts a choose gets in the budgeted stage
+    rounds: Optional[tuple]  # an indep choose's budgeted rounds,
+    #                (slots, leaf retries) each (_choose_indep); None for
+    #                firstn and for rules the model does not cover
+
+    @property
+    def fast(self) -> bool:
+        """Stage 1 is the one-shot pass over every lane."""
+        return self.bad_div > 1
+
+
+# today's plan for a healthy replicated map, and the floor of every plan
+DEFAULT_PLAN = SweepPlan(8, 2048, MID_BUDGET, None)
+MAX_BUDGET = 6
+# lanes the exact stage takes at a time where its capacity is larger
+_SLOW_BATCH = 1 << 13
+# slots of one vectorised straw2 level whose contested draw is resolved
+# from the draw tables (_straw2_choose_slots)
+_CLOSE_SLOTS = 1
+# share of lanes a budgeted round may lose to its static widths
+_ROUND_TAIL = 2.0 ** -11
+# the budgeted stage stops adding rounds once this share is left vacant
+_RESIDUE_AIM = 2.0 ** -8
+
+_plans: dict = {}  # (rule digest, device weights) -> SweepPlan
+
+
+def _poisson_tail(mean: float, k: int) -> float:
+    """P(X > k) for a Poisson X: an upper bound for a sum of unlike
+    coin flips of the same mean, which is what a lane's count of vacant
+    slots is."""
+    term = total = math.exp(-mean)
+    for i in range(1, k + 1):
+        term *= mean / i
+        total += term
+    return max(0.0, 1.0 - total)
+
+
+def _least_width(mean: float, tail: float, most: int, least: int) -> int:
+    k = least
+    while k < most and _poisson_tail(mean, k) > tail:
+        k += 1
+    return k
+
+
+def _pow2_div(share: float, floor_div: int) -> int:
+    """Largest power-of-two divisor d <= floor_div with 1/d >= share."""
+    d = floor_div
+    while d > 1 and 1.0 / d < share:
+        d //= 2
+    return d
+
+
+def _retry_model(flat: FlatMap, steps, result_max: int, dev_weights,
+                 choose_args=None):
+    """How often a pick of this rule fails on this map with these
+    device weights, read off the map on the host: (indep, numrep,
+    s2, q, a) or None for a rule that is not `take; one choose; emit`
+    over straw2 buckets.  s2 is the chance that two picks meet (the sum
+    of the squared shares of the items the choose draws from), q the
+    chance that a pick's slot fails for good this round (a device that
+    is out; a chooseleaf's every leaf try), a the chance that a
+    chooseleaf's FIRST leaf try fails."""
+    tun = flat.tunables
+    choose_tries, leaf_tries = tun.choose_total_tries + 1, 0
+    take = choose = None
+    for op, arg1, arg2 in steps:
+        if op == OP_SET_CHOOSE_TRIES and arg1 > 0:
+            choose_tries = arg1
+        elif op == OP_SET_CHOOSELEAF_TRIES and arg1 > 0:
+            leaf_tries = arg1
+        elif op == OP_TAKE and take is None and choose is None:
+            take = arg1
+        elif op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN,
+                    OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP) \
+                and take is not None and choose is None:
+            choose = (op, arg1, arg2)
+        elif op != OP_EMIT or choose is None:
+            return None
+    if choose is None:
+        return None
+    op, arg1, want = choose
+    indep = op in (OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP)
+    recurse = op in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP) and want > 0
+    numrep = min(arg1 if arg1 > 0 else result_max + arg1, result_max)
+    if numrep <= 0:
+        return None
+    if indep:
+        leaf_tries = leaf_tries or 1
+    else:
+        leaf_tries = leaf_tries or (
+            1 if tun.chooseleaf_descend_once else choose_tries)
+    weights = _choose_arg_weights(flat, choose_args)
+    items, sizes = np.asarray(flat.items), np.asarray(flat.sizes)
+    types, algs = np.asarray(flat.types), np.asarray(flat.algs)
+    dev_w = np.asarray(dev_weights).astype(np.int64)
+    n_buckets = items.shape[0]
+
+    def out_share(dev: int) -> float:
+        w = int(dev_w[dev]) if 0 <= dev < len(dev_w) else 0
+        return 0.0 if w >= 0x10000 else 1.0 - w / 65536.0
+
+    def spread(bno: int, stop_type: int, share: float, into: dict,
+               depth: int = 0) -> bool:
+        """Shares of the items of `stop_type` a descent from `bno`
+        ends at (0: devices), by straw2's weights."""
+        if not (0 <= bno < n_buckets) or algs[bno] != ALG_STRAW2 \
+                or depth > n_buckets:
+            return False
+        ws = weights[bno, :sizes[bno]].astype(np.float64)
+        if ws.sum() <= 0:
+            return True
+        for it, w in zip(items[bno, :sizes[bno]], ws / ws.sum()):
+            it = int(it)
+            if w <= 0:
+                continue
+            if it >= 0:
+                if stop_type == 0:
+                    into[it] = into.get(it, 0.0) + share * w
+            elif stop_type and 0 <= -1 - it < n_buckets \
+                    and types[-1 - it] == stop_type:
+                into[it] = into.get(it, 0.0) + share * w
+            elif not spread(-1 - it, stop_type, share * w, into, depth + 1):
+                return False
+        return True
+
+    picks: dict = {}
+    if not spread(-1 - take, want, 1.0, picks) or not picks:
+        return None
+    s2 = sum(p * p for p in picks.values())
+    q = a = 0.0
+    for it, p in picks.items():
+        if it >= 0:
+            q += p * out_share(it)
+        elif recurse:
+            leaves: dict = {}
+            if not spread(-1 - it, 0, 1.0, leaves):
+                return None
+            first = sum(lp * out_share(d) for d, lp in leaves.items()) \
+                + (1.0 - sum(leaves.values()))
+            a += p * first
+            q += p * first ** leaf_tries
+    return indep, numrep, s2, q, (a if leaf_tries > 1 else 0.0)
+
+
+def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
+               choose_args=None) -> SweepPlan:
+    """The staged sweeps' plan for this rule on this map with these
+    device weights, from a model of the retries and no run: never less
+    than DEFAULT_PLAN, which is what a healthy replicated map gets.
+
+    _retry_model gives the chance s2 that two picks meet and the chance
+    q that a pick fails by itself.  The expected number of picks of a
+    lane that fail bounds the share of lanes with a failure, so:
+    stage-2 capacity is twice the share expected unclean after one
+    attempt each, as a power-of-two part of the chunk, and a rule that
+    would send more than half of the lanes there skips the one-shot
+    pass and runs the budgeted program over every lane; stage-3
+    capacity is twice the share expected to outlast the budget.  An
+    indep choose retries by rounds, so its budgeted program is shaped
+    round by round: as many slots as all but 2^-11 of the lanes have
+    vacant by then (a Poisson bound on the count), as many leaf
+    retries likewise, and rounds until 2^-8 of the lanes are expected
+    to have a vacancy, 3 to 6 of them.  The plan is memoised by rule,
+    map and weights; a sweep that still overflows says so."""
+    dev_w = np.ascontiguousarray(np.asarray(dev_weights), dtype=np.uint32)
+    key = (_rule_digest(flat, steps, result_max, choose_args),
+           dev_w.tobytes())
+    plan = _plans.get(key)
+    if plan is not None:
+        return plan
+    model = _retry_model(flat, steps, result_max, dev_w, choose_args)
+    plan = DEFAULT_PLAN
+    if model is not None:
+        indep, n, s2, q, a = model
+        rounds = None
+        budget = MID_BUDGET
+        if indep:
+            placed = 0.0
+            for _ in range(n):
+                placed += 1.0 - min(1.0, q + placed * s2)
+            vacant = n - placed
+            # a lane is unclean after one attempt if a slot is vacant
+            # or a first leaf try failed
+            first = min(1.0, vacant + n * a)
+            again = min(1.0, q + (n - 1) * s2)
+            tail = _ROUND_TAIL * first
+            rounds = [(n, _least_width(n * a, tail, n, 0))]
+            lost = tail
+            while len(rounds) < MAX_BUDGET and (
+                    len(rounds) < MID_BUDGET or vacant > _RESIDUE_AIM):
+                width = _least_width(vacant, tail, n, 1)
+                rounds.append((width, _least_width(
+                    min(vacant, width) * a, tail, width, 0)))
+                lost += 2 * tail
+                vacant *= again
+            budget, rounds = len(rounds), tuple(rounds)
+            left = min(1.0, vacant + lost)
+        else:
+            fails = [min(1.0, q + i * s2) for i in range(n)]
+            first = min(1.0, sum(fails))
+            left = min(1.0, sum(f ** MID_BUDGET for f in fails))
+        plan = SweepPlan(_pow2_div(2 * first, DEFAULT_PLAN.bad_div),
+                         _pow2_div(2 * left, DEFAULT_PLAN.bad2_div),
+                         budget, rounds)
+    _plans[key] = plan
+    if len(_plans) > 256:
+        _plans.pop(next(iter(_plans)))
+    return plan
+
+
+# monotonic totals of the staged sweeps: ids swept, lanes that entered
+# the budgeted stage, lanes that entered the exact stage.  sweep_device
+# leaves its two lane counts on the device and files them here unread.
+_totals = {"crush.ids": 0, "crush.mid_lanes": 0, "crush.slow_lanes": 0}
+_unread: list = []   # (mid lanes, slow lanes) device scalars, a sweep each
+
+
+def sweep_totals() -> dict:
+    """{"crush.ids", "crush.mid_lanes", "crush.slow_lanes"} over every
+    sweep() and sweep_device() of the process so far.  Reading fetches
+    the device scalars filed since the last reading (it waits for the
+    sweeps that made them); a sweep itself never does."""
+    while _unread:
+        mid, slow = _unread.pop()
+        _totals["crush.mid_lanes"] += int(mid)
+        _totals["crush.slow_lanes"] += int(slow)
+    return dict(_totals)
+
+
+def _rule_shape(steps, result_max: int):
+    """("firstn" | "indep", numrep) of the rule's first choose."""
+    for op, arg1, _ in steps:
+        if op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN,
+                  OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP):
+            indep = op in (OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP)
+            return ("indep" if indep else "firstn",
+                    min(arg1 if arg1 > 0 else result_max + arg1, result_max))
+    return "", 0
+
+
+def _stage_programs(flat, steps, result_max, choose_args, plan: SweepPlan,
+                    fast: bool):
+    """The three stage programs of a plan: (one-shot or None, budgeted,
+    exact)."""
+    fast = compile_rule(flat, steps, result_max, choose_args,
+                        one_shot=True) if fast else None
+    mid = compile_rule(flat, steps, result_max, choose_args,
+                       one_shot=True, budget=plan.budget,
+                       rounds=plan.rounds)
+    return fast, mid, compile_rule(flat, steps, result_max, choose_args)
+
+
 def sweep(
     flat: FlatMap,
     steps: Sequence[Tuple[int, int, int]],
@@ -1342,6 +1784,13 @@ def sweep(
        full-retry program, padded to a power-of-two batch so the slow
        program compiles for O(log) distinct shapes.
 
+    Which stages run, and the budgeted stage's budget and shape, come
+    from sweep_plan(): a rule whose one-shot pass would leave most
+    lanes unclean (a wide indep choose, a map with much of it out)
+    drops stage 1 and runs the budgeted program over every lane.  The
+    capacities of the plan do not bind here: the fix-up batches are cut
+    on the host to what each chunk needs.
+
     Chunked so live device temps stay bounded at 10M+ ids.  Bit-exact
     with running the full program on everything: a clean lane's result
     is identical by construction (retries only fire on failure, and
@@ -1352,11 +1801,9 @@ def sweep(
     n = len(xs)
     if n == 0:
         return np.empty((0, result_max), dtype=np.int32)
-    fast = compile_rule(flat, steps, result_max, choose_args,
-                        one_shot=True)
-    mid = compile_rule(flat, steps, result_max, choose_args,
-                       one_shot=True, budget=MID_BUDGET)
-    slow = compile_rule(flat, steps, result_max, choose_args)
+    plan = sweep_plan(flat, steps, result_max, dev_weights, choose_args)
+    fast, mid, slow = _stage_programs(
+        flat, steps, result_max, choose_args, plan, plan.fast)
     chunk = min(chunk, n)
     outs = []
     # power-of-two padding bounds fixup shapes to O(log chunk); the
@@ -1371,17 +1818,24 @@ def sweep(
         if len(sub) < chunk:  # uniform shape: ONE compiled fast program
             sub = np.concatenate(
                 [sub, np.full(chunk - len(sub), sub[-1], np.int32)])
-        res, clean = fast(sub, dev_weights)
-        res = np.array(res)  # writable host copy
-        bad = np.nonzero(~np.asarray(clean))[0]
+        if fast is None:
+            res, bad = None, np.arange(chunk)
+        else:
+            res, clean = fast(sub, dev_weights)
+            res = np.array(res)  # writable host copy
+            bad = np.nonzero(~np.asarray(clean))[0]
         if bad.size:
             n_pad = shapebucket.covering(int(bad.size))
             n_pad = hw_mid = max(n_pad, hw_mid)
             padded = np.full(n_pad, sub[bad[0]], dtype=np.int32)
             padded[: bad.size] = sub[bad]
             res2, clean2 = mid(padded, dev_weights)
-            res[bad] = np.asarray(res2)[: bad.size]
+            if res is None:
+                res = np.array(res2)[:chunk]
+            else:
+                res[bad] = np.asarray(res2)[: bad.size]
             bad2 = np.nonzero(~np.asarray(clean2)[: bad.size])[0]
+            _totals["crush.mid_lanes"] += int(bad.size)
             if bad2.size:
                 n_pad2 = shapebucket.covering(int(bad2.size))
                 n_pad2 = hw_slow = max(n_pad2, hw_slow)
@@ -1389,8 +1843,98 @@ def sweep(
                 padded2[: bad2.size] = padded[bad2]
                 fixed = np.asarray(slow(padded2, dev_weights))
                 res[bad[bad2]] = fixed[: bad2.size]
+                _totals["crush.slow_lanes"] += int(bad2.size)
         outs.append(res[: len(xs) - off])
+    _totals["crush.ids"] += n
     return np.concatenate(outs) if len(outs) > 1 else outs[0]
+
+
+def _device_runner(flat, steps, result_max, choose_args, n: int,
+                   chunk: int, cap: int, cap2: int, plan: SweepPlan,
+                   with_fast: bool):
+    """sweep_device's one jitted program for n ids in chunks of `chunk`
+    with fix-up capacities `cap` and `cap2`, cached process-wide (like
+    compile_rule): a fresh jax.jit wrapper per call would re-trace and
+    re-compile on EVERY call, so repeated sweeps would time XLA, not
+    the sweep.  run(xs, w) -> (placements, overflow, lanes that entered
+    stage 2, lanes that entered stage 3)."""
+    import os
+
+    key = (_rule_digest(flat, steps, result_max, choose_args),
+           "sweep_device", n, chunk, cap, cap2,
+           os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1",
+           with_fast, plan.budget, plan.rounds)
+    run = _compiled_rules.get(key)
+    if run is None:
+        fast, mid, slow = _stage_programs(
+            flat, steps, result_max, choose_args, plan, with_fast)
+
+        @functools.partial(instrumented_jit, family="crush_mapper")
+        def run(xs2, w):
+            def body(carry, sub):
+                overflow, n_mid = carry
+                if fast is None:
+                    # every lane takes the budgeted program
+                    with jax.named_scope("crush.mid"):
+                        res, clean2 = mid(sub, w)
+                    return (overflow, n_mid + jnp.int32(chunk)), (
+                        res, ~clean2)
+                with jax.named_scope("crush.fast"):
+                    res, clean = fast(sub, w)
+                bad = jnp.nonzero(~clean, size=cap, fill_value=chunk)[0]
+                n_bad = jnp.sum(~clean, dtype=jnp.int32)
+                # padding lanes (index==chunk) clamp to chunk-1 and
+                # recompute sub[chunk-1]; their scatter is dropped
+                bad_xs = sub[jnp.minimum(bad, chunk - 1)]
+                with jax.named_scope("crush.mid"):
+                    res2, clean2 = mid(bad_xs, w)
+                res = res.at[bad].set(res2, mode="drop")
+                # residual mask back in chunk shape (padding dropped);
+                # the exact full-program fixup runs ONCE over the whole
+                # sweep after the scan — its while_loop overhead is per
+                # batch, not per chunk
+                resid = jnp.zeros((chunk,), jnp.bool_).at[bad].set(
+                    ~clean2, mode="drop")
+                return (overflow | (n_bad > cap),
+                        n_mid + jnp.minimum(n_bad, cap)), (res, resid)
+
+            (overflow, n_mid), (out, resids) = jax.lax.scan(
+                body, (jnp.asarray(False), jnp.int32(0)),
+                xs2.reshape(-1, chunk))
+            out = out.reshape(n, result_max)
+            resid_all = resids.reshape(n)
+            n3 = jnp.sum(resid_all, dtype=jnp.int32)
+            n_slow = jnp.minimum(n3, cap2)
+            if cap2 <= 2 * _SLOW_BATCH:
+                b3 = jnp.nonzero(resid_all, size=cap2, fill_value=n)[0]
+                xs3 = xs2[jnp.minimum(b3, n - 1)]
+                with jax.named_scope("crush.slow"):
+                    fixed = slow(xs3, w)
+                out = out.at[b3].set(fixed, mode="drop")
+            else:
+                # a capacity this large is room, not an expectation: the
+                # exact program takes the residue a batch at a time, as
+                # many batches as there is residue
+                b3 = jnp.nonzero(
+                    resid_all, size=-(-cap2 // _SLOW_BATCH) * _SLOW_BATCH,
+                    fill_value=n)[0]
+
+                def fix(i, out):
+                    at = jax.lax.dynamic_slice(
+                        b3, (i * _SLOW_BATCH,), (_SLOW_BATCH,))
+                    with jax.named_scope("crush.slow"):
+                        fixed = slow(xs2[jnp.minimum(at, n - 1)], w)
+                    return out.at[at].set(fixed, mode="drop")
+
+                out = jax.lax.fori_loop(
+                    0, (n_slow + (_SLOW_BATCH - 1)) // _SLOW_BATCH, fix, out)
+            return out, overflow | (n3 > cap2), n_mid, n_slow
+
+        _compiled_rules[key] = run
+        if len(_compiled_rules) > 256:
+            _compiled_rules.pop(next(iter(_compiled_rules)))
+
+    return run
 
 
 def sweep_device(
@@ -1401,8 +1945,8 @@ def sweep_device(
     dev_weights,
     choose_args=None,
     chunk: int = 1 << 19,
-    bad_div: int = 8,
-    bad2_div: int = 2048,
+    bad_div: Optional[int] = None,
+    bad2_div: Optional[int] = None,
 ):
     """Device-resident staged sweep: the whole multi-million-id program
     is ONE jit dispatch, placements stay in HBM, and nothing
@@ -1421,80 +1965,54 @@ def sweep_device(
        max(n/bad2_div, 2048)) — the full program's while_loop overhead
        is paid once per sweep, not once per chunk.
 
-    Healthy maps run ~6% unclean after stage 1 and ~0.006% after stage
-    2, far under the 12.5% / 0.05%+floor default capacities; if the
-    sweep overflows either capacity, the returned flag is True and the
-    caller must fall back to sweep() (results would be incomplete, not
-    wrong: overflowed lanes keep their earlier-stage placement, which
-    may differ from full retry).  bad_div=1, bad2_div=1 gives full
-    capacity at every stage (exact on any map, at full-program cost
-    for the fixup batches).
+    The plan (which stages, both capacities, the budgeted stage's
+    budget and shape) is sweep_plan()'s for this rule, map and device
+    weights: a healthy replicated map runs ~6% unclean after stage 1
+    and ~0.006% after stage 2 and gets capacities of 12.5% and 0.05%
+    (floor 2048 lanes) at a budget of 3; the erasure-coded pool's
+    `chooseleaf indep 12` over 64 hosts with one host out leaves three
+    lanes in four unclean after one attempt, so its plan drops stage 1,
+    runs the budgeted program over every lane and sizes stage 3 from
+    what the model expects to outlast the budget.  `bad_div` and
+    `bad2_div` override the plan's capacities (with `bad_div` given,
+    stage 1 runs whatever the plan says); bad_div=1, bad2_div=1 gives
+    full capacity at every stage (exact on any map, at full-program
+    cost for the fixup batches).  If a sweep overflows a capacity, the
+    returned flag is True and the placements are incomplete, not wrong:
+    overflowed lanes keep their earlier-stage placement, which may
+    differ from full retry; sweep(), whose batches are cut to what
+    each chunk needs, is exact then.
 
     xs length must be a multiple of `chunk` (callers pad; the bench
     repeats ids).  Returns (placements i32 [N, result_max] ON DEVICE,
-    overflow bool ON DEVICE).
+    overflow bool ON DEVICE).  The counts of lanes that entered stages
+    2 and 3 stay on the device too, filed for sweep_totals().
     """
     xs = jnp.asarray(xs, dtype=jnp.int32)
     n = int(xs.shape[0])
     chunk = min(chunk, n)
     assert n % chunk == 0, (n, chunk)
-    cap = max(1, chunk // bad_div)
+    plan = sweep_plan(flat, steps, result_max, dev_weights, choose_args)
+    # a capacity given for stage 2 keeps stage 1, whatever the plan says
+    with_fast = bad_div is not None or plan.fast
+    if bad_div is not None:
+        plan = plan._replace(bad_div=bad_div)
+    if bad2_div is not None:
+        plan = plan._replace(bad2_div=bad2_div)
+    cap = max(1, chunk // plan.bad_div)
     # global stage-3 capacity: residue is ~0.006% on healthy maps; the
     # floor keeps small sweeps from starving the exact stage
-    cap2 = min(n, max(n // bad2_div, 2048))
+    cap2 = min(n, max(n // plan.bad2_div, 2048))
 
-    # the jitted runner is cached process-wide (like compile_rule):
-    # a fresh jax.jit wrapper per call would re-trace + re-compile on
-    # EVERY call, so repeated sweeps would time XLA, not the sweep
-    import os
-
-    key = (_rule_digest(flat, steps, result_max, choose_args),
-           "sweep_device", n, chunk, cap, cap2,
-           os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1")
-    run = _compiled_rules.get(key)
-    if run is None:
-        fast = compile_rule(flat, steps, result_max, choose_args,
-                            one_shot=True)
-        mid = compile_rule(flat, steps, result_max, choose_args,
-                           one_shot=True, budget=MID_BUDGET)
-        slow = compile_rule(flat, steps, result_max, choose_args)
-
-        @functools.partial(instrumented_jit, family="crush_mapper")
-        def run(xs2, w):
-            def body(overflow, sub):
-                with jax.named_scope("crush.fast"):
-                    res, clean = fast(sub, w)
-                bad = jnp.nonzero(~clean, size=cap, fill_value=chunk)[0]
-                n_bad = jnp.sum(~clean)
-                # padding lanes (index==chunk) clamp to chunk-1 and
-                # recompute sub[chunk-1]; their scatter is dropped
-                bad_xs = sub[jnp.minimum(bad, chunk - 1)]
-                with jax.named_scope("crush.mid"):
-                    res2, clean2 = mid(bad_xs, w)
-                res = res.at[bad].set(res2, mode="drop")
-                # residual mask back in chunk shape (padding dropped);
-                # the exact full-program fixup runs ONCE over the whole
-                # sweep after the scan — its while_loop overhead is per
-                # batch, not per chunk
-                resid = jnp.zeros((chunk,), jnp.bool_).at[bad].set(
-                    ~clean2, mode="drop")
-                return overflow | (n_bad > cap), (res, resid)
-
-            overflow, (out, resids) = jax.lax.scan(
-                body, jnp.asarray(False), xs2.reshape(-1, chunk))
-            out = out.reshape(n, result_max)
-            resid_all = resids.reshape(n)
-            n3 = jnp.sum(resid_all)
-            b3 = jnp.nonzero(resid_all, size=cap2, fill_value=n)[0]
-            xs3 = xs2[jnp.minimum(b3, n - 1)]
-            with jax.named_scope("crush.slow"):
-                fixed = slow(xs3, w)
-            out = out.at[b3].set(fixed, mode="drop")
-            return out, overflow | (n3 > cap2)
-
-        _compiled_rules[key] = run
-        if len(_compiled_rules) > 256:
-            _compiled_rules.pop(next(iter(_compiled_rules)))
-
-    with tracing.span("crush.sweep", ids=n, chunk=chunk):
-        return run(xs, jnp.asarray(dev_weights, dtype=jnp.uint32))
+    run = _device_runner(flat, steps, result_max, choose_args, n, chunk,
+                         cap, cap2, plan, with_fast)
+    mode, numrep = _rule_shape(steps, result_max)
+    with tracing.span("crush.sweep", ids=n, chunk=chunk, numrep=numrep,
+                      mode=mode, cap=cap, cap2=cap2, budget=plan.budget):
+        out, overflow, n_mid, n_slow = run(
+            xs, jnp.asarray(dev_weights, dtype=jnp.uint32))
+    _totals["crush.ids"] += n
+    if len(_unread) >= 256:   # long since computed: the read waits for none
+        sweep_totals()
+    _unread.append((n_mid, n_slow))
+    return out, overflow
