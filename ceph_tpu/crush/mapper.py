@@ -120,7 +120,30 @@ _MAX_DRAW_TABS = 64
 MID_BUDGET = 3
 
 
-class _DeviceMap:
+class _HostMap:
+    """What the static plans read of a FlatMap, on the host: the
+    descent plans (_descent_plan) of a compiled rule and of sweep_plan,
+    which builds nothing for the device."""
+
+    def __init__(self, flat: FlatMap, choose_args=None):
+        # choose_args ({bucket_id: [weights]}, reference
+        # CrushWrapper.h:72 / crush_choose_arg) substitute the straw2
+        # draw weights — balancer weight-set overrides
+        self._np_weights = _choose_arg_weights(flat, choose_args)
+        self._np_items = np.asarray(flat.items)
+        self._np_sizes = np.asarray(flat.sizes)
+        self._np_types = np.asarray(flat.types)
+        self._np_algs = np.asarray(flat.algs)
+        self.n_buckets = int(flat.items.shape[0])
+        self.max_size = int(flat.items.shape[1])
+        self.depth = _tree_depth(flat)
+
+    def buckets_of_type(self, type_id: int) -> list:
+        return [b for b in range(self.n_buckets)
+                if int(self._np_types[b]) == type_id]
+
+
+class _DeviceMap(_HostMap):
     """FlatMap lowered to device arrays (captured by the compiled rule).
 
     Everything is int32/uint32: the 2^48-scale ln magnitudes and the
@@ -129,11 +152,9 @@ class _DeviceMap:
     """
 
     def __init__(self, flat: FlatMap, choose_args=None):
-        # choose_args ({bucket_id: [weights]}, reference
-        # CrushWrapper.h:72 / crush_choose_arg) substitute the straw2
-        # draw weights — balancer weight-set overrides
-        flat = dataclasses_replace_weights(
-            flat, _choose_arg_weights(flat, choose_args))
+        super().__init__(flat, choose_args)
+        flat = dataclasses_replace_weights(flat, self._np_weights)
+        self.max_devices = int(flat.max_devices)
         # magic reciprocals for the straw2 divide: weights are map
         # constants, so the exact truncating s64 division ln/w becomes
         # a 16-bit-limb mulhi + one correction, all in uint32 (TPU has
@@ -184,16 +205,6 @@ class _DeviceMap:
             jnp.asarray(((n >> (16 * i)) & 0xFFFF).astype(np.uint32))
             for i in range(4)
         ]
-        self.n_buckets = int(flat.items.shape[0])
-        self.max_size = int(flat.items.shape[1])
-        self.max_devices = int(flat.max_devices)
-        self.depth = _tree_depth(flat)
-        # host-side copies for static descent planning
-        self._np_items = np.asarray(flat.items)
-        self._np_sizes = np.asarray(flat.sizes)
-        self._np_types = np.asarray(flat.types)
-        self._np_algs = np.asarray(flat.algs)
-        self._np_weights = np.asarray(flat.weights)  # post-choose_args
         # legacy bucket algorithm support: aux planes are materialized
         # only for algs the map actually uses (straw2-only maps — the
         # modern default — pay nothing)
@@ -217,15 +228,16 @@ class _DeviceMap:
                        ).bit_length() - 1)
 
 
-def _level_fast_delta(dm: "_DeviceMap", frontier) -> int:
+def _level_fast_delta(dm: "_HostMap", frontier) -> int:
     """Hash-ambiguity window for the fastcmp straw2 draw at one descent
     level, or 0 when the level is ineligible.
 
     Eligible when every frontier bucket is straw2 with uniform positive
     item weights, all under ln.fastcmp_bounds()[delta]: then the draw
     winner is exactly the max-hash item unless the runner-up hash is
-    within delta (those lanes are flagged unclean and re-run through
-    the exact table path — see ln.fastcmp_bounds).
+    within delta (a contested draw: the budgeted stage compares the two
+    true draws, the firstn one-shot pass flags the lane for that stage
+    — see ln.fastcmp_bounds and _straw2_choose).
     CEPH_TPU_CRUSH_NO_FASTCMP=1 disables (A/B + safety)."""
     import os
 
@@ -256,31 +268,34 @@ def _level_fast_delta(dm: "_DeviceMap", frontier) -> int:
     return 0
 
 
-def _descent_plan(dm: "_DeviceMap", frontier, want_type: int,
-                  fastcmp: bool = False):
+def _descent_plan(dm: "_HostMap", frontier, want_type: int,
+                  fastcmp: bool = False, resolve: bool = True):
     """Static unroll plan for a descent whose possible start buckets
     are known at trace time: per level, (max bucket width actually
-    reachable, fastcmp delta).  A take->chooseleaf walk on a
+    reachable, fastcmp delta, resolve).  A take->chooseleaf walk on a
     root(64 hosts) -> host(16 osds) map plans [64, 16] instead of
     paying the global max_size at every level AND the global tree
     depth — for typical 2-level maps this halves the straw2 work per
     choose.  fastcmp=True (one-shot traces only) additionally marks
     levels whose frontier buckets have uniform weights: those levels
     draw by pure hash+argmax with an unclean flag instead of table
-    gathers (_level_fast_delta).
+    gathers (_level_fast_delta).  `resolve` is the stage's way with a
+    contested fastcmp draw, handed on to _straw2_choose with each
+    level: compare the two true draws (the budgeted stage), or only
+    flag the lane (the firstn one-shot pass).
 
     frontier: iterable of bucket indices possibly holding the walk at
-    level 0.  Returns a list of per-level (width, delta) tuples;
+    level 0.  Returns a list of per-level (width, delta, resolve) tuples;
     falls back to the conservative global plan when the frontier is
     unknown."""
     frontier = {b for b in frontier if 0 <= b < dm.n_buckets}
     if not frontier:
-        return [(dm.max_size, 0)] * dm.depth
+        return [(dm.max_size, 0, resolve)] * dm.depth
     plan = []
     for _ in range(dm.depth):
         width = max(int(dm._np_sizes[b]) for b in frontier)
         delta = _level_fast_delta(dm, frontier) if fastcmp else 0
-        plan.append((max(width, 1), delta))
+        plan.append((max(width, 1), delta, resolve))
         nxt = set()
         for b in frontier:
             for j in range(int(dm._np_sizes[b])):
@@ -331,7 +346,8 @@ _U16 = jnp.uint32(0xFFFF)
 _UMAX = jnp.uint32(0xFFFFFFFF)
 
 
-def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
+def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
+                   resolve: bool = True):
     """Vectorized bucket_straw2_choose (reference: mapper.c:361-384),
     exact and 64-bit-free.  Returns (item, ambig).
 
@@ -340,13 +356,25 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
     |ln| = n < 2^48, so argmax(draw) == lexicographic argmin of the
     positive quotient q = floor(n / w).
 
-    fastcmp path (delta > 0, one-shot traces on uniform-weight
-    buckets): the winner is the max-hash item directly — NO table
-    access at all (TPU gathers measured ~8x slower than the hash
-    itself).  Exact except when the runner-up hash is within `delta`
-    of the winner (ln.fastcmp_bounds derivation); those lanes return
-    ambig=True and the two-stage sweep re-runs them through the exact
-    program, so end-to-end results stay bit-identical.
+    fastcmp path (delta > 0, budgeted traces on uniform-weight
+    buckets): the winner is the max-hash item directly, with no table
+    access.  Exact except when the nearest distinct runner-up hash is
+    within `delta` of the winner's (ln.fastcmp_bounds derivation): a
+    CONTESTED draw, about width * delta / 65536 of them.  What becomes
+    of one is the stage's choice, `resolve`:
+    - resolve=True (the budgeted stage, which contested lanes reach and
+      whose residue has little room behind it): the two candidates'
+      true draws are compared through the draw tables, one w_idx gather
+      and four table gathers on EVERY lane; only a third distinct hash
+      inside the window (P ~ 1e-5 a draw) returns ambig=True;
+    - resolve=False (the firstn one-shot pass, which runs over every
+      id): no gather from w_idx or the draw tables at all; a contested
+      draw returns ambig=True, so the lane is unclean and the budgeted
+      stage re-runs it.  A gather costs the chip more than hashing the
+      bucket (PERF.md, PR 30), and the comparison was most of the pass.
+    Either way a lane that is not flagged carries the exact winner, so
+    the staged sweeps stay bit-identical to the full program.  A map
+    without draw tables flags, whatever `resolve` says.
 
     Table path (table_mode): weights are map constants, so q is
     precomputed per distinct weight as (hi, lo) u32 planes over all
@@ -375,7 +403,7 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
         sel2 = (~sel1) & (uv >= 0)
         u2 = jnp.max(jnp.where(sel2, uv, jnp.int32(-1)))
         close2 = (u2 >= 0) & (u1 - u2 <= delta)
-        if dm.table_mode:
+        if dm.table_mode and resolve:
             # EXACT runner-up resolution: the only contested case is
             # u1 - u2 <= delta (ln.fastcmp_bounds), so compare the two
             # candidates' true draws via two precomputed q-table
@@ -395,7 +423,7 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
             u3 = jnp.max(jnp.where(sel2 & (uv != u2), uv, jnp.int32(-1)))
             ambig = (u3 >= 0) & (u1 - u3 <= delta)
             return items[idx], ambig
-        # no q tables on this map: flag the contested case instead
+        # flag the contested case and leave it to the next stage
         # all-invalid: u1 == -1, argmax(all False) == 0 -> items[0],
         # identical to the table path's all-masked argmin
         return items[i1], close2
@@ -654,13 +682,15 @@ def _uniform_choose(dm: _DeviceMap, bno, x, r):
     return dm.items[bno][perm[pr]]
 
 
-def _bucket_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
+def _bucket_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
+                   resolve: bool = True):
     """Per-alg dispatch; straw2-only maps trace straight through the
-    straw2 path with zero overhead.  `width` / `delta` are the static
-    per-level bounds from the descent plan (straw2 only; the legacy
-    algs are rare enough to always run at full width).  `bno` and `r`
-    may be vectors of one length (an indep round's slots, see
-    _straw2_choose_slots), the result is then a vector too.  Returns
+    straw2 path with zero overhead.  `width` / `delta` / `resolve` are
+    the static per-level entries of the descent plan (straw2 only; the
+    legacy algs are rare enough to always run at full width; a vector
+    of slots resolves its first contested slot, see
+    _straw2_choose_slots).  `bno` and `r` may be vectors of one length
+    (an indep round's slots), the result is then a vector too.  Returns
     (item, ambig); delta > 0 implies the plan proved every reachable
     bucket at this level is straw2, so the legacy overrides below are
     per-lane no-ops then."""
@@ -669,10 +699,11 @@ def _bucket_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0):
         if dm.only_straw2 and (delta or dm.table_mode):
             return _straw2_choose_slots(dm, bno, x, r, width, delta)
         return jax.vmap(
-            lambda b, rr: _bucket_choose(dm, b, x, rr, width, delta))(bno, r)
+            lambda b, rr: _bucket_choose(dm, b, x, rr, width, delta,
+                                         resolve))(bno, r)
     if dm.only_straw2:
-        return _straw2_choose(dm, bno, x, r, width, delta)
-    out, ambig = _straw2_choose(dm, bno, x, r, width, delta)
+        return _straw2_choose(dm, bno, x, r, width, delta, resolve)
+    out, ambig = _straw2_choose(dm, bno, x, r, width, delta, resolve)
     alg = dm.algs[bno]
     if ALG_STRAW in dm.algs_present:
         out = jnp.where(alg == ALG_STRAW, _straw_choose(dm, bno, x, r),
@@ -743,10 +774,12 @@ def _descend(
     status = jnp.int32(_OK)
     ambig = jnp.asarray(False)
 
-    levels = plan if plan is not None else [(dm.max_size, 0)] * dm.depth
-    for width, fast_delta in levels:
+    levels = (plan if plan is not None
+              else [(dm.max_size, 0, True)] * dm.depth)
+    for width, fast_delta, resolve in levels:
         empty = dm.sizes[bno] == 0
-        it, amb = _bucket_choose(dm, bno, x, r_for(bno), width, fast_delta)
+        it, amb = _bucket_choose(dm, bno, x, r_for(bno), width, fast_delta,
+                                 resolve)
         bad_item = it >= dm.max_devices
         sub_bno = -1 - it
         valid_sub = (it < 0) & (sub_bno < dm.n_buckets)
@@ -1292,7 +1325,12 @@ def compile_rule(
     the lanes whose every placement succeeded at first attempt with no
     fastcmp draw ambiguity (_straw2_choose) — for those the full
     algorithm provably produces the identical result (retries only
-    trigger on failure).  Unclean lanes must be re-run through a
+    trigger on failure).  A firstn choose of this pass only FLAGS a
+    contested fastcmp draw (runner-up hash within delta, about
+    width * delta / 65536 of a level's draws), so its program gathers
+    nothing from w_idx or the draw tables; an indep choose compares the
+    true draws of a level's first contested slot
+    (_straw2_choose_slots).  Unclean lanes must be re-run through a
     higher-budget program (see sweep()); under vmap this removes the
     dominant cost of the full program, where every lane pays the
     batch's WORST-CASE retry rounds.
@@ -1301,10 +1339,15 @@ def compile_rule(
     semantics statically unrolled to N attempts per choose; lanes fully
     placed within the budget are bit-identical to the full program
     (deterministic attempt sequences), the rest stay unclean for the
-    exact full program.  For an `indep` choose the N rounds are shaped
-    by `rounds`, ((slots, leaf retries) a round, see _choose_indep and
-    sweep_plan, which is where the sweeps get theirs); without it every
-    round runs every slot with all its leaf tries.
+    exact full program.  This stage settles a contested fastcmp draw
+    itself, by the two candidates' true draws from the tables: the
+    lanes the one-shot pass flagged end here, and only a third hash in
+    the window is left to the exact program (budget 0: the table path
+    on every item, no fastcmp).  For an `indep` choose the N rounds are
+    shaped by `rounds`, ((slots, leaf retries) a round, see
+    _choose_indep and sweep_plan, which is where the sweeps get
+    theirs); without it every round runs every slot with all its leaf
+    tries.
 
     Compiled programs are cached process-wide by map content: rebuilding
     an identical map (common in tests and in OSDMap churn that leaves
@@ -1397,24 +1440,25 @@ def compile_rule(
                 # stage unclean lanes re-run through).  With the
                 # table_mode top-2 exact resolution the fastcmp draw is
                 # exact except for 3-candidates-in-window (~1e-5), so
-                # the mid stage keeps it too.
+                # the mid stage keeps it too.  The firstn one-shot pass
+                # has that stage behind it and only flags a contested
+                # draw: no gather from the draw tables on its lanes
+                # (sweep_plan counts the share it flags).
                 fc = budget_val > 0
+                resolve = not (firstn and budget_val == 1)
                 plan = (_descent_plan(dm, static_frontier, arg2,
-                                      fastcmp=fc)
+                                      fastcmp=fc, resolve=resolve)
                         if static_frontier is not None else None)
                 leaf_plan = None
                 if recurse and arg2 > 0:
                     # the leaf recursion starts from a bucket of type
                     # arg2 (whichever one the outer choose picked)
-                    leaf_starts = [b for b in range(dm.n_buckets)
-                                   if int(dm._np_types[b]) == arg2]
-                    leaf_plan = _descent_plan(dm, leaf_starts, 0,
-                                              fastcmp=fc)
+                    leaf_plan = _descent_plan(
+                        dm, dm.buckets_of_type(arg2), 0,
+                        fastcmp=fc, resolve=resolve)
                 # after this choose the walk holds items of type arg2
                 static_frontier = (
-                    [b for b in range(dm.n_buckets)
-                     if int(dm._np_types[b]) == arg2]
-                    if arg2 > 0 else None)
+                    dm.buckets_of_type(arg2) if arg2 > 0 else None)
 
                 o_buf = jnp.full((result_max,), ITEM_NONE, dtype=jnp.int32)
                 osize = jnp.int32(0)
@@ -1653,6 +1697,24 @@ def _retry_model(flat: FlatMap, steps, result_max: int, dev_weights,
     return indep, numrep, s2, q, (a if leaf_tries > 1 else 0.0)
 
 
+def _contested_share(flat: FlatMap, steps, numrep: int,
+                     choose_args=None) -> float:
+    """Bound on the share of lanes in which the firstn one-shot pass of
+    a `take; choose; emit` rule flags a contested fastcmp draw: numrep
+    descents, each level of the outer and the leaf plan contested on
+    about width * delta / 65536 of its draws (_straw2_choose).  Read
+    off the same static plans compile_rule builds."""
+    hm = _HostMap(flat, choose_args)
+    take = next(arg1 for op, arg1, _ in steps if op == OP_TAKE)
+    op, _, want = next(s for s in steps if s[0] in (
+        OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN))
+    levels = _descent_plan(hm, [-1 - take], want, fastcmp=True)
+    if op == OP_CHOOSELEAF_FIRSTN and want > 0:
+        levels = levels + _descent_plan(
+            hm, hm.buckets_of_type(want), 0, fastcmp=True)
+    return numrep * sum(w * d for w, d, _ in levels) / 65536.0
+
+
 def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
                choose_args=None) -> SweepPlan:
     """The staged sweeps' plan for this rule on this map with these
@@ -1661,7 +1723,11 @@ def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
 
     _retry_model gives the chance s2 that two picks meet and the chance
     q that a pick fails by itself.  The expected number of picks of a
-    lane that fail bounds the share of lanes with a failure, so:
+    lane that fail bounds the share of lanes with a failure; a firstn
+    one-shot pass also flags every contested fastcmp draw for the
+    budgeted stage (_contested_share: 0.7 % of the lanes for three
+    replicas over 64 hosts of 16, 9 % over 1024 OSDs straight under
+    the root), which settles it and sends nothing on.  So:
     stage-2 capacity is twice the share expected unclean after one
     attempt each, as a power-of-two part of the chunk, and a rule that
     would send more than half of the lanes there skips the one-shot
@@ -1673,9 +1739,13 @@ def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
     retries likewise, and rounds until 2^-8 of the lanes are expected
     to have a vacancy, 3 to 6 of them.  The plan is memoised by rule,
     map and weights; a sweep that still overflows says so."""
+    import os
+
     dev_w = np.ascontiguousarray(np.asarray(dev_weights), dtype=np.uint32)
+    # the contested share is read at the kill-switch's present value
     key = (_rule_digest(flat, steps, result_max, choose_args),
-           dev_w.tobytes())
+           dev_w.tobytes(),
+           os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1")
     plan = _plans.get(key)
     if plan is not None:
         return plan
@@ -1708,7 +1778,8 @@ def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
             left = min(1.0, vacant + lost)
         else:
             fails = [min(1.0, q + i * s2) for i in range(n)]
-            first = min(1.0, sum(fails))
+            first = min(1.0, sum(fails) + _contested_share(
+                flat, steps, n, choose_args))
             left = min(1.0, sum(f ** MID_BUDGET for f in fails))
         plan = SweepPlan(_pow2_div(2 * first, DEFAULT_PLAN.bad_div),
                          _pow2_div(2 * left, DEFAULT_PLAN.bad2_div),
@@ -1774,15 +1845,21 @@ def sweep(
     reference src/osd/OSDMapMapping.h:17) as a THREE-STAGE program:
 
     1. the one-shot trace maps every id with exactly one attempt per
-       choose (fastcmp draws) — the overwhelmingly common case on
-       healthy maps — and reports which lanes were clean;
-    2. the unclean lanes (collisions/rejections/draw ambiguity,
-       typically <6%) re-run through the bounded-budget trace (real
-       retry semantics unrolled to a few attempts — resolves nearly
-       all collisions at a fraction of the full program's cost);
+       choose — the overwhelmingly common case on healthy maps — and
+       reports which lanes were clean.  Its fastcmp draws pick the
+       max-hash item and, in a firstn rule, read no draw table: a
+       contested draw (runner-up hash within delta) makes the lane
+       unclean;
+    2. the unclean lanes (collisions, rejections, contested draws:
+       4.7 % + 0.7 % for three replicas over 64 hosts of 16) re-run
+       through the bounded-budget trace (real retry semantics unrolled
+       to a few attempts, a contested draw settled by the two
+       candidates' true draws — resolves nearly all of them at a
+       fraction of the full program's cost);
     3. the residue (typically <0.2%) re-runs through the exact
-       full-retry program, padded to a power-of-two batch so the slow
-       program compiles for O(log) distinct shapes.
+       full-retry program (every item drawn through the tables),
+       padded to a power-of-two batch so the slow program compiles for
+       O(log) distinct shapes.
 
     Which stages run, and the budgeted stage's budget and shape, come
     from sweep_plan(): a rule whose one-shot pass would leave most
@@ -1955,11 +2032,13 @@ def sweep_device(
 
     Same three-stage semantics as sweep() but with static shapes:
 
-    1. fast one-shot pass (fastcmp draws) over each chunk;
+    1. fast one-shot pass over each chunk (fastcmp draws; a firstn
+       rule's contested draws are flagged and gather nothing);
     2. the unclean lane INDICES are extracted with a fixed capacity of
        chunk/bad_div (jnp.nonzero(size=...)), re-run through the
-       bounded-budget program, and scattered back (out-of-capacity
-       padding indices are dropped);
+       bounded-budget program (which settles a contested draw from the
+       draw tables), and scattered back (out-of-capacity padding
+       indices are dropped);
     3. lanes still unclean after the budget re-run through the exact
        full-retry program in ONE global batch after the scan (capacity
        max(n/bad2_div, 2048)) — the full program's while_loop overhead
@@ -1967,9 +2046,12 @@ def sweep_device(
 
     The plan (which stages, both capacities, the budgeted stage's
     budget and shape) is sweep_plan()'s for this rule, map and device
-    weights: a healthy replicated map runs ~6% unclean after stage 1
-    and ~0.006% after stage 2 and gets capacities of 12.5% and 0.05%
-    (floor 2048 lanes) at a budget of 3; the erasure-coded pool's
+    weights: a healthy replicated map (three replicas over 64 hosts of
+    16) runs ~5.4% unclean after stage 1, 4.7% collisions and 0.7%
+    contested draws, and ~0.006% after stage 2 and gets capacities of
+    12.5% and 0.05% (floor 2048 lanes) at a budget of 3; a very wide
+    bucket (1024 OSDs straight under the root: 9% contested) gets 25%
+    for stage 2; the erasure-coded pool's
     `chooseleaf indep 12` over 64 hosts with one host out leaves three
     lanes in four unclean after one attempt, so its plan drops stage 1,
     runs the budgeted program over every lane and sizes stage 3 from

@@ -120,6 +120,41 @@ def test_a_healthy_replicated_maps_plan_is_todays(osds, hosts):
         assert plan.bad_div <= 2 and plan.bad2_div < 2048
 
 
+@pytest.mark.parametrize("hosts", [64, 0])
+def test_the_plan_counts_the_one_shot_passes_contested_draws(hosts,
+                                                             monkeypatch):
+    """A firstn one-shot pass flags every contested fastcmp draw for
+    the budgeted stage, numrep * sum(width * delta) / 65536 of the
+    lanes by the descent plans.  The benchmark's map (64 hosts of 16):
+    0.7 % beside 4.7 % of collisions, twice which is still under 1/8,
+    so the plan is the default.  1024 OSDs straight under the root:
+    9.4 % beside 0.3 %, so stage 2 gets a quarter of the chunk, which
+    collisions alone (fastcmp switched off) would not ask for."""
+    m, root = cmap.build_flat_cluster(1024, hosts=hosts)
+    steps = [(cmap.OP_TAKE, root, 0),
+             (cmap.OP_CHOOSELEAF_FIRSTN, 3, 1 if hosts else 0),
+             (cmap.OP_EMIT, 0, 0)]
+    flat = m.flatten()
+    w = np.full(1024, 0x10000, dtype=np.uint32)
+    indep, numrep, s2, q, _a = mapper._retry_model(flat, steps, 3, w)
+    assert (indep, numrep, q) == (False, 3, 0.0)
+    collisions = 3 * s2
+    share = mapper._contested_share(flat, steps, 3)
+    plan = mapper.sweep_plan(flat, steps, 3, w)
+    if hosts:
+        assert share == pytest.approx(3 * (64 * 2 + 16 * 2) / 65536)
+        assert 0.05 < collisions + share < 1 / 16
+        assert plan == mapper.DEFAULT_PLAN
+    else:
+        assert share == pytest.approx(3 * 1024 * 2 / 65536)
+        assert 2 * collisions < 1 / 8 < 2 * (collisions + share) < 1 / 4
+        assert plan == (4, 2048, 3, None)
+    # without fastcmp nothing is contested and no room is made for it
+    monkeypatch.setenv("CEPH_TPU_CRUSH_NO_FASTCMP", "1")
+    assert mapper._contested_share(flat, steps, 3) == 0.0
+    assert mapper.sweep_plan(flat, steps, 3, w) == mapper.DEFAULT_PLAN
+
+
 def test_the_ec_pools_plan_on_the_benchmarks_map():
     """chooseleaf indep 12 over 64 hosts in 8 racks, host 0 out, 32
     OSDs at 0.75: three lanes in four are unclean after one attempt, so
