@@ -61,17 +61,13 @@ def _opts() -> List[Option]:
         O("name", str, "client.admin", "entity name", LEVEL_BASIC, runtime=False),
         O("fsid", str, "", "cluster id", LEVEL_BASIC, runtime=False),
         O("log_level", int, 1, "default log verbosity", LEVEL_BASIC),
-        O("log_file", str, "", "log output path ('' = stderr)"),
         O("log_ring_size", int, 10000, "crash-dump ring entries"),
         O("tracing", bool, False, "record blkin-style trace spans"),
         O("admin_socket", str, "", "admin socket path ('' = disabled)"),
-        O("heartbeat_interval", float, 5.0, "internal liveness check period"),
         O("failpoint_inject", str, "",
           "arm fault-injection points (core/failpoint.py DSL: "
           "name=action[:modifier...],... — see failpoint.POINTS)"),
         # -- messenger ------------------------------------------------------
-        O("ms_bind_ip", str, "127.0.0.1", "listen address", runtime=False),
-        O("ms_connect_timeout", float, 10.0, "dial timeout seconds"),
         O("ms_retry_interval", float, 0.2, "session reconnect backoff"),
         O("ms_dispatch_throttle_bytes", int, 100 << 20,
           "max bytes of queued undispatched messages"),
@@ -91,8 +87,6 @@ def _opts() -> List[Option]:
           "seconds down before auto-out"),
         O("mon_osd_min_down_reporters", int, 2,
           "distinct failure reporters required to mark an osd down"),
-        O("mon_osd_adjust_heartbeat_grace", bool, True,
-          "scale grace by reporter history"),
         O("mon_pg_stats_stale_s", float, 30.0,
           "seconds after which an OSD's MPGStats report stops feeding "
           "PG health checks; a LIVE osd whose reports go stale past "
@@ -166,13 +160,8 @@ def _opts() -> List[Option]:
         O("osd_client_write_timeout", float, 30.0,
           "seconds before an in-flight client write whose commit (or "
           "durable-ack gate) never resolves answers retryable EAGAIN"),
-        O("osd_max_write_size", int, 90 << 20, "largest single write"),
         O("osd_pool_default_size", int, 3, "replica count"),
-        O("osd_pool_default_min_size", int, 0, "0 = size - size/2"),
         O("osd_pool_default_pg_num", int, 32, "pgs per new pool"),
-        O("osd_pool_default_erasure_code_profile", str,
-          "plugin=isa k=8 m=4 technique=reed_sol_van",
-          "default EC profile"),
         O("osd_recovery_max_active", int, 3, "concurrent recovery ops"),
         O("osd_recovery_read_timeout", float, 10.0,
           "seconds to wait for a recovery window's sub-read replies "
@@ -209,10 +198,6 @@ def _opts() -> List[Option]:
         O("osd_client_op_priority", int, 63, "client op priority"),
         O("osd_recovery_op_priority", int, 3, "recovery op priority"),
         # -- erasure code / device -----------------------------------------
-        O("erasure_code_batch_cols", int, 1 << 20,
-          "stripe-batch queue target columns per device dispatch"),
-        O("erasure_code_tile_n", int, 2048, "pallas column tile"),
-        O("tpu_stripe_queue_depth", int, 4, "in-flight device batches"),
         O("tpu_devpath", bool, True,
           "device-resident small-object data path: stage EC WRITEFULL "
           "payloads into the pinned pool, fuse crc32c into the encode "
@@ -256,7 +241,6 @@ def _opts() -> List[Option]:
           runtime=False),
         # -- objectstore ----------------------------------------------------
         O("objectstore", str, "memstore", "backend", enum=("memstore", "filestore")),
-        O("objectstore_path", str, "", "data directory for filestore"),
         O("objectstore_wal_sync", bool, False, "fsync the WAL per txn"),
         O("filestore_debug_inject_read_err", bool, False,
           "fault injection: EIO on reads marked bad"),
@@ -272,12 +256,8 @@ def _opts() -> List[Option]:
           "transaction (BlueStore csum_order analog)"),
         O("store_verify_read", bool, True,
           "verify per-extent at-rest seals on every read; a mismatch "
-          "raises instead of serving flipped bytes (off = bench "
+          "raises instead of serving flipped bytes (off = "
           "comparison mode — the corruption seam still applies)"),
-        # -- client ---------------------------------------------------------
-        O("objecter_timeout", float, 30.0, "op resend timeout"),
-        O("objecter_inflight_ops", int, 1024, "op throttle"),
-        O("rados_osd_op_timeout", float, 0.0, "0 = no timeout"),
     ]
 
 

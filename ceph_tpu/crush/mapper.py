@@ -19,8 +19,8 @@ version serialized catastrophically under vmap):
 - only the retry state machine (rare collisions/rejections) remains a
   ``lax.while_loop``, whose body is now the cheap unrolled descent; in
   the common case it runs 1-2 rounds for the whole batch;
-- callers chunk very large id batches host-side (bench.py) so live HBM
-  temps stay bounded.
+- very large id batches are cut into chunks (sweep, sweep_device) so
+  live HBM temps stay bounded.
 
 Semantics notes (kept bit-exact vs the real reference C,
 tests/test_crush_vs_reference.py):
@@ -237,14 +237,8 @@ def _level_fast_delta(dm: "_HostMap", frontier) -> int:
     winner is exactly the max-hash item unless the runner-up hash is
     within delta (a contested draw: the budgeted stage compares the two
     true draws, the firstn one-shot pass flags the lane for that stage
-    — see ln.fastcmp_bounds and _straw2_choose).
-    CEPH_TPU_CRUSH_NO_FASTCMP=1 disables (A/B + safety)."""
-    import os
-
+    — see ln.fastcmp_bounds and _straw2_choose)."""
     from ceph_tpu.crush import ln as _ln
-
-    if os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1":
-        return 0
 
     wmax = 0
     for b in frontier:
@@ -1353,18 +1347,13 @@ def compile_rule(
     an identical map (common in tests and in OSDMap churn that leaves
     the crush tree untouched) costs a digest, not a ~10s XLA compile.
     """
-    import os
-
     budget_val = (1 if one_shot else 0) if budget is None else int(budget)
-    # the kill-switch is read at TRACE time (_level_fast_delta), so it
-    # must key the compile cache or toggling it mid-process is inert
-    no_fc = os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1"
     if budget_val > 1 and rounds is not None:
         rounds = tuple((int(a), int(b)) for a, b in rounds)[:budget_val]
     else:
         rounds = None
     digest = _rule_digest(flat, steps, result_max, choose_args) + (
-        f":budget{budget_val}{':nofc' if no_fc else ''}"
+        f":budget{budget_val}"
         f"{':rounds%r' % (rounds,) if rounds else ''}"
         if budget_val else "")
     cached = _compiled_rules.get(digest)
@@ -1739,13 +1728,9 @@ def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
     retries likewise, and rounds until 2^-8 of the lanes are expected
     to have a vacancy, 3 to 6 of them.  The plan is memoised by rule,
     map and weights; a sweep that still overflows says so."""
-    import os
-
     dev_w = np.ascontiguousarray(np.asarray(dev_weights), dtype=np.uint32)
-    # the contested share is read at the kill-switch's present value
     key = (_rule_digest(flat, steps, result_max, choose_args),
-           dev_w.tobytes(),
-           os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1")
+           dev_w.tobytes())
     plan = _plans.get(key)
     if plan is not None:
         return plan
@@ -1935,11 +1920,8 @@ def _device_runner(flat, steps, result_max, choose_args, n: int,
     re-compile on EVERY call, so repeated sweeps would time XLA, not
     the sweep.  run(xs, w) -> (placements, overflow, lanes that entered
     stage 2, lanes that entered stage 3)."""
-    import os
-
     key = (_rule_digest(flat, steps, result_max, choose_args),
            "sweep_device", n, chunk, cap, cap2,
-           os.environ.get("CEPH_TPU_CRUSH_NO_FASTCMP") == "1",
            with_fast, plan.budget, plan.rounds)
     run = _compiled_rules.get(key)
     if run is None:
@@ -2065,10 +2047,10 @@ def sweep_device(
     differ from full retry; sweep(), whose batches are cut to what
     each chunk needs, is exact then.
 
-    xs length must be a multiple of `chunk` (callers pad; the bench
-    repeats ids).  Returns (placements i32 [N, result_max] ON DEVICE,
-    overflow bool ON DEVICE).  The counts of lanes that entered stages
-    2 and 3 stay on the device too, filed for sweep_totals().
+    xs length must be a multiple of `chunk` (callers pad).  Returns
+    (placements i32 [N, result_max] ON DEVICE, overflow bool ON
+    DEVICE).  The counts of lanes that entered stages 2 and 3 stay on
+    the device too, filed for sweep_totals().
     """
     xs = jnp.asarray(xs, dtype=jnp.int32)
     n = int(xs.shape[0])

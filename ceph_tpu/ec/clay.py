@@ -514,10 +514,6 @@ class ClayCodec(ErasureCode):
         reference likewise refuses ec_overwrites on clay pools)."""
         return False
 
-    # -- bench conveniences -------------------------------------------------
-    def encode_bytes(self, data: bytes) -> Dict[int, np.ndarray]:
-        return self.encode(range(self._k + self._m), data)
-
 
 class ErasureCodeClay:
     """Registry factory (plugin name "clay")."""

@@ -176,8 +176,7 @@ def _engine(n: int) -> str:
     backend = jax.default_backend()
     if ((backend == "tpu"
          or os.environ.get("CEPH_TPU_FORCE_PALLAS") == "1")
-            and n % 512 == 0
-            and os.environ.get("CEPH_TPU_NO_PALLAS") != "1"):
+            and n % 512 == 0):
         return "pallas"
     if backend == "cpu" and _native_rs_encode() is not None:
         return "native"

@@ -8,8 +8,11 @@ is amortized over every write in flight — the TPU equivalent of the
 reference's per-call SIMD batch (and the only way small stripes win;
 see SURVEY.md §7 hard parts #2).
 
-Double-buffering falls out of the design: while the device runs batch
-N, the worker is already collecting batch N+1.
+Nothing overlaps the device today: the one worker coalesces, stacks,
+dispatches and then blocks in the fetch of batch N before it takes
+batch N+1 (PERF §5: 55 ms of `dev_wait_ms.write` and 34 ms of host work,
+one after the other, in an 89.5 ms cycle).  A worker that assembles
+N+1 while the device runs N is ROADMAP D5.
 """
 
 from __future__ import annotations
@@ -147,7 +150,7 @@ class StripeBatchQueue:
 
     def sample(self, window_s: float = 10.0) -> None:
         """Refresh the device-visibility gauges: called off the data
-        path (the OSD stats tick, the bench) so `perf dump` and the
+        path (the OSD stats tick) so `perf dump` and the
         Prometheus export show live queue depth, staging occupancy,
         and the device-busy fraction: the rate at which devwatch's
         cumulative `dev.wait` seconds grew over the ring window."""

@@ -193,8 +193,6 @@ declare("crush_mapper", free_args=(1,),
         note="xs i32[n]: n pow2 (chunk or high-water fixup pad); "
              "arg1 is the device-weight vector, sized by the map "
              "epoch's OSD count (free)")
-declare("benchloop",
-        note="planes u32[k, T, 128] from gen_planes; T pow2 ladders")
 declare("meshio",
         note="stripe axis covering-padded to pow2 multiples of 4*dp")
 
@@ -295,7 +293,7 @@ def compile_cache_dir() -> Optional[str]:
 # produce (4k..256k objects over k in 2..8).  The queue pads every
 # batch to one of these, so warming them IS warming the op path.
 # 32768 is load-bearing: a 64KiB object at k=2 chunks to exactly that
-# width, and the bench's armed steady guard caught it missing.
+# width, and an armed steady guard caught it missing.
 WARM_COLS = (4096, 16384, 32768, 65536)
 # the flat decode matmul takes its recovery matrix as an operand (one
 # program per width serves every survivor signature), so its whole

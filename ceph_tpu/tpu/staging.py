@@ -5,9 +5,12 @@ segment is received into page-aligned buffers once and every later
 consumer (crc, EC encode, BlueStore) reads the SAME memory.  Our
 equivalent for a device-offloaded OSD: client write payloads land in a
 **pinned staging pool** (preallocated, bounded — the h2d DMA source on
-a real TPU rig), ride to the device once per *coalesced batch* (the
-StripeBatchQueue upload), and after that only metadata (crcs, oids,
-versions, extents) crosses back to host.  A ``DeviceBuf`` is the
+a real TPU rig) and ride to the device per *coalesced batch* (the
+StripeBatchQueue upload).  That is the design, not yet the measured
+path: the queue uploads a batch twice as numpy (its data planes for the
+encode, data + coding planes again for the crc pass: 1 and 1.5 MiB a
+1 MiB job) and fetches the coding planes whole in between, so more
+than metadata crosses back (PERF §5; ROADMAP S3).  A ``DeviceBuf`` is the
 payload's handle through the whole pipeline: messenger dispatch ->
 ``ObjectState.data`` -> ``ECBackend.submit`` -> ``Transaction`` ->
 store apply / wire serialization.
@@ -33,7 +36,7 @@ measured by ``DevPathStats``):
 
 Tier-1 runs ``JAX_PLATFORMS=cpu``, where "device" arrays share host
 RAM — so the copy-count/bytes-crossed COUNTERS are the CI-provable
-invariant, and raw GB/s evidence rides the bench aux on device rigs.
+invariant; speeds are the benchmark's to measure (PERF.md).
 """
 
 from __future__ import annotations

@@ -105,7 +105,7 @@ def _k8_decode():
 
 
 # T = 256: a 1 MiB object's 128 KiB chunk; T = 2048: a full
-# erasure_code_batch_cols batch (1 MiB columns)
+# StripeBatchQueue batch (max_batch_cols, 1 Mi columns)
 @pytest.mark.parametrize("T", [256, 2048])
 def test_encode_k8m4_at_queue_widths(chip, T):
     _compile_planes(chip, _k8m4(), T)

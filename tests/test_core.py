@@ -5,6 +5,7 @@ Mirrors the reference's src/test/common/ + src/test/encoding/ tier
 """
 
 import os
+import re
 import threading
 import time
 
@@ -140,6 +141,23 @@ def test_config_argv_and_diff():
 def test_config_schema_types_validate_defaults():
     for name, opt in SCHEMA.items():
         opt.validate(opt.default)
+
+
+def test_every_config_option_is_read_somewhere():
+    """An option that no line of the program reads is a switch that
+    changes nothing: each SCHEMA name occurs as a word in some .py file
+    under ceph_tpu/ (core/config.py apart) or tools/."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    words = set()
+    for top in ("ceph_tpu", "tools"):
+        for d, _dirs, files in os.walk(os.path.join(root, top)):
+            for f in files:
+                path = os.path.join(d, f)
+                if f.endswith(".py") and not path.endswith(
+                        os.path.join("core", "config.py")):
+                    with open(path) as fh:
+                        words.update(re.findall(r"\w+", fh.read()))
+    assert sorted(set(SCHEMA) - words) == []
 
 
 # -- perf counters ----------------------------------------------------------

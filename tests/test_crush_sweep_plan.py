@@ -8,6 +8,7 @@ to three witnesses: the host-staged sweep(), the C oracle
 (benchmarks/reference_crush_tree.py), which imports nothing of ceph_tpu.
 """
 
+import dataclasses
 import os
 import sys
 
@@ -121,15 +122,14 @@ def test_a_healthy_replicated_maps_plan_is_todays(osds, hosts):
 
 
 @pytest.mark.parametrize("hosts", [64, 0])
-def test_the_plan_counts_the_one_shot_passes_contested_draws(hosts,
-                                                             monkeypatch):
+def test_the_plan_counts_the_one_shot_passes_contested_draws(hosts):
     """A firstn one-shot pass flags every contested fastcmp draw for
     the budgeted stage, numrep * sum(width * delta) / 65536 of the
     lanes by the descent plans.  The benchmark's map (64 hosts of 16):
     0.7 % beside 4.7 % of collisions, twice which is still under 1/8,
     so the plan is the default.  1024 OSDs straight under the root:
     9.4 % beside 0.3 %, so stage 2 gets a quarter of the chunk, which
-    collisions alone (fastcmp switched off) would not ask for."""
+    collisions alone (mixed weights, no fastcmp) would not ask for."""
     m, root = cmap.build_flat_cluster(1024, hosts=hosts)
     steps = [(cmap.OP_TAKE, root, 0),
              (cmap.OP_CHOOSELEAF_FIRSTN, 3, 1 if hosts else 0),
@@ -149,10 +149,13 @@ def test_the_plan_counts_the_one_shot_passes_contested_draws(hosts,
         assert share == pytest.approx(3 * 1024 * 2 / 65536)
         assert 2 * collisions < 1 / 8 < 2 * (collisions + share) < 1 / 4
         assert plan == (4, 2048, 3, None)
-    # without fastcmp nothing is contested and no room is made for it
-    monkeypatch.setenv("CEPH_TPU_CRUSH_NO_FASTCMP", "1")
-    assert mapper._contested_share(flat, steps, 3) == 0.0
-    assert mapper.sweep_plan(flat, steps, 3, w) == mapper.DEFAULT_PLAN
+    # mixed item weights make every level ineligible for fastcmp
+    # (_level_fast_delta): nothing is contested, no room is made for it
+    wts = np.asarray(flat.weights).copy()
+    wts[:, 1::2] *= 3   # every other item of every bucket
+    mixed = dataclasses.replace(flat, weights=wts)
+    assert mapper._contested_share(mixed, steps, 3) == 0.0
+    assert mapper.sweep_plan(mixed, steps, 3, w) == mapper.DEFAULT_PLAN
 
 
 def test_the_ec_pools_plan_on_the_benchmarks_map():

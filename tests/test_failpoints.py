@@ -3,7 +3,7 @@ filestore EIO wiring, and — the PR-7 tentpole — the committed
 barrier/drop schedule that reproduces the 0xd403 acked-write-vs-
 rollback loss class without load or luck.
 
-The 0xd403 class (ROUND6_NOTES.md): under 2x CPU overload, ~1/3 of
+The 0xd403 class (see README): under 2x CPU overload, ~1/3 of
 thrash replays lost ACKED state (xattr loss, byte divergence, a
 missing object), always immediately after a `rolled back 1 divergent
 entries` line.  Root cause: a DEGRADED EC commit (a peer died

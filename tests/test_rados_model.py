@@ -400,9 +400,11 @@ def _dump_thrash_forensics(c, err, seed, model=None):
                     "latest_entry": (None if en is None else
                                      f"op={en.op} v={en.version}"),
                 }
-    out = os.path.join(os.path.dirname(__file__), "..", "scratch",
-                       f"thrash_ec_forensics_{seed:#x}.json")
-    with open(os.path.abspath(out), "w") as f:
+    out = os.path.abspath(os.path.join(
+        os.path.dirname(__file__), "..", "scratch",
+        f"thrash_ec_forensics_{seed:#x}.json"))
+    os.makedirs(os.path.dirname(out), exist_ok=True)
+    with open(out, "w") as f:
         json.dump(report, f, indent=1, sort_keys=True, default=str)
 
 

@@ -449,7 +449,7 @@ def test_windowed_pull_end_to_end():
 
 
 def test_recover_on_read_serves_before_full_pull():
-    """With a slow 16-object pull at window W=1, a read of an object
+    """With a slow 32-object pull at window W=1, a read of an object
     deep in the queue promotes it and is served by its own recovery
     round — while most of the pull is still outstanding — instead of
     EAGAINing until the end (recover_on_read_hits proves the parked
@@ -458,7 +458,7 @@ def test_recover_on_read_serves_before_full_pull():
     cl = LibClient(c)
     c.ctx.conf.set_val("osd_recovery_max_active", 1, force=True)
     try:
-        pgid, oids = _same_pg_oids(c, 16, "rr")
+        pgid, oids = _same_pg_oids(c, 32, "rr")
         _pg, acting, primary = c.primary_of(EC_POOL, oids[0])
         for oid in oids:
             assert cl.put(EC_POOL, oid,
@@ -467,8 +467,11 @@ def test_recover_on_read_serves_before_full_pull():
         for oid in oids:
             assert cl.put(EC_POOL, oid,
                           f"{oid}|B".encode() * 64).result == 0
-        # slow every surviving peer's vec answer: ~0.15s per window
-        # round makes the 16-round pull take seconds
+        # slow every surviving peer's vec answer: 0.25s per window
+        # round makes the 32-round pull take 8 s, of which more than
+        # 8 objects are missing for 5.75 s: room for a loaded rig to
+        # get from the revive to the read
+        round_s = [0.25]
         for o in c.osds.values():
             if not o.up or pgid not in o.pgs:
                 continue
@@ -476,7 +479,7 @@ def test_recover_on_read_serves_before_full_pull():
             orig = opg.handle_sub_read_vec
 
             def slow(msg, conn, _orig=orig):
-                time.sleep(0.15)
+                time.sleep(round_s[0])
                 _orig(msg, conn)
 
             opg.handle_sub_read_vec = slow
@@ -504,6 +507,7 @@ def test_recover_on_read_serves_before_full_pull():
             "did not shortcut the window")
         hits = svc.pg_perf.dump().get("recover_on_read_hits", 0)
         assert hits >= 1, "no parked read was woken by recovery"
+        round_s[0] = 0.0   # the race is over: let the pull drain
         for o in c.osds.values():
             if o.up:
                 o.wait_pgs_settled(30.0)
