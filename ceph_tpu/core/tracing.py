@@ -136,8 +136,11 @@ SPANS: Dict[str, str] = {
     #   device is done, fetch included
     # CRUSH sweep
     "crush.sweep": "",                    # sweep_device's call; counts ids,
-    #   chunk, numrep, mode (firstn/indep) and the plan it ran: cap, cap2
-    #   (lanes the budgeted and the exact stage can take), budget
+    #   chunk, numrep, mode (firstn/indep), the plan it ran: cap, cap2
+    #   (lanes the budgeted and the exact stage can take), budget; and the
+    #   descent levels of the stage programs it ran by how each reads its
+    #   bucket rows: const, onehot (from the level's static frontier),
+    #   gather (by the bucket index)
     # the mapper's monotonic totals (mapper.sweep_totals(): counters, not
     # spans, registered here so that their names are held to one table)
     "crush.ids": "crush_mid_lanes_per_id",          # ids swept: the divisor

@@ -16,6 +16,12 @@ version serialized catastrophically under vmap):
   levels) with masked carry — there is no data-dependent while_loop
   inside the descent, so each level is one wide [batch, bucket_width]
   hash+draw+argmax block that XLA fuses and tiles;
+- a level reads its bucket's rows from the level's static frontier (the
+  buckets a walk can stand in there, known at trace time): constants
+  for one bucket, a one-hot contraction with the frontier's own table
+  for a few, and only a wide or unknown frontier gathers by the bucket
+  index — on the chip a gather costs more than hashing the bucket
+  (_Rows);
 - only the retry state machine (rare collisions/rejections) remains a
   ``lax.while_loop``, whose body is now the cheap unrolled descent; in
   the common case it runs 1-2 rounds for the whole batch;
@@ -113,6 +119,17 @@ _SKIP = 2  # bad item / bad type: give up on this replica slot
 # (real maps quantize weights to a handful of device sizes)
 _MAX_DRAW_TABS = 64
 
+# a level of a descent whose frontier (the buckets a walk can stand in
+# there, known at trace time) holds at most this many reads its bucket
+# rows by a one-hot contraction with the frontier's own small table; a
+# wider frontier gathers by the bucket index.  From a probe on the chip
+# (PERF.md, PR 33): a row gather is 2.7-3.9 ms a slot-level of 2^19
+# lanes whatever the table, the contraction 5-9 us for each bucket of
+# the frontier, so they cross at 430-560 buckets; the one-hot itself is
+# kept, a byte a bucket, slot and lane, which is what holds this at a
+# quarter of the crossing
+_MAX_ONEHOT_FRONTIER = 128
+
 # mid-stage retry budget for the staged sweeps: real retry semantics
 # statically unrolled this many attempts (resolves ~97% of stage-1
 # unclean lanes; the rest hit the exact full program).  The least a
@@ -137,6 +154,23 @@ class _HostMap:
         self.n_buckets = int(flat.items.shape[0])
         self.max_size = int(flat.items.shape[1])
         self.depth = _tree_depth(flat)
+        # straw2 draw tables exist for a map of few distinct weights
+        # (_DeviceMap builds them)
+        w_all = self._np_weights.astype(np.uint64)
+        self._distinct = np.unique(w_all[w_all > 0])
+        self.table_mode = 0 < len(self._distinct) <= _MAX_DRAW_TABS
+        # the per-(bucket, item) tables a level's rows are read from
+        # (_Rows): the items, which of them can win a draw (flatten()
+        # pads a row with weight 0), and each one's draw table
+        self._tabs = {
+            "items": self._np_items.astype(np.int32),
+            "valid": ((np.arange(self.max_size) < self._np_sizes[:, None])
+                      & (w_all > 0)),
+        }
+        if self.table_mode:
+            # 0 for w==0 slots; those are masked invalid in the choose
+            self._tabs["w_idx"] = np.searchsorted(
+                self._distinct, np.maximum(w_all, 1)).astype(np.int32)
 
     def buckets_of_type(self, type_id: int) -> list:
         return [b for b in range(self.n_buckets)
@@ -155,19 +189,6 @@ class _DeviceMap(_HostMap):
         super().__init__(flat, choose_args)
         flat = dataclasses_replace_weights(flat, self._np_weights)
         self.max_devices = int(flat.max_devices)
-        # magic reciprocals for the straw2 divide: weights are map
-        # constants, so the exact truncating s64 division ln/w becomes
-        # a 16-bit-limb mulhi + one correction, all in uint32 (TPU has
-        # no native 64-bit integer datapath at all)
-        w_safe = np.maximum(np.asarray(flat.weights, dtype=np.uint64), 1)
-        magic = (np.uint64(0xFFFFFFFFFFFFFFFF) // w_safe).astype(object)
-        # magic split into 4x16-bit limbs
-        self.magic_l = [
-            jnp.asarray(
-                ((magic >> (16 * i)) & 0xFFFF).astype(np.uint32))
-            for i in range(4)
-        ]
-        self.items = jnp.asarray(flat.items, dtype=jnp.int32)
         self.weights = jnp.asarray(flat.weights, dtype=jnp.uint32)
         self.sizes = jnp.asarray(flat.sizes, dtype=jnp.int32)
         self.algs = jnp.asarray(flat.algs, dtype=jnp.int32)
@@ -180,10 +201,8 @@ class _DeviceMap(_HostMap):
         # arithmetic at all.  Maps with pathological weight diversity
         # (> _MAX_DRAW_TABS distinct values) fall back to the exact
         # u32-limb magic-reciprocal path below.
-        w_all = np.asarray(flat.weights, dtype=np.uint64)
-        distinct = np.unique(w_all[w_all > 0])
-        self.table_mode = 0 < len(distinct) <= _MAX_DRAW_TABS
         if self.table_mode:
+            distinct = self._distinct
             n64 = (-ln.ln16_table()).astype(np.uint64)
             thi = np.empty((len(distinct), 65536), dtype=np.uint32)
             tlo = np.empty((len(distinct), 65536), dtype=np.uint32)
@@ -193,11 +212,25 @@ class _DeviceMap(_HostMap):
                 tlo[i] = (q & 0xFFFFFFFF).astype(np.uint32)
             self.draw_hi = jnp.asarray(thi)
             self.draw_lo = jnp.asarray(tlo)
-            # per-(bucket, item) index into the tables (0 for w==0
-            # slots; those are masked invalid in the choose)
-            self.w_idx = jnp.asarray(
-                np.searchsorted(distinct, np.maximum(w_all, 1)
-                                ).astype(np.int32))
+        else:
+            # magic reciprocals for the straw2 divide: weights are map
+            # constants, so the exact truncating s64 division ln/w
+            # becomes a 16-bit-limb mulhi + one correction, all in
+            # uint32 (TPU has no native 64-bit integer datapath at all).
+            # The limb path reads the weight and its reciprocal, split
+            # into 4x16-bit limbs
+            self._tabs["weights"] = np.asarray(flat.weights, np.uint32)
+            w_safe = np.maximum(
+                np.asarray(flat.weights, dtype=np.uint64), 1)
+            magic = (np.uint64(0xFFFFFFFFFFFFFFFF) // w_safe).astype(object)
+            for i in range(4):
+                self._tabs[f"magic{i}"] = (
+                    (magic >> (16 * i)) & 0xFFFF).astype(np.uint32)
+        # the same tables on the device, for a level that gathers
+        self._dev = {k: jnp.asarray(v) for k, v in self._tabs.items()}
+        self.items = self._dev["items"]
+        # per-(bucket, item) index into the draw tables
+        self.w_idx = self._dev.get("w_idx")
         # n = -(crush_ln(u) - 2^48) in [1, 2^48] — note u=0 hits 2^48
         # EXACTLY, so limbs must cover 49 bits: 4x16-bit tables
         n = (-ln.ln16_table()).astype(np.uint64)
@@ -262,34 +295,70 @@ def _level_fast_delta(dm: "_HostMap", frontier) -> int:
     return 0
 
 
+class _Level(NamedTuple):
+    """One level of a descent plan: what _descent_plan observed of the
+    buckets a walk can stand in there."""
+
+    width: int      # widest bucket reachable at this level
+    delta: int      # fastcmp window, 0: every item is drawn
+    resolve: bool   # the stage's way with a contested fastcmp draw
+    frontier: Optional[tuple] = None  # those buckets; None: not known
+    read: str = "gather"  # how the level reads its bucket rows (_Rows):
+    #   "const" (one bucket: the rows are constants of the program),
+    #   "onehot" (a few: the bucket's place in the frontier, contracted
+    #   with the frontier's own table), "gather" (by the bucket index)
+    sub_type: int = 0  # the one type of the level's child buckets
+
+
+def _level_read(dm: "_HostMap", frontier):
+    """(read, sub_type) of a level with this frontier.  Reading from the
+    frontier takes straw2 buckets whose child buckets are of one type
+    (so that no winner's type is looked up), and few enough of them."""
+    sub_types = set()
+    for b in frontier:
+        if int(dm._np_algs[b]) != ALG_STRAW2:
+            return "gather", 0
+        for it in dm._np_items[b, :int(dm._np_sizes[b])]:
+            if it < 0 and -1 - int(it) < dm.n_buckets:
+                sub_types.add(int(dm._np_types[-1 - int(it)]))
+    if len(sub_types) > 1 or len(frontier) > _MAX_ONEHOT_FRONTIER:
+        return "gather", 0
+    return ("const" if len(frontier) == 1 else "onehot",
+            sub_types.pop() if sub_types else 0)
+
+
 def _descent_plan(dm: "_HostMap", frontier, want_type: int,
                   fastcmp: bool = False, resolve: bool = True):
     """Static unroll plan for a descent whose possible start buckets
-    are known at trace time: per level, (max bucket width actually
-    reachable, fastcmp delta, resolve).  A take->chooseleaf walk on a
-    root(64 hosts) -> host(16 osds) map plans [64, 16] instead of
-    paying the global max_size at every level AND the global tree
-    depth — for typical 2-level maps this halves the straw2 work per
-    choose.  fastcmp=True (one-shot traces only) additionally marks
-    levels whose frontier buckets have uniform weights: those levels
-    draw by pure hash+argmax with an unclean flag instead of table
-    gathers (_level_fast_delta).  `resolve` is the stage's way with a
-    contested fastcmp draw, handed on to _straw2_choose with each
-    level: compare the two true draws (the budgeted stage), or only
-    flag the lane (the firstn one-shot pass).
+    are known at trace time: a _Level for each level, which carries the
+    widest bucket actually reachable there, the fastcmp delta, `resolve`
+    and the level's frontier itself with the way its bucket rows are
+    read.  A take->chooseleaf walk on a root(64 hosts) -> host(16 osds)
+    map plans widths [64, 16] instead of paying the global max_size at
+    every level AND the global tree depth — for typical 2-level maps
+    this halves the straw2 work per choose — and reads the root's rows
+    as constants and a host's by its place among the 64, with no gather
+    by a bucket index (_Rows).  fastcmp=True (budgeted traces only)
+    additionally marks levels whose frontier buckets have uniform
+    weights: those levels draw by pure hash+argmax with an unclean flag
+    instead of table gathers (_level_fast_delta).  `resolve` is the
+    stage's way with a contested fastcmp draw, handed on to
+    _straw2_choose with each level: compare the two true draws (the
+    budgeted stage), or only flag the lane (the firstn one-shot pass).
 
     frontier: iterable of bucket indices possibly holding the walk at
-    level 0.  Returns a list of per-level (width, delta, resolve) tuples;
-    falls back to the conservative global plan when the frontier is
-    unknown."""
-    frontier = {b for b in frontier if 0 <= b < dm.n_buckets}
+    level 0, or None where that is not known: the conservative global
+    plan then, every level at full width and gathering."""
+    frontier = {b for b in frontier or () if 0 <= b < dm.n_buckets}
     if not frontier:
-        return [(dm.max_size, 0, resolve)] * dm.depth
+        return [_Level(dm.max_size, 0, resolve)] * dm.depth
     plan = []
     for _ in range(dm.depth):
         width = max(int(dm._np_sizes[b]) for b in frontier)
         delta = _level_fast_delta(dm, frontier) if fastcmp else 0
-        plan.append((max(width, 1), delta, resolve))
+        plan.append(_Level(max(width, 1), delta, resolve,
+                           tuple(sorted(frontier)),
+                           *_level_read(dm, frontier)))
         nxt = set()
         for b in frontier:
             for j in range(int(dm._np_sizes[b])):
@@ -340,10 +409,102 @@ _U16 = jnp.uint32(0xFFFF)
 _UMAX = jnp.uint32(0xFFFFFFFF)
 
 
-def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
-                   resolve: bool = True):
+def _pick(row, idx):
+    """row[..., idx] as a masked sum over the row: a gather, even from
+    a row of eight, costs the chip more than hashing it (PERF.md, PR 30,
+    PR 33)."""
+    at = jnp.arange(row.shape[-1]) == idx[..., None]
+    return jnp.sum(jnp.where(at, row, 0), axis=-1, dtype=row.dtype)
+
+
+class _Rows:
+    """One level's reads of the bucket tables, for the walk(s) standing
+    in bucket(s) `bno` (a scalar, or a vector of slots).
+
+    What the plan observed of the level's frontier decides how
+    (_Level.read).  "const": the frontier is one bucket, so its rows,
+    its size and its children's type are constants of the program.
+    "onehot": the bucket's place in the frontier as a one-hot
+    (bno == frontier[k]), and a row as the contraction of that one-hot
+    with the frontier's own [F, width] table, a select-and-sum that
+    fuses with what reads the row; whatever all the frontier's buckets
+    have in common (every item valid, one draw table, no empty bucket)
+    is a constant again and is not looked up at all.  "gather": by the
+    bucket index from the whole map's tables (a frontier that is
+    unknown, wide, or holds a bucket of a legacy alg).  A walk that is
+    done keeps a bucket outside the frontier and reads zeros there:
+    _descend masks all it makes of them."""
+
+    def __init__(self, dm: "_DeviceMap", bno, lvl: "_Level"):
+        self.dm, self.bno, self.lvl = dm, bno, lvl
+        self.frontier = (None if lvl.read == "gather"
+                         else list(lvl.frontier))
+        self.hot = (bno[..., None] == jnp.asarray(self.frontier, jnp.int32)
+                    if lvl.read == "onehot" else None)
+
+    def at(self, at):
+        """The reader of the slots `at` of a vector of slots."""
+        return _Rows(self.dm, self.bno[at], self.lvl)
+
+    def _of(self, tab):
+        """tab [F, ...], an entry a frontier bucket (numpy: a constant
+        of the map; jax: made from an operand) -> this walk's entry."""
+        if isinstance(tab, np.ndarray) and (tab == tab[:1]).all():
+            tab = tab[:1]
+        if tab.shape[0] == 1:
+            return jnp.broadcast_to(
+                tab[0], jnp.shape(self.bno) + tab.shape[1:])
+        hot = self.hot.reshape(self.hot.shape + (1,) * (tab.ndim - 1))
+        axis = jnp.ndim(self.bno)
+        if tab.dtype == np.bool_:
+            return jnp.any(hot & tab, axis=axis)
+        return jnp.sum(jnp.where(hot, tab, 0), axis=axis, dtype=tab.dtype)
+
+    def row(self, name: str):
+        """The bucket's row of the table `name` (_HostMap._tabs), cut
+        to the level's width."""
+        width = self.lvl.width
+        if self.frontier is None:
+            return self.dm._dev[name][:, :width][self.bno]
+        return self._of(self.dm._tabs[name][self.frontier, :width])
+
+    def empty(self):
+        if self.frontier is None:
+            return self.dm.sizes[self.bno] == 0
+        return self._of(self.dm._np_sizes[self.frontier] == 0)
+
+    def sub_type(self, sub_bno, valid_sub):
+        """The type of the child bucket a draw chose, 0 for a device."""
+        if self.frontier is None:
+            dm = self.dm
+            return jnp.where(
+                valid_sub,
+                dm.types[jnp.clip(sub_bno, 0, dm.n_buckets - 1)], 0)
+        return jnp.where(valid_sub, jnp.int32(self.lvl.sub_type), 0)
+
+    def dev_weight(self, dev_weights, item, idx):
+        """The device weight of `item`, the draw's winner at place `idx`
+        of its row (what is_out reads).  From the frontier: the table
+        dev_weights[items] of the frontier's rows is built once a call,
+        not a lane (the weights are an operand, the items are not)."""
+        wmax = dev_weights.shape[0]
+        if self.frontier is None or idx is None:
+            return dev_weights[jnp.clip(item, 0, wmax - 1)].astype(
+                jnp.uint32)
+        items = self.dm._np_items[self.frontier, :self.lvl.width]
+        if (items < 0).all():  # no device to choose here
+            return jnp.zeros(jnp.shape(item), jnp.uint32)
+        tab = dev_weights[np.clip(items, 0, wmax - 1)].astype(jnp.uint32)
+        return _pick(self._of(tab), idx)
+
+
+def _straw2_choose(dm: _DeviceMap, x, r, rows: _Rows):
     """Vectorized bucket_straw2_choose (reference: mapper.c:361-384),
-    exact and 64-bit-free.  Returns (item, ambig).
+    exact and 64-bit-free.  Returns (item, ambig, the winner's place in
+    the bucket's row).  The bucket, its rows and the level's static
+    entries (width, delta, resolve) come with `rows`, the level's
+    reader (_Rows); the winner's item is a masked sum over the row
+    (_pick).
 
     The C computes draw = div64_s64(ln, w) per item and keeps the
     strictly-greatest draw (first index on ties).  ln is negative with
@@ -358,9 +519,9 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
     of one is the stage's choice, `resolve`:
     - resolve=True (the budgeted stage, which contested lanes reach and
       whose residue has little room behind it): the two candidates'
-      true draws are compared through the draw tables, one w_idx gather
-      and four table gathers on EVERY lane; only a third distinct hash
-      inside the window (P ~ 1e-5 a draw) returns ambig=True;
+      true draws are compared through the draw tables, four table
+      gathers on EVERY lane; only a third distinct hash inside the
+      window (P ~ 1e-5 a draw) returns ambig=True;
     - resolve=False (the firstn one-shot pass, which runs over every
       id): no gather from w_idx or the draw tables at all; a contested
       draw returns ambig=True, so the lane is unclean and the budgeted
@@ -378,16 +539,14 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
     limb products (never overflowing u32), then one upward correction
     (q_est is provably in {q-1, q} for n < 2^48).
     """
-    width = width or dm.max_size
-    items = dm.items[:, :width][bno]
-    wts = dm.weights[:, :width][bno]
-    size = dm.sizes[bno]
+    delta, resolve = rows.lvl.delta, rows.lvl.resolve
+    items = rows.row("items")
+    valid = rows.row("valid")
     u = hashes.hash32_3(
         x.astype(jnp.uint32), items.astype(jnp.uint32), r.astype(jnp.uint32),
         xp=jnp,
     ) & _U16
     if delta:
-        valid = (jnp.arange(width) < size) & (wts > 0)
         uv = jnp.where(valid, u.astype(jnp.int32), jnp.int32(-1))
         u1 = jnp.max(uv)
         sel1 = uv == u1  # valid implied: invalid slots are -1 < u1
@@ -405,7 +564,7 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
             # a THIRD distinct hash inside the window (P ~ 1e-5 per
             # draw) stays ambiguous.
             i2 = jnp.argmax(sel2 & (uv == u2)).astype(jnp.int32)
-            wi = dm.w_idx[bno, jnp.minimum(i1, width - 1)]
+            wi = _pick(rows.row("w_idx"), i1)
             u2c = jnp.clip(u2, 0, 0xFFFF)
             q1h, q1l = dm.draw_hi[wi, u1], dm.draw_lo[wi, u1]
             q2h, q2l = dm.draw_hi[wi, u2c], dm.draw_lo[wi, u2c]
@@ -416,28 +575,29 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
             idx = jnp.where(close2, resolved, i1)
             u3 = jnp.max(jnp.where(sel2 & (uv != u2), uv, jnp.int32(-1)))
             ambig = (u3 >= 0) & (u1 - u3 <= delta)
-            return items[idx], ambig
+            return _pick(items, idx), ambig, idx
         # flag the contested case and leave it to the next stage
-        # all-invalid: u1 == -1, argmax(all False) == 0 -> items[0],
+        # all-invalid: u1 == -1, argmax(all True) == 0 -> items[0],
         # identical to the table path's all-masked argmin
-        return items[i1], close2
+        return _pick(items, i1), close2, i1
     no_ambig = jnp.asarray(False)
     if dm.table_mode:
         ui = u.astype(jnp.int32)
-        wi = dm.w_idx[:, :width][bno]
+        wi = rows.row("w_idx")
         q_hi = dm.draw_hi[wi, ui]
         q_lo = dm.draw_lo[wi, ui]
-        valid = (jnp.arange(width) < size) & (wts > 0)
         q_hi = jnp.where(valid, q_hi, _UMAX)
         q_lo = jnp.where(valid, q_lo, _UMAX)
         min_hi = jnp.min(q_hi)
         cand = q_hi == min_hi
         min_lo = jnp.min(jnp.where(cand, q_lo, _UMAX))
         sel = cand & (q_lo == min_lo)
-        return items[jnp.argmax(sel)], no_ambig
+        idx = jnp.argmax(sel).astype(jnp.int32)
+        return _pick(items, idx), no_ambig, idx
     ui = u.astype(jnp.int32)
+    wts = rows.row("weights")
     nl = [dm.ln_l[i][ui] for i in range(4)]  # n in 4x16-bit limbs
-    ml = [mlj[:, :width][bno] for mlj in dm.magic_l]  # magic, 16-bit limbs
+    ml = [rows.row(f"magic{i}") for i in range(4)]  # magic, 16-bit limbs
 
     # P = n * magic: 16-bit-limb column accumulation; per-column sums
     # stay < 2^19 (<= 4 lo + 4 hi terms of < 2^16 each)
@@ -489,40 +649,37 @@ def _straw2_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
     q_lo = q_lo2
 
     # winner = first index of the minimal (q_hi, q_lo) among valid items
-    valid = (jnp.arange(width) < size) & (wts > 0)
     q_hi = jnp.where(valid, q_hi, _UMAX)
     q_lo = jnp.where(valid, q_lo, _UMAX)
     min_hi = jnp.min(q_hi)
     cand = q_hi == min_hi
     min_lo = jnp.min(jnp.where(cand, q_lo, _UMAX))
     sel = cand & (q_lo == min_lo)
-    return items[jnp.argmax(sel)], no_ambig
+    idx = jnp.argmax(sel).astype(jnp.int32)
+    return _pick(items, idx), no_ambig, idx
 
 
-def _straw2_choose_slots(dm: _DeviceMap, bno, x, r, width=None,
-                         delta: int = 0):
+def _straw2_choose_slots(dm: _DeviceMap, x, r, rows: _Rows):
     """_straw2_choose for a vector of (bucket, r) pairs at once (an
-    indep round's slots): bno, r [S] -> (items [S], ambig [S]), each
-    entry what _straw2_choose gives for its pair.
+    indep round's slots): rows.bno, r [S] -> (items [S], ambig [S],
+    places [S]), each entry what _straw2_choose gives for its pair.
 
     One block of array code for all the slots of a round, where a
     Python loop over twelve slots made twelve copies of the descent in
     the program (and a compile of many minutes).  The hash runs over
     one flat [S * width] axis; on the chip that measured the same as a
     vmapped slot axis (PERF.md, PR 30: what a level costs there is its
-    table gathers, not its hash).  Draw-table and
+    lookups, not its hash).  The slots' rows come through `rows`
+    (_Rows: from the level's frontier where the plan knows it), a
+    winner's item is a masked sum over its row.  Draw-table and
     fastcmp paths only; _bucket_choose maps the rest slot by slot."""
-    width = width or dm.max_size
-    items = dm.items[:, :width][bno]            # [S, width]
-    wts = dm.weights[:, :width][bno]
+    width, delta = rows.lvl[:2]
+    items = rows.row("items")            # [S, width]
+    valid = rows.row("valid")
     u = (hashes.hash32_3(
         x.astype(jnp.uint32), items.reshape(-1).astype(jnp.uint32),
         jnp.repeat(r.astype(jnp.uint32), width), xp=jnp,
     ) & _U16).reshape(items.shape)
-    # flatten() pads a bucket's row with weight 0, so the weight alone
-    # says which entries are items that can win (no lookup of its size)
-    valid = wts > 0
-    rows = jnp.arange(items.shape[0])
     if delta:
         uv = jnp.where(valid, u.astype(jnp.int32), jnp.int32(-1))
         u1 = jnp.max(uv, axis=-1)
@@ -532,7 +689,7 @@ def _straw2_choose_slots(dm: _DeviceMap, bno, x, r, width=None,
         u2 = jnp.max(jnp.where(sel2, uv, jnp.int32(-1)), axis=-1)
         close2 = (u2 >= 0) & (u1 - u2 <= delta)
         if not dm.table_mode:
-            return items[rows, i1], close2
+            return _pick(items, i1), close2, i1
         # the runner-up is within delta in one slot of two thousand,
         # and the four draw-table gathers of this comparison were 62 %
         # of a one-shot pass when every slot made them (PERF.md, PR 30):
@@ -544,7 +701,7 @@ def _straw2_choose_slots(dm: _DeviceMap, bno, x, r, width=None,
         i2a = jnp.argmax(
             (uva != u1a[:, None]) & (uva == u2a[:, None]),
             axis=-1).astype(jnp.int32)
-        wi = dm.w_idx[bno[at], jnp.minimum(i1a, width - 1)]
+        wi = _pick(rows.at(at).row("w_idx"), i1a)
         u2c = jnp.clip(u2a, 0, 0xFFFF)
         q1h, q1l = dm.draw_hi[wi, u1a], dm.draw_lo[wi, u1a]
         q2h, q2l = dm.draw_hi[wi, u2c], dm.draw_lo[wi, u2c]
@@ -558,17 +715,17 @@ def _straw2_choose_slots(dm: _DeviceMap, bno, x, r, width=None,
             jnp.cumsum(close2.astype(jnp.int32)) > at.shape[0])
         u3 = jnp.max(jnp.where(sel2 & (uv != u2[:, None]), uv,
                                jnp.int32(-1)), axis=-1)
-        return items[rows, idx], (
-            ((u3 >= 0) & (u1 - u3 <= delta)) | unresolved)
+        return _pick(items, idx), (
+            ((u3 >= 0) & (u1 - u3 <= delta)) | unresolved), idx
     ui = u.astype(jnp.int32)
-    wi = dm.w_idx[:, :width][bno]
+    wi = rows.row("w_idx")
     q_hi = jnp.where(valid, dm.draw_hi[wi, ui], _UMAX)
     q_lo = jnp.where(valid, dm.draw_lo[wi, ui], _UMAX)
     cand = q_hi == jnp.min(q_hi, axis=-1)[:, None]
     min_lo = jnp.min(jnp.where(cand, q_lo, _UMAX), axis=-1)
     sel = cand & (q_lo == min_lo[:, None])
-    return (items[rows, jnp.argmax(sel, axis=-1)],
-            jnp.zeros(items.shape[:1], jnp.bool_))
+    idx = jnp.argmax(sel, axis=-1).astype(jnp.int32)
+    return (_pick(items, idx), jnp.zeros(items.shape[:1], jnp.bool_), idx)
 
 
 def _umulhi32(a, b):
@@ -676,28 +833,29 @@ def _uniform_choose(dm: _DeviceMap, bno, x, r):
     return dm.items[bno][perm[pr]]
 
 
-def _bucket_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
-                   resolve: bool = True):
+def _bucket_choose(dm: _DeviceMap, bno, x, r, lvl: _Level, rows: _Rows):
     """Per-alg dispatch; straw2-only maps trace straight through the
-    straw2 path with zero overhead.  `width` / `delta` / `resolve` are
-    the static per-level entries of the descent plan (straw2 only; the
-    legacy algs are rare enough to always run at full width; a vector
-    of slots resolves its first contested slot, see
-    _straw2_choose_slots).  `bno` and `r` may be vectors of one length
-    (an indep round's slots), the result is then a vector too.  Returns
-    (item, ambig); delta > 0 implies the plan proved every reachable
-    bucket at this level is straw2, so the legacy overrides below are
-    per-lane no-ops then."""
+    straw2 path with zero overhead.  `lvl` is the static entry of the
+    descent plan for this level and `rows` its reader for `bno` (straw2
+    only; the legacy algs are rare enough to always run at full width
+    and gather; a vector of slots resolves its first contested slot,
+    see _straw2_choose_slots).  `bno` and `r` may be vectors of one
+    length (an indep round's slots), the result is then a vector too.
+    Returns (item, ambig, the winner's place in its row or None); a
+    level that reads from its frontier has straw2 buckets only
+    (_level_read), and delta > 0 implies the same, so the legacy
+    overrides below are per-lane no-ops then."""
+    straw2 = dm.only_straw2 or lvl.read != "gather"
     if jnp.ndim(bno):
         # a vector of slots (an indep round)
-        if dm.only_straw2 and (delta or dm.table_mode):
-            return _straw2_choose_slots(dm, bno, x, r, width, delta)
+        if straw2 and (lvl.delta or dm.table_mode):
+            return _straw2_choose_slots(dm, x, r, rows)
         return jax.vmap(
-            lambda b, rr: _bucket_choose(dm, b, x, rr, width, delta,
-                                         resolve))(bno, r)
-    if dm.only_straw2:
-        return _straw2_choose(dm, bno, x, r, width, delta, resolve)
-    out, ambig = _straw2_choose(dm, bno, x, r, width, delta, resolve)
+            lambda b, rr: _bucket_choose(
+                dm, b, x, rr, lvl, _Rows(dm, b, lvl)))(bno, r)
+    out, ambig, idx = _straw2_choose(dm, x, r, rows)
+    if straw2:
+        return out, ambig, idx
     alg = dm.algs[bno]
     if ALG_STRAW in dm.algs_present:
         out = jnp.where(alg == ALG_STRAW, _straw_choose(dm, bno, x, r),
@@ -711,14 +869,13 @@ def _bucket_choose(dm: _DeviceMap, bno, x, r, width=None, delta: int = 0,
     if ALG_UNIFORM in dm.algs_present:
         out = jnp.where(alg == ALG_UNIFORM,
                         _uniform_choose(dm, bno, x, r), out)
-    return out, ambig
+    return out, ambig, None
 
 
-def _is_out(dev_weights, max_devices, item, x):
-    """Reweight rejection (reference: mapper.c:424-438)."""
-    wmax = dev_weights.shape[0]
-    idx = jnp.clip(item, 0, wmax - 1)
-    w = dev_weights[idx].astype(jnp.uint32)
+def _is_out(w, wmax: int, item, x):
+    """Reweight rejection (reference: mapper.c:424-438) of `item`,
+    whose device weight `w` the descent that chose it read
+    (_Rows.dev_weight); `wmax` is the length of the weight vector."""
     h = hashes.hash32_2(
         x.astype(jnp.uint32), item.astype(jnp.uint32), xp=jnp
     ) & jnp.uint32(0xFFFF)
@@ -738,6 +895,7 @@ def _descend(
     indep_numrep: Optional[object] = None,
     ftotal=None,
     plan=None,
+    dev_weights=None,
 ):
     """Walk intervening buckets until an item of want_type is chosen.
 
@@ -745,7 +903,13 @@ def _descend(
     while_loop, so under vmap every level is one wide batch of straw2
     draws.  For indep, r is recomputed per level from the current
     bucket's alg (reference: mapper.c:719-728); for firstn r_base is
-    final.  Returns (item, status).
+    final.  What a level looks up of its bucket (rows, size, the
+    winner's type and device weight) it reads as the plan's level says
+    (_Rows): from the level's static frontier where that is known and
+    small, by a gather with the bucket index otherwise.  Returns (item,
+    status, ambig, the item's device weight for _is_out where devices
+    are what is chosen: want_type 0 and `dev_weights` given, else
+    None).
     """
 
     def r_for(bno):
@@ -753,8 +917,7 @@ def _descend(
             return r_base
         numrep = indep_numrep
         if ALG_UNIFORM not in dm.algs_present:
-            # no bucket to look up: a gather from even a tiny table
-            # costs the chip as much as hashing a bucket's items
+            # no bucket's alg to look up
             return r_base + numrep * ftotal
         uniform = (dm.algs[bno] == ALG_UNIFORM) & (
             dm.sizes[bno] % jnp.maximum(numrep, 1) == 0
@@ -767,20 +930,20 @@ def _descend(
     done = jnp.asarray(False)
     status = jnp.int32(_OK)
     ambig = jnp.asarray(False)
+    if want_type != 0:
+        dev_weights = None
+    dev_w = None if dev_weights is None else jnp.uint32(0)
 
     levels = (plan if plan is not None
-              else [(dm.max_size, 0, True)] * dm.depth)
-    for width, fast_delta, resolve in levels:
-        empty = dm.sizes[bno] == 0
-        it, amb = _bucket_choose(dm, bno, x, r_for(bno), width, fast_delta,
-                                 resolve)
+              else [_Level(dm.max_size, 0, True)] * dm.depth)
+    for lvl in levels:
+        rows = _Rows(dm, bno, lvl)
+        empty = rows.empty()
+        it, amb, idx = _bucket_choose(dm, bno, x, r_for(bno), lvl, rows)
         bad_item = it >= dm.max_devices
         sub_bno = -1 - it
         valid_sub = (it < 0) & (sub_bno < dm.n_buckets)
-        itemtype = jnp.where(
-            valid_sub, dm.types[jnp.clip(sub_bno, 0, dm.n_buckets - 1)], 0
-        )
-        is_target = itemtype == want_type
+        is_target = rows.sub_type(sub_bno, valid_sub) == want_type
         # resolution order mirrors the C walk
         new_status = jnp.where(
             empty,
@@ -800,21 +963,25 @@ def _descend(
         # masked carry: lanes already done pass through unchanged
         status = jnp.where(done, status, new_status)
         item = jnp.where(done, item, new_item)
+        if dev_weights is not None:
+            dev_w = jnp.where(
+                done, dev_w, rows.dev_weight(dev_weights, it, idx))
         ambig = ambig | ((~done) & amb)
         bno = jnp.where((~done) & keep_going, sub_bno, bno)
         done = done | ~keep_going
 
     status = jnp.where(done, status, jnp.int32(_SKIP))  # depth exhausted
-    return item, status, ambig
+    return item, status, ambig, dev_w
 
 
 def _leaf_attempt(dm, dev_weights, bno, x, r, outpos, out2, plan=None):
     """One recursive chooseleaf descent attempt (type-0 target)."""
     nslots = out2.shape[0]
-    item, status, ambig = _descend(dm, bno, x, r, 0, plan=plan)
+    item, status, ambig, dev_w = _descend(
+        dm, bno, x, r, 0, plan=plan, dev_weights=dev_weights)
     collide = jnp.any((jnp.arange(nslots) < outpos) & (out2 == item))
     reject = (status == _REJECT) | _is_out(
-        dev_weights, dm.max_devices, item, x
+        dev_w, dev_weights.shape[0], item, x
     )
     skip = status == _SKIP
     fail = reject | collide
@@ -906,16 +1073,20 @@ def _choose_firstn_oneshot(
     tries=1 sequential body: retries only change results on failure,
     and failures here mean the lane is re-run by the full program."""
     reps = jnp.arange(numrep, dtype=jnp.int32)
-    items, statuses, ambigs = jax.vmap(
-        lambda r: _descend(dm, bucket_bno, x, r, want_type, plan=plan)
+    wmax = dev_weights.shape[0]
+    items, statuses, ambigs, dev_ws = jax.vmap(
+        lambda r: _descend(
+            dm, bucket_bno, x, r, want_type, plan=plan,
+            dev_weights=dev_weights)
     )(reps)
     ambig_any = jnp.any(ambigs)
     if recurse_to_leaf:
         sub_rs = (reps >> (vary_r - 1)) if vary_r else jnp.zeros_like(reps)
         # stable profile: leaf rep is 0 for every slot
-        leaf_items, leaf_statuses, leaf_ambigs = jax.vmap(
+        leaf_items, leaf_statuses, leaf_ambigs, leaf_ws = jax.vmap(
             lambda it, sr: _descend(
-                dm, -1 - jnp.minimum(it, -1), x, sr, 0, plan=leaf_plan)
+                dm, -1 - jnp.minimum(it, -1), x, sr, 0, plan=leaf_plan,
+                dev_weights=dev_weights)
         )(items, sub_rs)
         # dummy descents (item not a bucket) carry no real ambiguity
         ambig_any = ambig_any | jnp.any(leaf_ambigs & (items < 0))
@@ -935,14 +1106,14 @@ def _choose_firstn_oneshot(
             l_collide = jnp.any((jnp.arange(numrep) < outpos)
                                 & (out2 == l_item))
             l_ok = ((l_status == _OK) & (~l_collide)
-                    & ~_is_out(dev_weights, dm.max_devices, l_item, x))
+                    & ~_is_out(leaf_ws[rep], wmax, l_item, x))
             leaf = jnp.where(is_bucket, l_item, item)
             leaf_fail = is_bucket & (~l_ok) & (~collide) & (status == _OK)
             reject = reject | leaf_fail
         if want_type == 0:
             reject = reject | (
                 (status == _OK) & (~collide)
-                & _is_out(dev_weights, dm.max_devices, item, x))
+                & _is_out(dev_ws[rep], wmax, item, x))
         placed = (status == _OK) & (~reject) & (~collide) & (~skip)
         out = jnp.where(placed, out.at[outpos].set(item), out)
         out2 = jnp.where(placed, out2.at[outpos].set(leaf), out2)
@@ -993,8 +1164,9 @@ def _choose_firstn(
         def body(c, rep=rep):
             ftotal, item_prev, leaf_prev, placed, give_up, amb0 = c
             r = rep + ftotal
-            item, status, amb = _descend(dm, bucket_bno, x, r, want_type,
-                                         plan=plan)
+            item, status, amb, dev_w = _descend(
+                dm, bucket_bno, x, r, want_type, plan=plan,
+                dev_weights=dev_weights)
             collide = jnp.any((jnp.arange(numrep) < outpos) & (out == item))
             reject = status == _REJECT
             skip = status == _SKIP
@@ -1015,7 +1187,7 @@ def _choose_firstn(
                 reject = reject | (
                     (status == _OK)
                     & (~collide)
-                    & _is_out(dev_weights, dm.max_devices, item, x)
+                    & _is_out(dev_w, dev_weights.shape[0], item, x)
                 )
             fail = reject | collide
             nf = ftotal + 1
@@ -1068,12 +1240,13 @@ def _leaf_indep_try(dm, dev_weights, bno, x, numrep, parent_r, ftotal,
     `bno`: r' = parent_r + numrep * ftotal.  bno, parent_r and ftotal
     may be vectors (a round's slots).  Returns (device or ITEM_UNDEF,
     ambig)."""
-    item, status, amb = _descend(
+    item, status, amb, dev_w = _descend(
         dm, bno, x, parent_r, 0,
         indep_numrep=jnp.int32(numrep), ftotal=ftotal, plan=plan,
+        dev_weights=dev_weights,
     )
     bad = status != _OK
-    outed = _is_out(dev_weights, dm.max_devices, item, x)
+    outed = _is_out(dev_w, dev_weights.shape[0], item, x)
     return jnp.where(bad | outed, ITEM_UNDEF, item), amb
 
 
@@ -1177,9 +1350,10 @@ def _choose_indep(
         """`reps` [S]: the slots this round descends for, in slot
         order; `live` [S]: which of them are real.  leaf_retries=None:
         every slot's leaf recursion runs all its tries."""
-        items, statuses, ambs = _descend(
+        items, statuses, ambs, dev_ws = _descend(
             dm, jnp.broadcast_to(bucket_bno, reps.shape), x, reps,
-            want_type, indep_numrep=nrep, ftotal=ftotal, plan=plan)
+            want_type, indep_numrep=nrep, ftotal=ftotal, plan=plan,
+            dev_weights=dev_weights)
         poison = jnp.asarray(False)
         if recurse_to_leaf:
             is_bucket = items < 0
@@ -1213,7 +1387,7 @@ def _choose_indep(
             ambs = ambs | (leaf_ambs & is_bucket)
         if want_type == 0:
             outed = (statuses == _OK) & _is_out(
-                dev_weights, dm.max_devices, items, x)
+                dev_ws, dev_weights.shape[0], items, x)
         for k in range(reps.shape[0]):
             rep, item, status = reps[k], items[k], statuses[k]
             mine = slot_ids == rep
@@ -1294,6 +1468,46 @@ def _rule_digest(flat: FlatMap, steps, result_max: int,
 
 _compiled_rules: dict = {}  # digest -> compiled fn (process lifetime)
 
+_CHOOSE_OPS = (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN,
+               OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP)
+
+
+def _choose_plans(dm: _HostMap, steps, result_max: int, budget_val: int):
+    """[(descent plan, leaf plan or None)] for the choose steps a rule
+    runs, in their order, on the host.  The static frontier is the set
+    of buckets a choose could start from, known at trace time (take
+    args are static; after a typed choose, every bucket of that type):
+    it drives the per-level widths, depths and reads of the plans.
+
+    fastcmp deltas only in budgeted traces; the full program must stay
+    exact standalone (it is the final stage unclean lanes re-run
+    through).  With the table_mode top-2 exact resolution the fastcmp
+    draw is exact except for 3-candidates-in-window (~1e-5), so the mid
+    stage keeps it too.  The firstn one-shot pass has that stage behind
+    it and only flags a contested draw: no gather from the draw tables
+    on its lanes (sweep_plan counts the share it flags)."""
+    plans, static_frontier = [], None
+    for op, arg1, arg2 in steps:
+        if op == OP_TAKE:
+            static_frontier = [-1 - arg1]
+        elif op in _CHOOSE_OPS and (
+                arg1 if arg1 > 0 else result_max + arg1) > 0:
+            firstn = op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN)
+            kw = dict(fastcmp=budget_val > 0,
+                      resolve=not (firstn and budget_val == 1))
+            leaf_plan = None
+            if op in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP) \
+                    and arg2 > 0:
+                # the leaf recursion starts from a bucket of type arg2
+                # (whichever one the outer choose picked)
+                leaf_plan = _descent_plan(
+                    dm, dm.buckets_of_type(arg2), 0, **kw)
+            plans.append(
+                (_descent_plan(dm, static_frontier, arg2, **kw), leaf_plan))
+            # after this choose the walk holds items of type arg2
+            static_frontier = dm.buckets_of_type(arg2) if arg2 > 0 else None
+    return plans
+
 
 def compile_rule(
     flat: FlatMap,
@@ -1343,6 +1557,11 @@ def compile_rule(
     theirs); without it every round runs every slot with all its leaf
     tries.
 
+    The returned callable's `levels` counts the descent levels of the
+    program's plans by how each reads its bucket rows (_Rows): {"const",
+    "onehot", "gather"}; sweep_device puts the counts of the stage
+    programs it ran on its span.
+
     Compiled programs are cached process-wide by map content: rebuilding
     an identical map (common in tests and in OSDMap churn that leaves
     the crush tree untouched) costs a digest, not a ~10s XLA compile.
@@ -1362,6 +1581,7 @@ def compile_rule(
     dm = _DeviceMap(flat, choose_args)
     tun = flat.tunables
     steps = [tuple(int(v) for v in s) for s in steps]
+    plans = _choose_plans(dm, steps, result_max, budget_val)
 
     def one_x(x, dev_weights):
         x = x.astype(jnp.int32)
@@ -1376,30 +1596,20 @@ def compile_rule(
         vary_r = tun.chooseleaf_vary_r
         stable = tun.chooseleaf_stable
         wsize_bound = 0  # static upper bound on wsize, tracked at trace time
-        # static frontier: the set of buckets the NEXT choose could
-        # start from, known at trace time (take args are static; after
-        # a typed choose, every bucket of that type).  Drives the
-        # per-level width/depth descent plans.
-        static_frontier = None
+        step_plans = iter(plans)
 
         for op, arg1, arg2 in steps:
             if op == OP_TAKE:
                 w_buf = w_buf.at[0].set(arg1)
                 wsize = jnp.int32(1)
                 wsize_bound = 1
-                static_frontier = [-1 - arg1]
             elif op == OP_SET_CHOOSE_TRIES:
                 if arg1 > 0:
                     choose_tries = arg1
             elif op == OP_SET_CHOOSELEAF_TRIES:
                 if arg1 > 0:
                     choose_leaf_tries = arg1
-            elif op in (
-                OP_CHOOSE_FIRSTN,
-                OP_CHOOSELEAF_FIRSTN,
-                OP_CHOOSE_INDEP,
-                OP_CHOOSELEAF_INDEP,
-            ):
+            elif op in _CHOOSE_OPS:
                 firstn = op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN)
                 recurse = op in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP)
                 numrep = arg1 if arg1 > 0 else result_max + arg1
@@ -1424,30 +1634,7 @@ def compile_rule(
                 else:
                     use_tries, use_recurse, use_unroll = (
                         choose_tries, recurse_tries, 0)
-                # fastcmp deltas only in budgeted traces; the full
-                # program must stay exact standalone (it is the final
-                # stage unclean lanes re-run through).  With the
-                # table_mode top-2 exact resolution the fastcmp draw is
-                # exact except for 3-candidates-in-window (~1e-5), so
-                # the mid stage keeps it too.  The firstn one-shot pass
-                # has that stage behind it and only flags a contested
-                # draw: no gather from the draw tables on its lanes
-                # (sweep_plan counts the share it flags).
-                fc = budget_val > 0
-                resolve = not (firstn and budget_val == 1)
-                plan = (_descent_plan(dm, static_frontier, arg2,
-                                      fastcmp=fc, resolve=resolve)
-                        if static_frontier is not None else None)
-                leaf_plan = None
-                if recurse and arg2 > 0:
-                    # the leaf recursion starts from a bucket of type
-                    # arg2 (whichever one the outer choose picked)
-                    leaf_plan = _descent_plan(
-                        dm, dm.buckets_of_type(arg2), 0,
-                        fastcmp=fc, resolve=resolve)
-                # after this choose the walk holds items of type arg2
-                static_frontier = (
-                    dm.buckets_of_type(arg2) if arg2 > 0 else None)
+                plan, leaf_plan = next(step_plans)
 
                 o_buf = jnp.full((result_max,), ITEM_NONE, dtype=jnp.int32)
                 osize = jnp.int32(0)
@@ -1528,6 +1715,10 @@ def compile_rule(
             jnp.asarray(dev_weights, dtype=jnp.uint32),
         )
 
+    # the program's levels by how each reads its bucket rows (_Rows)
+    run.levels = {read: sum(lvl.read == read for pair in plans
+                            for plan in pair if plan for lvl in plan)
+                  for read in ("const", "onehot", "gather")}
     _compiled_rules[digest] = run
     if len(_compiled_rules) > 256:  # bound trace/executable retention
         _compiled_rules.pop(next(iter(_compiled_rules)))
@@ -1614,9 +1805,7 @@ def _retry_model(flat: FlatMap, steps, result_max: int, dev_weights,
             leaf_tries = arg1
         elif op == OP_TAKE and take is None and choose is None:
             take = arg1
-        elif op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN,
-                    OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP) \
-                and take is not None and choose is None:
+        elif op in _CHOOSE_OPS and take is not None and choose is None:
             choose = (op, arg1, arg2)
         elif op != OP_EMIT or choose is None:
             return None
@@ -1701,7 +1890,7 @@ def _contested_share(flat: FlatMap, steps, numrep: int,
     if op == OP_CHOOSELEAF_FIRSTN and want > 0:
         levels = levels + _descent_plan(
             hm, hm.buckets_of_type(want), 0, fastcmp=True)
-    return numrep * sum(w * d for w, d, _ in levels) / 65536.0
+    return numrep * sum(lv.width * lv.delta for lv in levels) / 65536.0
 
 
 def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
@@ -1797,8 +1986,7 @@ def sweep_totals() -> dict:
 def _rule_shape(steps, result_max: int):
     """("firstn" | "indep", numrep) of the rule's first choose."""
     for op, arg1, _ in steps:
-        if op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN,
-                  OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP):
+        if op in _CHOOSE_OPS:
             indep = op in (OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP)
             return ("indep" if indep else "firstn",
                     min(arg1 if arg1 > 0 else result_max + arg1, result_max))
@@ -1989,6 +2177,11 @@ def _device_runner(flat, steps, result_max, choose_args, n: int,
                     0, (n_slow + (_SLOW_BATCH - 1)) // _SLOW_BATCH, fix, out)
             return out, overflow | (n3 > cap2), n_mid, n_slow
 
+        # the levels of the stage programs it runs, by how each reads
+        # its bucket rows (compile_rule)
+        run.levels = {read: sum(prog.levels[read]
+                                for prog in (fast, mid, slow) if prog)
+                      for read in mid.levels}
         _compiled_rules[key] = run
         if len(_compiled_rules) > 256:
             _compiled_rules.pop(next(iter(_compiled_rules)))
@@ -2072,7 +2265,8 @@ def sweep_device(
                          cap, cap2, plan, with_fast)
     mode, numrep = _rule_shape(steps, result_max)
     with tracing.span("crush.sweep", ids=n, chunk=chunk, numrep=numrep,
-                      mode=mode, cap=cap, cap2=cap2, budget=plan.budget):
+                      mode=mode, cap=cap, cap2=cap2, budget=plan.budget,
+                      **run.levels):
         out, overflow, n_mid, n_slow = run(
             xs, jnp.asarray(dev_weights, dtype=jnp.uint32))
     _totals["crush.ids"] += n

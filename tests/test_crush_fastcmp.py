@@ -90,10 +90,12 @@ def test_fastcmp_choose_matches_table_choose():
     @jax.jit
     def both(xs):
         def one(x):
-            fast_it, amb = mapper._straw2_choose(
-                dm, jnp.int32(host0), x, jnp.int32(0), width, delta=2)
-            tab_it, _ = mapper._straw2_choose(
-                dm, jnp.int32(host0), x, jnp.int32(0), width, delta=0)
+            fast_it, amb, _ = mapper._straw2_choose(
+                dm, x, jnp.int32(0), mapper._Rows(
+                    dm, jnp.int32(host0), mapper._Level(width, 2, True)))
+            tab_it, _, _ = mapper._straw2_choose(
+                dm, x, jnp.int32(0), mapper._Rows(
+                    dm, jnp.int32(host0), mapper._Level(width, 0, True)))
             return fast_it, tab_it, amb
         return jax.vmap(one)(xs)
 
@@ -153,11 +155,12 @@ def test_flag_only_choose_matches_table_choose(width):
     @jax.jit
     def both(xs, rs):
         def one(x, r):
-            flag_it, amb = mapper._straw2_choose(
-                dm, jnp.int32(bno), x, r, width, delta=delta,
-                resolve=False)
-            tab_it, _ = mapper._straw2_choose(
-                dm, jnp.int32(bno), x, r, width, delta=0)
+            flag_it, amb, _ = mapper._straw2_choose(
+                dm, x, r, mapper._Rows(
+                    dm, jnp.int32(bno), mapper._Level(width, delta, False)))
+            tab_it, _, _ = mapper._straw2_choose(
+                dm, x, r, mapper._Rows(
+                    dm, jnp.int32(bno), mapper._Level(width, 0, True)))
             return flag_it, tab_it, amb
         return jax.vmap(one)(xs, rs)
 
@@ -209,7 +212,9 @@ def _program_consts(fn, *args):
 def test_one_shot_firstn_program_holds_no_draw_table():
     """The flat benchmark map's one-shot program reads neither draw_hi,
     draw_lo nor w_idx (they are not among the arrays it closes over);
-    the budgeted and the exact programs hold all three."""
+    the budgeted and the exact programs hold the two draw tables, and
+    not w_idx either: the map has one distinct weight, so which table
+    an item draws from is a constant of each level."""
     m, root = cmap.build_flat_cluster(1024, hosts=64)
     steps = [(cmap.OP_TAKE, root, 0), (cmap.OP_CHOOSELEAF_FIRSTN, 3, 1),
              (cmap.OP_EMIT, 0, 0)]
@@ -228,14 +233,15 @@ def test_one_shot_firstn_program_holds_no_draw_table():
             and np.array_equal(np.asarray(c), tab) for c in consts)}
 
     assert held(one_shot=True) == set()
-    assert held(one_shot=True, budget=mapper.MID_BUDGET) == set(tables)
-    assert held() == set(tables)
+    assert held(one_shot=True, budget=mapper.MID_BUDGET) == {
+        "draw_hi", "draw_lo"}
+    assert held() == {"draw_hi", "draw_lo"}
     # what the plans say: the one-shot pass flags, the budgeted stage
     # settles, the exact program has no fastcmp level
     for kw, want in ((dict(fastcmp=True, resolve=False), (2, False)),
                      (dict(fastcmp=True), (2, True)), ({}, (0, True))):
         plan = mapper._descent_plan(dm, [-1 - root], 1, **kw)
-        assert [lv[1:] for lv in plan] == [want]
+        assert [lv[1:3] for lv in plan] == [want]
 
 
 def test_staged_sweeps_exact_with_contested_draws():

@@ -252,8 +252,12 @@ def test_the_sweep_span_says_what_ran():
                         chunk=1024)
     span, = [r for r in tracing.recorder().held()[0][n0:]
              if r[NAME] == "crush.sweep"]
+    # no one-shot pass: the budgeted and the exact program, each with
+    # the root (one bucket: constants) and the racks and the hosts
+    # (read by their place in the level's frontier); nothing gathers
     assert span[COUNTS] == {
         "ids": 2048, "chunk": 1024, "numrep": 6, "mode": "indep",
-        "cap": 1024, "cap2": 2048, "budget": plan.budget}
+        "cap": 1024, "cap2": 2048, "budget": plan.budget,
+        "const": 2, "onehot": 4, "gather": 0}
     assert {"crush.sweep", "crush.ids", "crush.mid_lanes",
             "crush.slow_lanes"} <= set(tracing.SPANS)

@@ -390,9 +390,12 @@ def test_named_scopes_leave_sweep_device_bit_identical():
     np.testing.assert_array_equal(np.asarray(got), want)
     new = tracing.recorder().held()[0][n0:]
     sweep, = [r for r in new if r[NAME] == "crush.sweep"]
+    # three stage programs, each with the root's level (constants) and
+    # the hosts' (read by their place in the frontier)
     assert sweep[COUNTS] == {"ids": 1024, "chunk": 1024, "numrep": 3,
                              "mode": "firstn", "cap": 1024, "cap2": 1024,
-                             "budget": 3}
+                             "budget": 3, "const": 3, "onehot": 3,
+                             "gather": 0}
     assert any(r[NAME] == "dev.dispatch" and r[PARENT] == sweep[ID]
                and r[COUNTS]["family"] == "crush_mapper" for r in new)
     # the three stage programs sit under their scopes inside the one
