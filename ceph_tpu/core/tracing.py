@@ -140,13 +140,21 @@ SPANS: Dict[str, str] = {
     #   (lanes the budgeted and the exact stage can take), budget; and the
     #   descent levels of the stage programs it ran by how each reads its
     #   bucket rows: const, onehot (from the level's static frontier),
-    #   gather (by the bucket index)
+    #   gather (by the bucket index); and by how each draws: draw_fast
+    #   (fastcmp: the max-hash item, delta > 0), draw_table (every item
+    #   through the draw tables), draw_limb (every item by the u32-limb
+    #   division)
     # the mapper's monotonic totals (mapper.sweep_totals(): counters, not
     # spans, registered here so that their names are held to one table)
     "crush.ids": "crush_mid_lanes_per_id",          # ids swept: the divisor
     #   of both ratios
     "crush.mid_lanes": "crush_mid_lanes_per_id",    # lanes into stage 2
     "crush.slow_lanes": "crush_slow_lanes_per_id",  # lanes into stage 3
+    "crush.full_draws": "crush_full_draws_per_id",  # bucket items whose
+    #   true straw2 draw (table or limb path) the stage programs computed:
+    #   a stage program's full draws a lane, from static counts, times the
+    #   lanes that entered the stage; the exact stage's rolled retry loop
+    #   counts at one pass of its body, so its part is a lower bound
 }
 
 # a concluded op's timeline, filed by OpTracker.unregister: read by

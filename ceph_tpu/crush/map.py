@@ -14,7 +14,7 @@ are negative, bucket id b lives at flat index -1-b.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -225,9 +225,25 @@ class CrushMap:
         b.weights.append(weight)
 
     def reweight_item(self, bucket_id: int, item: int, weight: int) -> None:
+        """Set one entry of one bucket; the bucket's ancestors keep the
+        sums they had (adjust_item_weight carries a change up)."""
         b = self.buckets[bucket_id]
         i = b.items.index(item)
         b.weights[i] = weight
+
+    def adjust_item_weight(self, item: int, weight: int) -> int:
+        """`ceph osd crush reweight` / `crushtool --reweight-item`
+        (reference: CrushWrapper::adjust_item_weight ->
+        adjust_item_weight_in_bucket -> bucket_adjust_item_weight): set
+        `item`'s weight in every bucket that holds it, and carry each
+        such bucket's new sum up as its own weight in the bucket that
+        holds it, through every ancestor.  Returns how many buckets
+        hold the item (0: nothing changed)."""
+        holders = [bid for bid, b in self.buckets.items() if item in b.items]
+        for bid in holders:
+            self.reweight_item(bid, item, weight)
+            self.adjust_item_weight(bid, self.buckets[bid].weight)
+        return len(holders)
 
     def remove_item(self, bucket_id: int, item: int) -> None:
         b = self.buckets[bucket_id]
@@ -339,11 +355,13 @@ class CrushMap:
 def build_layered_cluster(
     n_osds: int,
     layers: Sequence[Tuple[int, int]],
-    osd_weight: int = 0x10000,
+    osd_weight: Union[int, Sequence[int]] = 0x10000,
 ) -> Tuple[CrushMap, List[List[int]]]:
     """`crushtool --build --num_osds N <name> straw2 <size> ...`
     (reference: src/tools/crushtool.cc:112-218, the `--build` loop):
-    ``layers`` lists (type id, size) from the devices upward.  Each
+    ``layers`` lists (type id, size) from the devices upward;
+    ``osd_weight`` is every device's 16.16 weight, or one for each
+    device (the map `--reweight-item` leaves of a `--build`).  Each
     layer groups the items of the one below, in order, into straw2
     buckets of ``size`` items (the last may be short; size 0 puts all
     of them into one bucket); a bucket's weight as an item is the sum
@@ -353,7 +371,13 @@ def build_layered_cluster(
     the last layer's size is 0."""
     m = CrushMap()
     lower = list(range(n_osds))
-    lower_w = [osd_weight] * n_osds
+    if isinstance(osd_weight, (int, np.integer)):
+        lower_w = [int(osd_weight)] * n_osds
+    else:
+        lower_w = [int(w) for w in osd_weight]
+        if len(lower_w) != n_osds:
+            raise ValueError(
+                f"{len(lower_w)} device weights for {n_osds} devices")
     ids: List[List[int]] = []
     for type_id, size in layers:
         size = size or len(lower)

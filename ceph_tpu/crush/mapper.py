@@ -1472,12 +1472,64 @@ _CHOOSE_OPS = (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN,
                OP_CHOOSE_INDEP, OP_CHOOSELEAF_INDEP)
 
 
-def _choose_plans(dm: _HostMap, steps, result_max: int, budget_val: int):
-    """[(descent plan, leaf plan or None)] for the choose steps a rule
-    runs, in their order, on the host.  The static frontier is the set
-    of buckets a choose could start from, known at trace time (take
-    args are static; after a typed choose, every bucket of that type):
-    it drives the per-level widths, depths and reads of the plans.
+class _Choose(NamedTuple):
+    """One choose step of a rule as a stage program runs it, all of it
+    static: what compile_rule unrolls and what the span and
+    `crush.full_draws` count."""
+
+    firstn: bool
+    recurse: bool        # chooseleaf
+    numrep: int
+    tries: int
+    recurse_tries: int
+    unroll: int          # attempts a retry loop is unrolled to; 0: rolled
+    rounds: Optional[tuple]  # an indep choose's budgeted rounds
+    oneshot: bool        # firstn: the rep-vectorized one-attempt pass
+    sources: int         # most buckets the step can start from
+    plan: list           # the descent's levels (_descent_plan)
+    leaf_plan: Optional[list]  # the leaf recursion's, or None
+
+    def descents(self):
+        """(outer, leaf) descents a lane makes for one source bucket,
+        a rolled loop (while_loop, fori_loop) counted at one pass of
+        its body."""
+        n = self.numrep
+        if self.firstn:
+            if self.oneshot:
+                return n, n * self.recurse
+            outer = n * (min(self.unroll, self.tries)
+                         if self.unroll and self.tries > 1 else 1)
+            return outer, outer * self.recurse * (
+                min(self.unroll, self.recurse_tries)
+                if self.unroll and self.recurse_tries > 1 else 1)
+        if self.rounds is None:
+            return n, n * self.recurse
+        outer = leaf = 0
+        for k, (width, leaf_retries) in enumerate(self.rounds[:self.tries]):
+            slots = n if k == 0 or width >= n else width
+            outer += slots
+            leaf += slots + min(leaf_retries, slots) * (
+                self.recurse_tries - 1)
+        return outer, leaf * self.recurse
+
+    def full_draws(self) -> int:
+        """Bucket items whose true straw2 draw (table or limb path) a
+        lane computes in this step: the widths of its levels without a
+        fastcmp window, times the descents through them."""
+        outer, leaf = self.descents()
+        return self.sources * (
+            outer * sum(lv.width for lv in self.plan if not lv.delta)
+            + leaf * sum(lv.width for lv in self.leaf_plan or ()
+                         if not lv.delta))
+
+
+def _choose_plans(dm: _HostMap, steps, result_max: int, budget_val: int,
+                  tun, rounds=None):
+    """[_Choose] for the choose steps a rule runs, in their order, on
+    the host.  The static frontier is the set of buckets a choose could
+    start from, known at trace time (take args are static; after a
+    typed choose, every bucket of that type): it drives the per-level
+    widths, depths and reads of the plans.
 
     fastcmp deltas only in budgeted traces; the full program must stay
     exact standalone (it is the final stage unclean lanes re-run
@@ -1485,28 +1537,81 @@ def _choose_plans(dm: _HostMap, steps, result_max: int, budget_val: int):
     draw is exact except for 3-candidates-in-window (~1e-5), so the mid
     stage keeps it too.  The firstn one-shot pass has that stage behind
     it and only flags a contested draw: no gather from the draw tables
-    on its lanes (sweep_plan counts the share it flags)."""
-    plans, static_frontier = [], None
+    on its lanes (sweep_plan counts the share it flags).
+
+    budget_val 1 is the one-shot shape, a single inline attempt;
+    above 1 the budgeted stage, real retry semantics statically
+    unrolled to budget attempts; 0 the exact program."""
+    chooses, static_frontier = [], None
+    choose_tries = tun.choose_total_tries + 1
+    choose_leaf_tries = 0
+    sources = 0  # static upper bound on the working set's size
     for op, arg1, arg2 in steps:
         if op == OP_TAKE:
             static_frontier = [-1 - arg1]
+            sources = 1
+        elif op == OP_SET_CHOOSE_TRIES:
+            if arg1 > 0:
+                choose_tries = arg1
+        elif op == OP_SET_CHOOSELEAF_TRIES:
+            if arg1 > 0:
+                choose_leaf_tries = arg1
         elif op in _CHOOSE_OPS and (
                 arg1 if arg1 > 0 else result_max + arg1) > 0:
             firstn = op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN)
+            recurse = op in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP)
+            numrep = min(arg1 if arg1 > 0 else result_max + arg1,
+                         result_max)
+            if firstn:
+                recurse_tries = choose_leaf_tries or (
+                    1 if tun.chooseleaf_descend_once else choose_tries)
+            else:
+                recurse_tries = choose_leaf_tries or 1
             kw = dict(fastcmp=budget_val > 0,
                       resolve=not (firstn and budget_val == 1))
             leaf_plan = None
-            if op in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP) \
-                    and arg2 > 0:
+            if recurse and arg2 > 0:
                 # the leaf recursion starts from a bucket of type arg2
                 # (whichever one the outer choose picked)
                 leaf_plan = _descent_plan(
                     dm, dm.buckets_of_type(arg2), 0, **kw)
-            plans.append(
-                (_descent_plan(dm, static_frontier, arg2, **kw), leaf_plan))
+            chooses.append(_Choose(
+                firstn=firstn, recurse=recurse, numrep=numrep,
+                tries=1 if budget_val == 1 else choose_tries,
+                recurse_tries=1 if budget_val == 1 else recurse_tries,
+                unroll=budget_val if budget_val > 1 else 0,
+                # budgeted traces unroll their rounds: the one-shot
+                # pass is round 0 alone, the mid stage takes the sweep
+                # plan's shape or, without one, every slot and leaf try
+                # in every round
+                rounds=None if firstn or not budget_val else (
+                    rounds or ((numrep, numrep),) * budget_val),
+                oneshot=firstn and budget_val == 1 and bool(
+                    tun.chooseleaf_stable or not recurse),
+                sources=min(sources, result_max),
+                plan=_descent_plan(dm, static_frontier, arg2, **kw),
+                leaf_plan=leaf_plan))
             # after this choose the walk holds items of type arg2
             static_frontier = dm.buckets_of_type(arg2) if arg2 > 0 else None
-    return plans
+            sources = min(result_max, sources * numrep)
+    return chooses
+
+
+def _level_counts(dm: _HostMap, chooses):
+    """The descent levels of a program's plans, counted by how each
+    reads its bucket rows (_Rows: const, onehot, gather) and by how
+    each draws (_straw2_choose: draw_fast with a fastcmp window,
+    draw_table every item through the draw tables, draw_limb every item
+    by the u32-limb division): (reads, draws)."""
+    levels = [lvl for ch in chooses for plan in (ch.plan, ch.leaf_plan)
+              if plan for lvl in plan]
+    full = "draw_table" if dm.table_mode else "draw_limb"
+    reads = dict.fromkeys(("const", "onehot", "gather"), 0)
+    draws = dict.fromkeys(("draw_fast", "draw_table", "draw_limb"), 0)
+    for lvl in levels:
+        reads[lvl.read] += 1
+        draws["draw_fast" if lvl.delta else full] += 1
+    return reads, draws
 
 
 def compile_rule(
@@ -1558,9 +1663,13 @@ def compile_rule(
     tries.
 
     The returned callable's `levels` counts the descent levels of the
-    program's plans by how each reads its bucket rows (_Rows): {"const",
-    "onehot", "gather"}; sweep_device puts the counts of the stage
-    programs it ran on its span.
+    program's plans by how each reads its bucket rows (_Rows: "const",
+    "onehot", "gather"), its `draws` by how each draws ("draw_fast",
+    "draw_table", "draw_limb", see _level_counts); its `full_draws` is
+    the number of bucket items whose true straw2 draw a lane computes
+    (_Choose.full_draws).  sweep_device puts both counts of the stage
+    programs it ran on its span and files the full draws for
+    sweep_totals().
 
     Compiled programs are cached process-wide by map content: rebuilding
     an identical map (common in tests and in OSDMap churn that leaves
@@ -1581,7 +1690,7 @@ def compile_rule(
     dm = _DeviceMap(flat, choose_args)
     tun = flat.tunables
     steps = [tuple(int(v) for v in s) for s in steps]
-    plans = _choose_plans(dm, steps, result_max, budget_val)
+    chooses = _choose_plans(dm, steps, result_max, budget_val, tun, rounds)
 
     def one_x(x, dev_weights):
         x = x.astype(jnp.int32)
@@ -1591,87 +1700,54 @@ def compile_rule(
         result_len = jnp.int32(0)
         clean = jnp.asarray(True)  # every choose succeeded first try
 
-        choose_tries = tun.choose_total_tries + 1
-        choose_leaf_tries = 0
         vary_r = tun.chooseleaf_vary_r
         stable = tun.chooseleaf_stable
         wsize_bound = 0  # static upper bound on wsize, tracked at trace time
-        step_plans = iter(plans)
+        step_chooses = iter(chooses)
 
         for op, arg1, arg2 in steps:
             if op == OP_TAKE:
                 w_buf = w_buf.at[0].set(arg1)
                 wsize = jnp.int32(1)
                 wsize_bound = 1
-            elif op == OP_SET_CHOOSE_TRIES:
-                if arg1 > 0:
-                    choose_tries = arg1
-            elif op == OP_SET_CHOOSELEAF_TRIES:
-                if arg1 > 0:
-                    choose_leaf_tries = arg1
             elif op in _CHOOSE_OPS:
-                firstn = op in (OP_CHOOSE_FIRSTN, OP_CHOOSELEAF_FIRSTN)
-                recurse = op in (OP_CHOOSELEAF_FIRSTN, OP_CHOOSELEAF_INDEP)
-                numrep = arg1 if arg1 > 0 else result_max + arg1
-                if numrep <= 0:
+                if (arg1 if arg1 > 0 else result_max + arg1) <= 0:
                     continue
-                numrep = min(numrep, result_max)
-                if firstn:
-                    recurse_tries = (
-                        choose_leaf_tries
-                        or (1 if tun.chooseleaf_descend_once else choose_tries)
-                    )
-                else:
-                    recurse_tries = choose_leaf_tries or 1
-                if budget_val == 1:
-                    # legacy one-shot shape: single inline attempt
-                    use_tries, use_recurse, use_unroll = 1, 1, 0
-                elif budget_val > 1:
-                    # bounded-budget mid stage: real retry semantics,
-                    # statically unrolled to budget attempts
-                    use_tries, use_recurse, use_unroll = (
-                        choose_tries, recurse_tries, budget_val)
-                else:
-                    use_tries, use_recurse, use_unroll = (
-                        choose_tries, recurse_tries, 0)
-                plan, leaf_plan = next(step_plans)
+                ch = next(step_chooses)
+                numrep = ch.numrep
 
                 o_buf = jnp.full((result_max,), ITEM_NONE, dtype=jnp.int32)
                 osize = jnp.int32(0)
-                # sources are w_buf[:wsize]; wsize_bound keeps the unroll
-                # tight for the common take->choose->emit shape (1 source)
-                for i in range(min(wsize_bound, result_max)):
+                # sources are w_buf[:wsize]; the static bound keeps the
+                # unroll tight for the common take->choose->emit shape
+                # (1 source)
+                for i in range(ch.sources):
                     src_active = jnp.int32(i) < wsize
                     bno = -1 - w_buf[i]
                     bno_ok = (bno >= 0) & (bno < dm.n_buckets)
                     active = src_active & bno_ok
                     bno_safe = jnp.clip(bno, 0, dm.n_buckets - 1)
-                    if firstn:
-                        if budget_val == 1 and (stable or not recurse):
-                            # rep-vectorized fast pass (see helper)
-                            vals, cnt, amb = _choose_firstn_oneshot(
-                                dm, dev_weights, bno_safe, x, numrep,
-                                arg2, recurse, vary_r, plan, leaf_plan,
-                            )
-                        else:
-                            vals, cnt, amb = _choose_firstn(
-                                dm, dev_weights, bno_safe, x, numrep,
-                                arg2, use_tries, use_recurse, recurse,
-                                vary_r, stable, plan, leaf_plan,
-                                use_unroll,
-                            )
+                    if ch.oneshot:
+                        # rep-vectorized fast pass (see helper)
+                        vals, cnt, amb = _choose_firstn_oneshot(
+                            dm, dev_weights, bno_safe, x, numrep,
+                            arg2, ch.recurse, vary_r, ch.plan,
+                            ch.leaf_plan,
+                        )
+                        step_clean = (cnt == numrep) & (~amb)
+                    elif ch.firstn:
+                        vals, cnt, amb = _choose_firstn(
+                            dm, dev_weights, bno_safe, x, numrep,
+                            arg2, ch.tries, ch.recurse_tries, ch.recurse,
+                            vary_r, stable, ch.plan, ch.leaf_plan,
+                            ch.unroll,
+                        )
                         step_clean = (cnt == numrep) & (~amb)
                     else:
-                        # budgeted traces unroll their rounds: the
-                        # one-shot pass is round 0 alone, the mid stage
-                        # takes the sweep plan's shape or, without
-                        # one, every slot and leaf try in every round
                         vals, cnt, amb = _choose_indep(
                             dm, dev_weights, bno_safe, x, numrep, numrep,
-                            arg2, use_tries, use_recurse, recurse,
-                            plan, leaf_plan,
-                            (rounds or ((numrep, numrep),) * budget_val)
-                            if budget_val else None,
+                            arg2, ch.tries, ch.recurse_tries, ch.recurse,
+                            ch.plan, ch.leaf_plan, ch.rounds,
                         )
                         step_clean = jnp.all(vals != ITEM_NONE) & (~amb)
                     clean = clean & ((~active) | step_clean)
@@ -1689,7 +1765,7 @@ def compile_rule(
                         osize = osize + valid.astype(jnp.int32)
                 w_buf = o_buf
                 wsize = osize
-                wsize_bound = min(result_max, wsize_bound * numrep)
+                wsize_bound = min(result_max, ch.sources * numrep)
             elif op == OP_EMIT:
                 for i in range(min(wsize_bound, result_max)):
                     valid = (jnp.int32(i) < wsize) & (result_len < result_max)
@@ -1715,10 +1791,8 @@ def compile_rule(
             jnp.asarray(dev_weights, dtype=jnp.uint32),
         )
 
-    # the program's levels by how each reads its bucket rows (_Rows)
-    run.levels = {read: sum(lvl.read == read for pair in plans
-                            for plan in pair if plan for lvl in plan)
-                  for read in ("const", "onehot", "gather")}
+    run.levels, run.draws = _level_counts(dm, chooses)
+    run.full_draws = sum(ch.full_draws() for ch in chooses)
     _compiled_rules[digest] = run
     if len(_compiled_rules) > 256:  # bound trace/executable retention
         _compiled_rules.pop(next(iter(_compiled_rules)))
@@ -1965,21 +2039,49 @@ def sweep_plan(flat: FlatMap, steps, result_max: int, dev_weights,
 
 
 # monotonic totals of the staged sweeps: ids swept, lanes that entered
-# the budgeted stage, lanes that entered the exact stage.  sweep_device
+# the budgeted stage, lanes that entered the exact stage, and the bucket
+# items whose true straw2 draw the stage programs computed.  sweep_device
 # leaves its two lane counts on the device and files them here unread.
-_totals = {"crush.ids": 0, "crush.mid_lanes": 0, "crush.slow_lanes": 0}
-_unread: list = []   # (mid lanes, slow lanes) device scalars, a sweep each
+_totals = {"crush.ids": 0, "crush.mid_lanes": 0, "crush.slow_lanes": 0,
+           "crush.full_draws": 0}
+# (mid lanes, slow lanes) device scalars and the stage programs' full
+# draws a lane (_stage_full_draws), a sweep each
+_unread: list = []
+
+
+def _count_stages(ids: int, mid_lanes: int, slow_lanes: int,
+                  full_draws) -> None:
+    """File the lanes that entered each stage, and the full draws of
+    the stage programs over them: `full_draws` is what a lane of the
+    one-shot (0 where there is none), the budgeted and the exact
+    program draws in full."""
+    _totals["crush.ids"] += ids
+    _totals["crush.mid_lanes"] += mid_lanes
+    _totals["crush.slow_lanes"] += slow_lanes
+    _totals["crush.full_draws"] += sum(
+        lanes * draws for lanes, draws in zip(
+            (ids, mid_lanes, slow_lanes), full_draws))
+
+
+def _stage_full_draws(fast, mid, slow) -> tuple:
+    return (fast.full_draws if fast else 0, mid.full_draws,
+            slow.full_draws)
 
 
 def sweep_totals() -> dict:
-    """{"crush.ids", "crush.mid_lanes", "crush.slow_lanes"} over every
-    sweep() and sweep_device() of the process so far.  Reading fetches
+    """{"crush.ids", "crush.mid_lanes", "crush.slow_lanes",
+    "crush.full_draws"} over every sweep() and sweep_device() of the
+    process so far.  `crush.full_draws` is reckoned on the host from
+    static counts: the full draws a lane of each stage program
+    (compile_rule's `full_draws`: the widths of its levels without a
+    fastcmp window times the descents through them) times the lanes
+    that entered the stage.  A rolled loop counts at one pass of its
+    body, so for the exact stage it is a lower bound.  Reading fetches
     the device scalars filed since the last reading (it waits for the
     sweeps that made them); a sweep itself never does."""
     while _unread:
-        mid, slow = _unread.pop()
-        _totals["crush.mid_lanes"] += int(mid)
-        _totals["crush.slow_lanes"] += int(slow)
+        mid_lanes, slow_lanes, full_draws = _unread.pop()
+        _count_stages(0, int(mid_lanes), int(slow_lanes), full_draws)
     return dict(_totals)
 
 
@@ -2054,6 +2156,7 @@ def sweep(
     plan = sweep_plan(flat, steps, result_max, dev_weights, choose_args)
     fast, mid, slow = _stage_programs(
         flat, steps, result_max, choose_args, plan, plan.fast)
+    full_draws = _stage_full_draws(fast, mid, slow)
     chunk = min(chunk, n)
     outs = []
     # power-of-two padding bounds fixup shapes to O(log chunk); the
@@ -2085,7 +2188,7 @@ def sweep(
             else:
                 res[bad] = np.asarray(res2)[: bad.size]
             bad2 = np.nonzero(~np.asarray(clean2)[: bad.size])[0]
-            _totals["crush.mid_lanes"] += int(bad.size)
+            _count_stages(0, int(bad.size), int(bad2.size), full_draws)
             if bad2.size:
                 n_pad2 = shapebucket.covering(int(bad2.size))
                 n_pad2 = hw_slow = max(n_pad2, hw_slow)
@@ -2093,9 +2196,8 @@ def sweep(
                 padded2[: bad2.size] = padded[bad2]
                 fixed = np.asarray(slow(padded2, dev_weights))
                 res[bad[bad2]] = fixed[: bad2.size]
-                _totals["crush.slow_lanes"] += int(bad2.size)
         outs.append(res[: len(xs) - off])
-    _totals["crush.ids"] += n
+    _count_stages(n, 0, 0, full_draws)
     return np.concatenate(outs) if len(outs) > 1 else outs[0]
 
 
@@ -2178,10 +2280,12 @@ def _device_runner(flat, steps, result_max, choose_args, n: int,
             return out, overflow | (n3 > cap2), n_mid, n_slow
 
         # the levels of the stage programs it runs, by how each reads
-        # its bucket rows (compile_rule)
-        run.levels = {read: sum(prog.levels[read]
+        # its bucket rows and how each draws (compile_rule)
+        run.levels = {kind: sum(getattr(prog, by)[kind]
                                 for prog in (fast, mid, slow) if prog)
-                      for read in mid.levels}
+                      for by in ("levels", "draws")
+                      for kind in getattr(mid, by)}
+        run.full_draws = _stage_full_draws(fast, mid, slow)
         _compiled_rules[key] = run
         if len(_compiled_rules) > 256:
             _compiled_rules.pop(next(iter(_compiled_rules)))
@@ -2269,8 +2373,8 @@ def sweep_device(
                       **run.levels):
         out, overflow, n_mid, n_slow = run(
             xs, jnp.asarray(dev_weights, dtype=jnp.uint32))
-    _totals["crush.ids"] += n
+    _count_stages(n, 0, 0, run.full_draws)
     if len(_unread) >= 256:   # long since computed: the read waits for none
         sweep_totals()
-    _unread.append((n_mid, n_slow))
+    _unread.append((n_mid, n_slow, run.full_draws))
     return out, overflow

@@ -207,3 +207,34 @@ def test_crush_sweep_chunk_program(chip):
         _spec(chip, (1024,), jnp.uint32)).compile()
     # one program's temps fit the chip (16 GB HBM) with room to spare
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+def test_crush_sweep_chunk_program_with_table_draws(chip):
+    """The same chunk program on a map weighted by drive capacity (the
+    benchmark's `crush-rep3-hetero-rack-1024osd`): no level is fastcmp,
+    so the one-shot pass draws every item through the draw tables, 192
+    table gathers an id.  It compiles and fits at chunk 2^19 (3.2 GB
+    of temps when PR 34 added the configuration)."""
+    import json
+    import os
+
+    from ceph_tpu.crush import map as cmap
+    from ceph_tpu.crush import mapper
+
+    with open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmarks", "configs",
+            "crush-rep3-hetero-rack-1024osd.json")) as f:
+        cfg = json.load(f)
+    m, ids = cmap.build_layered_cluster(
+        1024, [(la["type_id"], la["size"]) for la in cfg["layers"]],
+        cfg["osd_weights"])
+    steps = [(cmap.OP_TAKE, ids[-1][0], 0),
+             (cmap.OP_CHOOSELEAF_FIRSTN, 0, 1), (cmap.OP_EMIT, 0, 0)]
+    fast = mapper.compile_rule(m.flatten(), steps, 3, None, one_shot=True)
+    assert fast.draws == {"draw_fast": 0, "draw_table": 3, "draw_limb": 0}
+    assert fast.full_draws == 3 * (8 + 8 + 16)
+    compiled = jax.jit(fast).lower(
+        _spec(chip, (1 << 19,), jnp.int32),
+        _spec(chip, (1024,), jnp.uint32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30

@@ -254,10 +254,13 @@ def test_the_sweep_span_says_what_ran():
              if r[NAME] == "crush.sweep"]
     # no one-shot pass: the budgeted and the exact program, each with
     # the root (one bucket: constants) and the racks and the hosts
-    # (read by their place in the level's frontier); nothing gathers
+    # (read by their place in the level's frontier); nothing gathers.
+    # Uniform weights: the budgeted program's three levels draw by
+    # fastcmp, the exact program's three through the draw tables
     assert span[COUNTS] == {
         "ids": 2048, "chunk": 1024, "numrep": 6, "mode": "indep",
         "cap": 1024, "cap2": 2048, "budget": plan.budget,
-        "const": 2, "onehot": 4, "gather": 0}
+        "const": 2, "onehot": 4, "gather": 0,
+        "draw_fast": 3, "draw_table": 3, "draw_limb": 0}
     assert {"crush.sweep", "crush.ids", "crush.mid_lanes",
-            "crush.slow_lanes"} <= set(tracing.SPANS)
+            "crush.slow_lanes", "crush.full_draws"} <= set(tracing.SPANS)

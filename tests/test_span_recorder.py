@@ -391,17 +391,20 @@ def test_named_scopes_leave_sweep_device_bit_identical():
     new = tracing.recorder().held()[0][n0:]
     sweep, = [r for r in new if r[NAME] == "crush.sweep"]
     # three stage programs, each with the root's level (constants) and
-    # the hosts' (read by their place in the frontier)
+    # the hosts' (read by their place in the frontier); the one-shot and
+    # the budgeted program draw by fastcmp, the exact one by the tables
     assert sweep[COUNTS] == {"ids": 1024, "chunk": 1024, "numrep": 3,
                              "mode": "firstn", "cap": 1024, "cap2": 1024,
                              "budget": 3, "const": 3, "onehot": 3,
-                             "gather": 0}
+                             "gather": 0, "draw_fast": 4, "draw_table": 2,
+                             "draw_limb": 0}
     assert any(r[NAME] == "dev.dispatch" and r[PARENT] == sweep[ID]
                and r[COUNTS]["family"] == "crush_mapper" for r in new)
     # the three stage programs sit under their scopes inside the one
     # family scope: what a trace's op metadata will say
+    digest = mapper._rule_digest(flat, steps, 3, None)
     run, = [v for k, v in mapper._compiled_rules.items()
-            if k[1:6] == ("sweep_device", 1024, 1024, 1024, 1024)]
+            if k[:6] == (digest, "sweep_device", 1024, 1024, 1024, 1024)]
     text = run.jitted.lower(
         jnp.asarray(xs), jnp.asarray(dev_w)).as_text(debug_info=True)
     assert "jit(run)/crush_mapper/" in text

@@ -5,13 +5,14 @@ Flag-compatible core of the reference tool (reference:
 src/tools/crushtool.cc:112-218 for --build/--test and
 src/crush/CrushTester.cc:472 for the placement-distribution test),
 with the inversion this framework exists for: the --test sweep is ONE
-vmapped jit dispatch over the whole x-range instead of a scalar
-crush_do_rule loop.
+staged device dispatch over the whole x-range (mapper.sweep_device)
+instead of a scalar crush_do_rule loop.
 
 Examples:
   crushtool.py --build --num_osds 64 host straw2 4 root straw2 0 -o map.bin
   crushtool.py -i map.bin --test --rule 0 --num-rep 3 --min-x 0 \\
       --max-x 9999 --show-statistics --show-utilization
+  crushtool.py -i map.bin --reweight-item osd.5 3.638 -o map.bin
 """
 
 from __future__ import annotations
@@ -60,6 +61,32 @@ def build_map(num_osds: int, layers) -> cmap.CrushMap:
     return m
 
 
+def place(flat, steps, num_rep: int, xs: np.ndarray, dev_w) -> np.ndarray:
+    """The staged device sweep over xs (padded to whole chunks); where
+    it overflows a capacity of its plan, the host sweep, whose fix-up
+    batches are cut to what each chunk needs."""
+    chunk = min(1 << 19, len(xs))
+    padded = np.concatenate(
+        [xs, np.full(-len(xs) % chunk, xs[-1], dtype=np.int32)])
+    out, overflow = mapper.sweep_device(flat, steps, num_rep, padded, dev_w,
+                                        chunk=chunk)
+    if bool(overflow):
+        return mapper.sweep(flat, steps, num_rep, xs, dev_w)
+    return np.asarray(out)[:len(xs)]
+
+
+def item_id(m: cmap.CrushMap, name: str) -> int:
+    """`osd.N`, a bucket's name, or what -d calls a bucket without one
+    (`bucket<N>` for id -N)."""
+    if name.startswith("osd."):
+        return int(name[4:])
+    names = {f"bucket{-bid}": bid for bid in m.buckets}
+    names.update({n: bid for bid, n in m.bucket_names.items()})
+    if name not in names:
+        raise SystemExit(f"crushtool: no item {name} in the map")
+    return names[name]
+
+
 def run_test(m: cmap.CrushMap, args) -> dict:
     rule_no = args.rule
     if rule_no >= len(m.rules):
@@ -69,13 +96,12 @@ def run_test(m: cmap.CrushMap, args) -> dict:
             (cmap.OP_EMIT, 0, 0)]))
         rule_no = len(m.rules) - 1
     rule = m.rules[rule_no]
-    fn = mapper.compile_rule(m.flatten(), rule.steps, args.num_rep)
     xs = np.arange(args.min_x, args.max_x + 1, dtype=np.int32)
     dev_w = np.full(m.max_devices, 0x10000, dtype=np.uint32)
     if args.weight:
         for osd, w in args.weight:
             dev_w[osd] = int(float(w) * 0x10000)
-    out = np.asarray(fn(xs, dev_w))
+    out = place(m.flatten(), rule.steps, args.num_rep, xs, dev_w)
 
     valid = (out != ITEM_NONE) & (out >= 0)
     sizes = valid.sum(axis=1)
@@ -119,6 +145,10 @@ def main(argv=None) -> int:
     p.add_argument("--num_osds", type=int, default=0)
     p.add_argument("layers", nargs="*",
                    help="--build layers: name alg size triples")
+    p.add_argument("--reweight-item", nargs=2, action="append", default=[],
+                   metavar=("NAME", "W"),
+                   help="set an item's CRUSH weight (16.16 from the float "
+                        "W) and carry it up through its ancestors")
     p.add_argument("--test", action="store_true")
     p.add_argument("--rule", type=int, default=0)
     p.add_argument("--num-rep", type=int, default=3)
@@ -153,6 +183,12 @@ def main(argv=None) -> int:
     else:
         print("need --build, -c or -i", file=sys.stderr)
         return 1
+
+    for name, w in args.reweight_item:
+        if not m.adjust_item_weight(item_id(m, name),
+                                    int(float(w) * 0x10000)):
+            print(f"crushtool: no bucket holds {name}", file=sys.stderr)
+            return 1
 
     if args.decompile:
         from ceph_tpu.crush.compiler import decompile
