@@ -141,9 +141,10 @@ SPANS: Dict[str, str] = {
     #   descent levels of the stage programs it ran by how each reads its
     #   bucket rows: const, onehot (from the level's static frontier),
     #   gather (by the bucket index); and by how each draws: draw_fast
-    #   (fastcmp: the max-hash item, delta > 0), draw_table (every item
-    #   through the draw tables), draw_limb (every item by the u32-limb
-    #   division)
+    #   (fastcmp: the max-hash item, delta > 0), draw_class (the max-hash
+    #   item of each weight class through the draw tables), draw_table
+    #   (every item through the draw tables), draw_limb (every item by the
+    #   u32-limb division)
     # the mapper's monotonic totals (mapper.sweep_totals(): counters, not
     # spans, registered here so that their names are held to one table)
     "crush.ids": "crush_mid_lanes_per_id",          # ids swept: the divisor

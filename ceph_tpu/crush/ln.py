@@ -75,14 +75,18 @@ def fastcmp_bounds() -> dict:
     crush_ln's fixed-point interpolation is NOT monotone (adjacent
     values can invert by up to ~2^27.7), but the inversion is local:
     at distance >= 2 the magnitudes separate by > 2^25.  Consequence:
-    in a bucket whose (positive) item weights all equal w <= bound[d],
-    the straw2 winner argmin(floor(n/w)) is EXACTLY the item with the
-    maximum hash (first index on hash ties) whenever the runner-up
-    hash is more than d below the maximum — floor(a/w) > floor(b/w)
-    for a - b >= w.  The vmapped one-shot sweep uses this to replace
-    the draw-table gathers with a pure hash+argmax, flagging lanes
-    whose top-2 hashes are within d as unclean for the exact re-run
-    (mapper._straw2_choose fastcmp path).
+    among ANY set of items that share one (positive) weight
+    w <= bound[d] — a whole bucket of uniform weights, or one weight
+    class of a bucket that holds several — the least quotient
+    floor(n/w) is EXACTLY that of the item with the maximum hash
+    (first index on hash ties) whenever the set's runner-up hash is
+    more than d below its maximum — floor(a/w) > floor(b/w) for
+    a - b >= w.  The vmapped one-shot sweep uses this to replace
+    the draw-table gathers with a pure hash+argmax in a bucket of one
+    weight, and to draw one candidate a weight class in a bucket of
+    several, flagging lanes whose top-2 hashes (of a class) are within
+    d as unclean for the exact re-run (mapper._straw2_choose fastcmp
+    path, mapper._class_choose).
 
     Computed exactly from the table via suffix-max (not hardcoded so
     the derivation is checkable): bound[d] = min_u [n(u) -

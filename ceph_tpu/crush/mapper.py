@@ -33,6 +33,14 @@ tests/test_crush_vs_reference.py):
 - straw2 draw: crush_hash32_3(x, id, r) & 0xffff -> fixed-point ln table
   -> truncating s64 divide by the 16.16 weight; ties keep the first item
   (argmax == the C "strictly greater" update rule).
+- how many items of a bucket are drawn follows what the descent plan
+  observes of the map (_level_fast_delta): among items of ONE weight
+  under ln.fastcmp_bounds() the max-hash item wins unless another hash
+  is within the window, so a budgeted trace draws nothing where every
+  bucket is uniform inside (the max-hash item wins), one candidate a
+  weight class where a bucket holds a few weights (a map weighted by
+  drive capacity), and every item otherwise; the exact program always
+  draws every item.
 - firstn: per-rep retry with r' = rep + ftotal, collision against chosen
   prefix, reweight rejection via is_out, chooseleaf recursion with
   vary_r / stable.
@@ -212,6 +220,7 @@ class _DeviceMap(_HostMap):
                 tlo[i] = (q & 0xFFFFFFFF).astype(np.uint32)
             self.draw_hi = jnp.asarray(thi)
             self.draw_lo = jnp.asarray(tlo)
+            self._draw_rows: dict = {}
         else:
             # magic reciprocals for the straw2 divide: weights are map
             # constants, so the exact truncating s64 division ln/w
@@ -261,38 +270,66 @@ class _DeviceMap(_HostMap):
                        ).bit_length() - 1)
 
 
-def _level_fast_delta(dm: "_HostMap", frontier) -> int:
-    """Hash-ambiguity window for the fastcmp straw2 draw at one descent
-    level, or 0 when the level is ineligible.
+    def draw_rows(self, c: int):
+        """(hi, lo) rows of the draw tables of the distinct weight `c`,
+        cut once (and not staged into the trace that asks first):
+        constants of a program that draws a weight class from them
+        (_class_choose)."""
+        rows = self._draw_rows.get(c)
+        if rows is None:
+            with jax.ensure_compile_time_eval():
+                rows = self._draw_rows[c] = (self.draw_hi[c],
+                                             self.draw_lo[c])
+        return rows
 
-    Eligible when every frontier bucket is straw2 with uniform positive
-    item weights, all under ln.fastcmp_bounds()[delta]: then the draw
-    winner is exactly the max-hash item unless the runner-up hash is
-    within delta (a contested draw: the budgeted stage compares the two
-    true draws, the firstn one-shot pass flags the lane for that stage
-    — see ln.fastcmp_bounds and _straw2_choose)."""
-    from ceph_tpu.crush import ln as _ln
 
-    wmax = 0
+def _level_fast_delta(dm: "_HostMap", frontier):
+    """(delta, classes) of one descent level: the hash-ambiguity window
+    of the fastcmp straw2 draw there and the weight classes it draws a
+    candidate each from, or (0, ()) when the level is ineligible and
+    draws every item.
+
+    ln.fastcmp_bounds() holds for any set of items that share ONE
+    weight w <= bound[delta]: the max-hash item of the set has the
+    strictly least quotient of it unless another distinct hash of the
+    set lies within delta (a contested draw: the budgeted stage compares
+    the true draws, the firstn one-shot pass flags the lane for that
+    stage, see _straw2_choose).  Eligible when every frontier bucket is
+    straw2 and every positive item weight of the frontier is at most
+    bound[delta], delta the least window that takes the GREATEST weight.
+    Then:
+    - every bucket uniform inside (whatever the buckets' weights are
+      among themselves): the bucket's one class is all of its items, the
+      winner is its max-hash item and no table is read; classes ();
+    - unlike weights inside a bucket, the map has draw tables and the
+      frontier's distinct positive weights are fewer than the level's
+      width: the winner is one of the max-hash items of the weight
+      classes, so only those are drawn; classes are the draw-table rows
+      (indices into dm._distinct) of those weights, ascending;
+    - as many classes as items, or no draw tables: nothing is saved,
+      (0, ())."""
+    weights, uniform, width = set(), True, 0
     for b in frontier:
         if int(dm._np_algs[b]) != ALG_STRAW2:
-            return 0
+            return 0, ()
         sz = int(dm._np_sizes[b])
-        if sz == 0:
-            continue
+        width = max(width, sz)
         ws = dm._np_weights[b, :sz]
         pos = ws[ws > 0]
         if pos.size == 0:
             continue
-        if (pos != pos[0]).any():
-            return 0
-        wmax = max(wmax, int(pos[0]))
-    if wmax == 0:
-        return 0
-    for d, bound in _ln.fastcmp_bounds().items():
-        if wmax <= bound:
-            return d
-    return 0
+        uniform = uniform and not (pos != pos[0]).any()
+        weights.update(int(w) for w in np.unique(pos))
+    if not weights:
+        return 0, ()
+    delta = next((d for d, bound in ln.fastcmp_bounds().items()
+                  if max(weights) <= bound), 0)
+    if uniform or not delta:
+        return delta, ()
+    if not dm.table_mode or len(weights) >= width:
+        return 0, ()
+    return delta, tuple(
+        int(c) for c in np.searchsorted(dm._distinct, sorted(weights)))
 
 
 class _Level(NamedTuple):
@@ -308,6 +345,18 @@ class _Level(NamedTuple):
     #   "onehot" (a few: the bucket's place in the frontier, contracted
     #   with the frontier's own table), "gather" (by the bucket index)
     sub_type: int = 0  # the one type of the level's child buckets
+    classes: tuple = ()  # delta > 0 and buckets of unlike weights inside:
+    #   the draw-table rows of the frontier's weight classes, each drawn
+    #   one candidate (_level_fast_delta); (): one class a bucket
+
+    def draws(self) -> int:
+        """Bucket items whose true straw2 draw a lane computes here: the
+        level's width without a window, none where every bucket is
+        uniform inside, a candidate a weight class otherwise and its
+        runner-up too in a stage that resolves."""
+        if not self.delta:
+            return self.width
+        return len(self.classes) * (2 if self.resolve else 1)
 
 
 def _level_read(dm: "_HostMap", frontier):
@@ -339,12 +388,15 @@ def _descent_plan(dm: "_HostMap", frontier, want_type: int,
     this halves the straw2 work per choose — and reads the root's rows
     as constants and a host's by its place among the 64, with no gather
     by a bucket index (_Rows).  fastcmp=True (budgeted traces only)
-    additionally marks levels whose frontier buckets have uniform
-    weights: those levels draw by pure hash+argmax with an unclean flag
-    instead of table gathers (_level_fast_delta).  `resolve` is the
-    stage's way with a contested fastcmp draw, handed on to
-    _straw2_choose with each level: compare the two true draws (the
-    budgeted stage), or only flag the lane (the firstn one-shot pass).
+    additionally gives a level its fastcmp window and weight classes
+    (_level_fast_delta): frontier buckets uniform inside draw by pure
+    hash+argmax with an unclean flag instead of table gathers; buckets
+    of a few unlike weights draw the max-hash item of each weight class
+    (`classes`); the rest, and every level of the exact program, draw
+    every item.  `resolve` is the stage's way with a contested fastcmp
+    draw, handed on to _straw2_choose with each level: compare the true
+    draws (the budgeted stage), or only flag the lane (the firstn
+    one-shot pass).
 
     frontier: iterable of bucket indices possibly holding the walk at
     level 0, or None where that is not known: the conservative global
@@ -355,10 +407,11 @@ def _descent_plan(dm: "_HostMap", frontier, want_type: int,
     plan = []
     for _ in range(dm.depth):
         width = max(int(dm._np_sizes[b]) for b in frontier)
-        delta = _level_fast_delta(dm, frontier) if fastcmp else 0
+        delta, classes = (_level_fast_delta(dm, frontier) if fastcmp
+                          else (0, ()))
         plan.append(_Level(max(width, 1), delta, resolve,
                            tuple(sorted(frontier)),
-                           *_level_read(dm, frontier)))
+                           *_level_read(dm, frontier), classes))
         nxt = set()
         for b in frontier:
             for j in range(int(dm._np_sizes[b])):
@@ -498,6 +551,61 @@ class _Rows:
         return _pick(self._of(tab), idx)
 
 
+def _class_choose(dm: _DeviceMap, lvl: _Level, items, uv, wi):
+    """The straw2 choose of a level whose buckets hold unlike weights
+    (_Level.classes), for one bucket's row [width] or the rows of a
+    vector of slots [S, width]: `uv` the items' hashes (-1: an item
+    that cannot win), `wi` their draw-table rows.  Returns (item,
+    ambig, the winner's place), as _straw2_choose does.
+
+    Among the items of ONE weight the max-hash item has the strictly
+    least quotient unless another distinct hash of theirs lies within
+    `delta` (ln.fastcmp_bounds), so the bucket's winner is one of the
+    classes' max-hash items, first index on a hash tie: one candidate a
+    class, its true draw two 1-D gathers from the class's own rows of
+    the draw tables (constants of the program).  An empty class has no
+    candidate.  The winner is the lexicographic argmin of (hi, lo) over
+    the candidates and, of equal quotients (two classes can tie), the
+    one placed first in the row: the C's strictly-greater update keeps
+    the first item.  A class whose runner-up hash is within the window
+    is contested: resolve=False flags the lane; resolve=True draws every
+    class's runner-up as a candidate too, and only a third distinct
+    hash of a class inside the window stays ambig."""
+    none = jnp.int32(-1)
+    ambig = jnp.zeros(uv.shape[:-1], jnp.bool_)
+    cands = []  # (hash or -1, place), `per` of them a class
+    per = 2 if lvl.resolve else 1
+    for c in lvl.classes:
+        uc = jnp.where(wi == c, uv, none)
+        u1 = jnp.max(uc, axis=-1)
+        sel1 = uc == u1[..., None]
+        cands.append((u1, jnp.argmax(sel1, axis=-1)))
+        # nearest DISTINCT runner-up of the class
+        rest = (~sel1) & (uc >= 0)
+        near = jnp.max(jnp.where(rest, uc, none), axis=-1)
+        if lvl.resolve:
+            sel2 = rest & (uc == near[..., None])
+            cands.append((near, jnp.argmax(sel2, axis=-1)))
+            near = jnp.max(jnp.where(rest & ~sel2, uc, none), axis=-1)
+        ambig = ambig | ((near >= 0) & (u1 - near <= lvl.delta))
+    u = jnp.stack([u for u, _ in cands], axis=-1)
+    at = jnp.stack([i for _, i in cands], axis=-1).astype(jnp.int32)
+    ui = jnp.maximum(u, 0)
+    # a class's candidates from its own row of each table, one gather
+    q_hi, q_lo = (
+        jnp.where(u >= 0, jnp.concatenate(
+            [row[ui[..., per * k: per * (k + 1)]]
+             for k, row in enumerate(rows)], axis=-1), _UMAX)
+        for rows in zip(*(dm.draw_rows(c) for c in lvl.classes)))
+    best = q_hi == jnp.min(q_hi, axis=-1)[..., None]
+    min_lo = jnp.min(jnp.where(best, q_lo, _UMAX), axis=-1)
+    best = best & (q_lo == min_lo[..., None])
+    # no candidate at all: every place is 0, the table path's all-masked
+    # argmin
+    idx = jnp.min(jnp.where(best, at, lvl.width), axis=-1)
+    return _pick(items, idx), ambig, idx
+
+
 def _straw2_choose(dm: _DeviceMap, x, r, rows: _Rows):
     """Vectorized bucket_straw2_choose (reference: mapper.c:361-384),
     exact and 64-bit-free.  Returns (item, ambig, the winner's place in
@@ -511,8 +619,10 @@ def _straw2_choose(dm: _DeviceMap, x, r, rows: _Rows):
     |ln| = n < 2^48, so argmax(draw) == lexicographic argmin of the
     positive quotient q = floor(n / w).
 
-    fastcmp path (delta > 0, budgeted traces on uniform-weight
-    buckets): the winner is the max-hash item directly, with no table
+    fastcmp path (delta > 0, budgeted traces).  Buckets of unlike
+    weights inside (rows.lvl.classes): one candidate a weight class,
+    _class_choose.  Buckets uniform inside, positive item weights under
+    the bound: the winner is the max-hash item directly, with no table
     access.  Exact except when the nearest distinct runner-up hash is
     within `delta` of the winner's (ln.fastcmp_bounds derivation): a
     CONTESTED draw, about width * delta / 65536 of them.  What becomes
@@ -548,6 +658,9 @@ def _straw2_choose(dm: _DeviceMap, x, r, rows: _Rows):
     ) & _U16
     if delta:
         uv = jnp.where(valid, u.astype(jnp.int32), jnp.int32(-1))
+        if rows.lvl.classes:
+            return _class_choose(dm, rows.lvl, items, uv,
+                                 rows.row("w_idx"))
         u1 = jnp.max(uv)
         sel1 = uv == u1  # valid implied: invalid slots are -1 < u1
         i1 = jnp.argmax(sel1).astype(jnp.int32)
@@ -672,7 +785,9 @@ def _straw2_choose_slots(dm: _DeviceMap, x, r, rows: _Rows):
     lookups, not its hash).  The slots' rows come through `rows`
     (_Rows: from the level's frontier where the plan knows it), a
     winner's item is a masked sum over its row.  Draw-table and
-    fastcmp paths only; _bucket_choose maps the rest slot by slot."""
+    fastcmp paths only (a level of weight classes through
+    _class_choose, every slot resolved); _bucket_choose maps the rest
+    slot by slot."""
     width, delta = rows.lvl[:2]
     items = rows.row("items")            # [S, width]
     valid = rows.row("valid")
@@ -682,6 +797,9 @@ def _straw2_choose_slots(dm: _DeviceMap, x, r, rows: _Rows):
     ) & _U16).reshape(items.shape)
     if delta:
         uv = jnp.where(valid, u.astype(jnp.int32), jnp.int32(-1))
+        if rows.lvl.classes:
+            return _class_choose(dm, rows.lvl, items, uv,
+                                 rows.row("w_idx"))
         u1 = jnp.max(uv, axis=-1)
         sel1 = uv == u1[:, None]
         i1 = jnp.argmax(sel1, axis=-1).astype(jnp.int32)
@@ -1514,13 +1632,14 @@ class _Choose(NamedTuple):
 
     def full_draws(self) -> int:
         """Bucket items whose true straw2 draw (table or limb path) a
-        lane computes in this step: the widths of its levels without a
-        fastcmp window, times the descents through them."""
+        lane computes in this step: what its levels draw (_Level.draws:
+        the width without a fastcmp window, a candidate a weight class
+        and in a resolving stage its runner-up, nothing where every
+        bucket is uniform inside), times the descents through them."""
         outer, leaf = self.descents()
         return self.sources * (
-            outer * sum(lv.width for lv in self.plan if not lv.delta)
-            + leaf * sum(lv.width for lv in self.leaf_plan or ()
-                         if not lv.delta))
+            outer * sum(lv.draws() for lv in self.plan)
+            + leaf * sum(lv.draws() for lv in self.leaf_plan or ()))
 
 
 def _choose_plans(dm: _HostMap, steps, result_max: int, budget_val: int,
@@ -1600,17 +1719,21 @@ def _choose_plans(dm: _HostMap, steps, result_max: int, budget_val: int,
 def _level_counts(dm: _HostMap, chooses):
     """The descent levels of a program's plans, counted by how each
     reads its bucket rows (_Rows: const, onehot, gather) and by how
-    each draws (_straw2_choose: draw_fast with a fastcmp window,
-    draw_table every item through the draw tables, draw_limb every item
-    by the u32-limb division): (reads, draws)."""
+    each draws (_straw2_choose: draw_fast the max-hash item of a bucket
+    uniform inside, draw_class the max-hash item of each weight class
+    through the draw tables, draw_table every item through the draw
+    tables, draw_limb every item by the u32-limb division):
+    (reads, draws)."""
     levels = [lvl for ch in chooses for plan in (ch.plan, ch.leaf_plan)
               if plan for lvl in plan]
     full = "draw_table" if dm.table_mode else "draw_limb"
     reads = dict.fromkeys(("const", "onehot", "gather"), 0)
-    draws = dict.fromkeys(("draw_fast", "draw_table", "draw_limb"), 0)
+    draws = dict.fromkeys(
+        ("draw_fast", "draw_class", "draw_table", "draw_limb"), 0)
     for lvl in levels:
         reads[lvl.read] += 1
-        draws["draw_fast" if lvl.delta else full] += 1
+        draws["draw_class" if lvl.classes else
+              "draw_fast" if lvl.delta else full] += 1
     return reads, draws
 
 
@@ -1641,12 +1764,13 @@ def compile_rule(
     trigger on failure).  A firstn choose of this pass only FLAGS a
     contested fastcmp draw (runner-up hash within delta, about
     width * delta / 65536 of a level's draws), so its program gathers
-    nothing from w_idx or the draw tables; an indep choose compares the
-    true draws of a level's first contested slot
-    (_straw2_choose_slots).  Unclean lanes must be re-run through a
-    higher-budget program (see sweep()); under vmap this removes the
-    dominant cost of the full program, where every lane pays the
-    batch's WORST-CASE retry rounds.
+    nothing from w_idx or the draw tables on a map whose buckets are
+    uniform inside, and one candidate a weight class where they are not
+    (_class_choose); an indep choose compares the true draws of a
+    level's first contested slot (_straw2_choose_slots).  Unclean lanes
+    must be re-run through a higher-budget program (see sweep()); under
+    vmap this removes the dominant cost of the full program, where
+    every lane pays the batch's WORST-CASE retry rounds.
 
     budget=N (with one_shot=True) builds the MID stage: real retry
     semantics statically unrolled to N attempts per choose; lanes fully
@@ -1665,9 +1789,9 @@ def compile_rule(
     The returned callable's `levels` counts the descent levels of the
     program's plans by how each reads its bucket rows (_Rows: "const",
     "onehot", "gather"), its `draws` by how each draws ("draw_fast",
-    "draw_table", "draw_limb", see _level_counts); its `full_draws` is
-    the number of bucket items whose true straw2 draw a lane computes
-    (_Choose.full_draws).  sweep_device puts both counts of the stage
+    "draw_class", "draw_table", "draw_limb", see _level_counts); its
+    `full_draws` is the number of bucket items whose true straw2 draw a
+    lane computes (_Choose.full_draws).  sweep_device puts both counts of the stage
     programs it ran on its span and files the full draws for
     sweep_totals().
 
@@ -1954,8 +2078,11 @@ def _contested_share(flat: FlatMap, steps, numrep: int,
     """Bound on the share of lanes in which the firstn one-shot pass of
     a `take; choose; emit` rule flags a contested fastcmp draw: numrep
     descents, each level of the outer and the leaf plan contested on
-    about width * delta / 65536 of its draws (_straw2_choose).  Read
-    off the same static plans compile_rule builds."""
+    about width * delta / 65536 of its draws (_straw2_choose).  A level
+    of weight classes flags when any class is contested, about
+    size * delta / 65536 a class: the classes' sizes sum to at most
+    the width, so the same figure bounds it.  Read off the same static
+    plans compile_rule builds."""
     hm = _HostMap(flat, choose_args)
     take = next(arg1 for op, arg1, _ in steps if op == OP_TAKE)
     op, _, want = next(s for s in steps if s[0] in (
@@ -2073,10 +2200,10 @@ def sweep_totals() -> dict:
     "crush.full_draws"} over every sweep() and sweep_device() of the
     process so far.  `crush.full_draws` is reckoned on the host from
     static counts: the full draws a lane of each stage program
-    (compile_rule's `full_draws`: the widths of its levels without a
-    fastcmp window times the descents through them) times the lanes
-    that entered the stage.  A rolled loop counts at one pass of its
-    body, so for the exact stage it is a lower bound.  Reading fetches
+    (compile_rule's `full_draws`: what its levels draw, _Level.draws,
+    times the descents through them) times the lanes that entered the
+    stage.  A rolled loop counts at one pass of its body, so for the
+    exact stage it is a lower bound.  Reading fetches
     the device scalars filed since the last reading (it waits for the
     sweeps that made them); a sweep itself never does."""
     while _unread:
@@ -2122,9 +2249,10 @@ def sweep(
     1. the one-shot trace maps every id with exactly one attempt per
        choose — the overwhelmingly common case on healthy maps — and
        reports which lanes were clean.  Its fastcmp draws pick the
-       max-hash item and, in a firstn rule, read no draw table: a
-       contested draw (runner-up hash within delta) makes the lane
-       unclean;
+       max-hash item (of each weight class, where a bucket holds
+       several) and, in a firstn rule, read no draw table but for the
+       classes' candidates: a contested draw (runner-up hash within
+       delta) makes the lane unclean;
     2. the unclean lanes (collisions, rejections, contested draws:
        4.7 % + 0.7 % for three replicas over 64 hosts of 16) re-run
        through the bounded-budget trace (real retry semantics unrolled

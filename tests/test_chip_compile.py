@@ -211,10 +211,11 @@ def test_crush_sweep_chunk_program(chip):
 
 def test_crush_sweep_chunk_program_with_table_draws(chip):
     """The same chunk program on a map weighted by drive capacity (the
-    benchmark's `crush-rep3-hetero-rack-1024osd`): no level is fastcmp,
-    so the one-shot pass draws every item through the draw tables, 192
-    table gathers an id.  It compiles and fits at chunk 2^19 (3.2 GB
-    of temps when PR 34 added the configuration)."""
+    benchmark's `crush-rep3-hetero-rack-1024osd`): every level holds
+    unlike weights inside its buckets, so the one-shot pass draws one
+    candidate a weight class through the draw tables (2 + 4 + 3 a
+    replica, 54 table gathers an id; 192 when every item was drawn,
+    PR 34).  It compiles and fits at chunk 2^19."""
     import json
     import os
 
@@ -232,8 +233,9 @@ def test_crush_sweep_chunk_program_with_table_draws(chip):
     steps = [(cmap.OP_TAKE, ids[-1][0], 0),
              (cmap.OP_CHOOSELEAF_FIRSTN, 0, 1), (cmap.OP_EMIT, 0, 0)]
     fast = mapper.compile_rule(m.flatten(), steps, 3, None, one_shot=True)
-    assert fast.draws == {"draw_fast": 0, "draw_table": 3, "draw_limb": 0}
-    assert fast.full_draws == 3 * (8 + 8 + 16)
+    assert fast.draws == {"draw_fast": 0, "draw_class": 3, "draw_table": 0,
+                          "draw_limb": 0}
+    assert fast.full_draws == 3 * (2 + 4 + 3)
     compiled = jax.jit(fast).lower(
         _spec(chip, (1 << 19,), jnp.int32),
         _spec(chip, (1024,), jnp.uint32)).compile()

@@ -129,7 +129,9 @@ def test_the_plan_counts_the_one_shot_passes_contested_draws(hosts):
     0.7 % beside 4.7 % of collisions, twice which is still under 1/8,
     so the plan is the default.  1024 OSDs straight under the root:
     9.4 % beside 0.3 %, so stage 2 gets a quarter of the chunk, which
-    collisions alone (mixed weights, no fastcmp) would not ask for."""
+    collisions alone would not ask for.  A level of fewer weights than
+    items (the class draw) flags as many; one of as many weights as
+    items draws in full and flags nothing."""
     m, root = cmap.build_flat_cluster(1024, hosts=hosts)
     steps = [(cmap.OP_TAKE, root, 0),
              (cmap.OP_CHOOSELEAF_FIRSTN, 3, 1 if hosts else 0),
@@ -149,13 +151,23 @@ def test_the_plan_counts_the_one_shot_passes_contested_draws(hosts):
         assert share == pytest.approx(3 * 1024 * 2 / 65536)
         assert 2 * collisions < 1 / 8 < 2 * (collisions + share) < 1 / 4
         assert plan == (4, 2048, 3, None)
-    # mixed item weights make every level ineligible for fastcmp
-    # (_level_fast_delta): nothing is contested, no room is made for it
+    # two item weights in every bucket: every level draws a candidate a
+    # weight class (_level_fast_delta) and flags a contested class, so
+    # the same room is made (the classes' sizes sum to the width)
     wts = np.asarray(flat.weights).copy()
     wts[:, 1::2] *= 3   # every other item of every bucket
     mixed = dataclasses.replace(flat, weights=wts)
-    assert mapper._contested_share(mixed, steps, 3) == 0.0
-    assert mapper.sweep_plan(mixed, steps, 3, w) == mapper.DEFAULT_PLAN
+    assert mapper._contested_share(mixed, steps, 3) == share
+    s2_mixed = mapper._retry_model(mixed, steps, 3, w)[2]
+    assert mapper.sweep_plan(mixed, steps, 3, w).bad_div == mapper._pow2_div(
+        2 * (3 * s2_mixed + share), mapper.DEFAULT_PLAN.bad_div)
+    # as many item weights as items make every level ineligible
+    # (_level_fast_delta): nothing is contested, no room is made for it
+    wts = np.asarray(flat.weights).copy()
+    wts *= 1 + np.arange(wts.shape[1], dtype=wts.dtype) % 50
+    every = dataclasses.replace(flat, weights=wts)
+    assert mapper._contested_share(every, steps, 3) == (
+        0.0 if hosts else share)
 
 
 def test_the_ec_pools_plan_on_the_benchmarks_map():
@@ -261,6 +273,6 @@ def test_the_sweep_span_says_what_ran():
         "ids": 2048, "chunk": 1024, "numrep": 6, "mode": "indep",
         "cap": 1024, "cap2": 2048, "budget": plan.budget,
         "const": 2, "onehot": 4, "gather": 0,
-        "draw_fast": 3, "draw_table": 3, "draw_limb": 0}
+        "draw_fast": 3, "draw_class": 0, "draw_table": 3, "draw_limb": 0}
     assert {"crush.sweep", "crush.ids", "crush.mid_lanes",
             "crush.slow_lanes", "crush.full_draws"} <= set(tracing.SPANS)

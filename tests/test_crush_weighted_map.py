@@ -1,7 +1,9 @@
 """A map whose CRUSH weights are its drives' capacities (PR 34): three
 drive generations over root/rack/host, so that no straw2 level has
-uniform weights and every item of every level is drawn in full (the
-table path), in the one-shot pass too.
+uniform weights.  Since PR 35 a level whose buckets hold fewer distinct
+weights than items draws one candidate a weight class (the class
+draw); the exact program still draws every item in full (the table
+path).
 
 Small size, CPU: 128 OSDs, `host straw2 4 rack straw2 4 root straw2 0`,
 the benchmark configuration's three weights laid out by its h mod 4
@@ -9,7 +11,9 @@ recipe.  Placements are held to two witnesses: the C oracle
 `_native.do_rule` and the benchmark's numpy reference
 (benchmarks/reference_crush_firstn_tree.py, nothing of ceph_tpu in it).
 Also here: `CrushMap.adjust_item_weight` and `crushtool
---reweight-item`, by which such a map is made.
+--reweight-item`, by which such a map is made; and the benchmark
+cell's own map (1,024 OSDs, hosts of 16), on which the plans, the span
+and the sweeps of a firstn and an indep rule are held to the oracle.
 """
 
 import contextlib
@@ -17,6 +21,8 @@ import io
 import json
 import os
 import sys
+
+import functools
 
 import numpy as np
 import pytest
@@ -156,56 +162,201 @@ def test_weight_follows_capacity():
     assert 3.2 < share[w == TB16].mean() / share[w == TB4].mean() < 4.8
 
 
-# -- no level is fastcmp: plans, span, counter ----------------------------------
+# -- the benchmark cell's own map: plans, span, counter, sweeps ------------------
+CELL_IDS = 16384
+
+
+@functools.lru_cache(maxsize=None)
+def cell():
+    """(flat map, root id, configuration) of
+    `crush-rep3-hetero-rack-1024osd`."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "crush-rep3-hetero-rack-1024osd.json")) as f:
+        cfg = json.load(f)
+    m, ids = cmap.build_layered_cluster(
+        cfg["num_osds"], [(la["type_id"], la["size"]) for la in cfg["layers"]],
+        cfg["osd_weights"])
+    return m.flatten(), ids[-1][0], cfg
+
+
+def cell_steps(op=cmap.OP_CHOOSELEAF_FIRSTN):
+    return [(cmap.OP_TAKE, cell()[1], 0), (op, 0, 1), (cmap.OP_EMIT, 0, 0)]
+
+
 @pytest.mark.parametrize("budget", [1, 3, 0])
 def test_every_level_of_every_stage_draws_in_full(budget):
-    hm = mapper._HostMap(cluster().flatten())
+    """What it said until PR 35, and still says of the exact program
+    (budget 0).  The budgeted traces draw a candidate a weight class:
+    2 rack weights in the root (the greater one over bound[2]: delta
+    3), 4 host weights in a rack, 3 device weights over the 64 hosts."""
+    flat, _, _ = cell()
+    hm = mapper._HostMap(flat)
     assert hm.table_mode and len(hm._distinct) == 9
-    ch, = mapper._choose_plans(hm, STEPS, 3, budget,
-                               cmap.Tunables())
-    assert [(lv.width, lv.delta, lv.read) for lv in ch.plan] == [
-        (8, 0, "const"), (4, 0, "onehot")]
-    assert [(lv.width, lv.delta, lv.read) for lv in ch.leaf_plan] == [
-        (4, 0, "onehot")]
+    ch, = mapper._choose_plans(hm, cell_steps(), 3, budget, cmap.Tunables())
+    levels = [(lv.width, lv.delta, len(lv.classes), lv.read)
+              for lv in ch.plan + ch.leaf_plan]
     # descents a lane: one a replica in the one-shot pass, `budget` a
     # replica unrolled, one pass of the retry loop in the exact program
     tries = {1: 1, 3: 3, 0: 1}[budget]
     assert ch.descents() == (3 * tries, 3 * tries)
-    assert ch.full_draws() == 3 * tries * (8 + 4 + 4)
+    if budget == 0:
+        assert levels == [(8, 0, 0, "const"), (8, 0, 0, "onehot"),
+                          (16, 0, 0, "onehot")]
+        assert ch.full_draws() == 3 * (8 + 8 + 16)
+    else:
+        assert levels == [(8, 3, 2, "const"), (8, 2, 4, "onehot"),
+                          (16, 2, 3, "onehot")]
+        # the classes are the weights' rows of the draw tables
+        w = np.asarray(flat.weights)
+        for lv in ch.plan + ch.leaf_plan:
+            rows = w[list(lv.frontier), :lv.width]
+            assert [int(hm._distinct[c]) for c in lv.classes] == sorted(
+                set(rows[rows > 0].tolist()))
+            assert lv.resolve == (budget == 3)
+        # a candidate a class where a contested draw is flagged, its
+        # runner-up too where it is settled
+        assert ch.full_draws() == 3 * tries * (2 + 4 + 3) * (
+            2 if budget == 3 else 1)
     # the same tree with mean weights is the one the fast path was made
-    # for: every level has its window
+    # for: every level has its window and one class a bucket
     mean, = mapper._choose_plans(mapper._HostMap(cluster(True).flatten()),
                                  STEPS, 3, 3, cmap.Tunables())
-    assert all(lv.delta > 0 for lv in mean.plan + mean.leaf_plan)
+    assert all(lv.delta > 0 and not lv.classes
+               for lv in mean.plan + mean.leaf_plan)
     assert mean.full_draws() == 0
+    # the small map above: two classes in the root, as many host weights
+    # as hosts in a rack (drawn in full), three classes over the hosts
+    small, = mapper._choose_plans(mapper._HostMap(cluster().flatten()),
+                                  STEPS, 3, budget, cmap.Tunables())
+    assert [(lv.width, lv.delta, len(lv.classes))
+            for lv in small.plan + small.leaf_plan] == (
+        [(8, 0, 0), (4, 0, 0), (4, 0, 0)] if budget == 0 else
+        [(8, 2, 2), (4, 0, 0), (4, 2, 3)])
+
+
+@functools.lru_cache(maxsize=None)
+def cell_sweep(op):
+    """sweep_device over the cell's map, CELL_IDS ids: (placements, what
+    the totals grew by, the span's counts, device weights)."""
+    flat, _, cfg = cell()
+    dev_w = np.full(cfg["num_osds"], 0x10000, np.uint32)
+    nrep = 3
+    if op == cmap.OP_CHOOSELEAF_INDEP:
+        # an EC pool's 4+2 with a host out and eight OSDs at 0.75
+        nrep = 6
+        dev_w[:16] = 0
+        dev_w[[16 * h + 5 for h in range(1, 9)]] = 0xC000
+    before = mapper.sweep_totals()
+    n0 = len(tracing.recorder().held()[0])
+    got, overflow = mapper.sweep_device(
+        flat, cell_steps(op), nrep, np.arange(CELL_IDS, dtype=np.int32),
+        dev_w, chunk=4096)
+    assert not bool(overflow)
+    span, = [r for r in tracing.recorder().held()[0][n0:]
+             if r[NAME] == "crush.sweep"]
+    after = mapper.sweep_totals()
+    return (np.asarray(got), {k: after[k] - before[k] for k in after},
+            span[COUNTS], dev_w)
 
 
 def test_the_span_counts_how_levels_draw_and_the_total_grows():
-    flat = cluster().flatten()
-    dev_w = np.full(N_OSDS, 0x10000, np.uint32)
-    before = mapper.sweep_totals()
-    n0 = len(tracing.recorder().held()[0])
-    sweep_device(flat, dev_w)
-    span, = [r for r in tracing.recorder().held()[0][n0:]
-             if r[NAME] == "crush.sweep"]
-    # three stage programs of three levels each, all through the tables
-    assert {k: span[COUNTS][k] for k in (
-        "draw_fast", "draw_table", "draw_limb", "const", "onehot",
-        "gather")} == {"draw_fast": 0, "draw_table": 9, "draw_limb": 0,
-                       "const": 3, "onehot": 6, "gather": 0}
-    after = mapper.sweep_totals()
-    grew = {k: after[k] - before[k] for k in after}
-    assert grew["crush.ids"] == len(XS)
-    assert 0.02 * len(XS) < grew["crush.mid_lanes"] < 0.2 * len(XS)
-    # 48 items an id in the one-shot pass, three tries of that a lane of
-    # the budgeted stage, one pass of it a lane of the exact stage
-    assert grew["crush.full_draws"] == 48 * (
-        len(XS) + 3 * grew["crush.mid_lanes"] + grew["crush.slow_lanes"])
+    flat, _, _ = cell()
+    _, grew, counts, dev_w = cell_sweep(cmap.OP_CHOOSELEAF_FIRSTN)
+    # three stage programs of three levels each: the one-shot and the
+    # budgeted one draw by classes, the exact one through the tables
+    assert {k: counts[k] for k in (
+        "draw_fast", "draw_class", "draw_table", "draw_limb", "const",
+        "onehot", "gather")} == {
+            "draw_fast": 0, "draw_class": 6, "draw_table": 3, "draw_limb": 0,
+            "const": 3, "onehot": 6, "gather": 0}
+    assert grew["crush.ids"] == CELL_IDS
+    assert 0.02 * CELL_IDS < grew["crush.mid_lanes"] < 0.2 * CELL_IDS
+    # 9 candidates a replica in the one-shot pass; candidate and
+    # runner-up, three tries a replica, a lane of the budgeted stage;
+    # every item, one pass, a lane of the exact stage
+    assert grew["crush.full_draws"] == (
+        27 * CELL_IDS + 162 * grew["crush.mid_lanes"]
+        + 96 * grew["crush.slow_lanes"])
     # the host sweep files the same
-    mapper.sweep(flat, STEPS, 3, XS, dev_w, chunk=1024)
-    host = {k: v - after[k] for k, v in mapper.sweep_totals().items()}
+    before = mapper.sweep_totals()
+    mapper.sweep(flat, cell_steps(), 3, np.arange(CELL_IDS, dtype=np.int32),
+                 dev_w, chunk=4096)
+    host = {k: v - before[k] for k, v in mapper.sweep_totals().items()}
     assert host == grew
     assert tracing.SPANS["crush.full_draws"] == "crush_full_draws_per_id"
+
+
+def contested_lanes(flat, root, xs):
+    """Lanes of the firstn one-shot pass (replica r descends with r at
+    every level: vary_r 1, stable) in which a weight class of a bucket
+    on a replica's way is contested, reckoned on the host with the true
+    winners."""
+    from ceph_tpu.crush import hashes, ln
+
+    items, weights = np.asarray(flat.items), np.asarray(flat.weights)
+    sizes = np.asarray(flat.sizes)
+    bounds = ln.fastcmp_bounds()
+    flagged = np.zeros(len(xs), bool)
+    for r in range(3):
+        bno = np.full(len(xs), -1 - root)
+        for _ in range(3):   # root, rack, host
+            width = int(sizes[bno].max())
+            its, ws = items[bno, :width], weights[bno, :width]
+            u = (hashes.hash32_3(xs.astype(np.uint32)[:, None],
+                                 its.astype(np.uint32), np.uint32(r), xp=np)
+                 & 0xFFFF).astype(np.int64)
+            delta = next(d for d, b in bounds.items() if ws.max() <= b)
+            for w in np.unique(ws[ws > 0]):
+                uc = np.where(ws == w, u, -1)
+                u1 = uc.max(axis=1)
+                u2 = np.where(uc == u1[:, None], -1, uc).max(axis=1)
+                flagged |= (u2 >= 0) & (u1 - u2 <= delta)
+            win = ln.straw2_draw(u.astype(np.uint32), ws).argmax(axis=1)
+            bno = -1 - its[np.arange(len(xs)), win]
+    return flagged
+
+
+def test_the_cells_firstn_sweeps_equal_crush_do_rule():
+    """sweep_device == sweep() == _native.do_rule on the cell's own map,
+    and the lanes whose class draw is contested are in crush.mid_lanes:
+    the one-shot pass flags them for the budgeted stage."""
+    flat, root, _ = cell()
+    xs = np.arange(CELL_IDS, dtype=np.int32)
+    got, grew, _, dev_w = cell_sweep(cmap.OP_CHOOSELEAF_FIRSTN)
+    steps = np.asarray(cell_steps(), dtype=np.int32).ravel()
+    want = np.array([_native.do_rule(flat, steps, int(x), 3, dev_w)
+                     for x in xs])
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        mapper.sweep(flat, cell_steps(), 3, xs, dev_w, chunk=4096), want)
+    fast = mapper.compile_rule(flat, cell_steps(), 3, one_shot=True)
+    clean = np.concatenate([np.asarray(fast(xs[o:o + 4096], dev_w)[1])
+                            for o in range(0, CELL_IDS, 4096)])
+    assert grew["crush.mid_lanes"] == int((~clean).sum())
+    flagged = contested_lanes(flat, root, xs)
+    # three replicas x about (8 x 3 + 8 x 2 + 16 x 2) / 65536 at most
+    assert 10 < flagged.sum() < 0.0033 * 1.5 * CELL_IDS
+    assert not clean[flagged].any()
+    assert all(len(set(row // 16)) == 3 for row in want[:256])
+
+
+def test_the_cells_indep_sweeps_equal_crush_do_rule():
+    """`chooseleaf indep` over the same map (a vector of slots through
+    _straw2_choose_slots, class levels resolving): sweep_device ==
+    sweep() == _native.do_rule with a host out and OSDs reweighted."""
+    flat, _, _ = cell()
+    op = cmap.OP_CHOOSELEAF_INDEP
+    xs = np.arange(CELL_IDS, dtype=np.int32)
+    got, grew, counts, dev_w = cell_sweep(op)
+    assert counts["mode"] == "indep" and counts["draw_class"] >= 3
+    steps = np.asarray(cell_steps(op), dtype=np.int32).ravel()
+    want = np.array([_native.do_rule(flat, steps, int(x), 6, dev_w)
+                     for x in xs])
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        mapper.sweep(flat, cell_steps(op), 6, xs, dev_w, chunk=4096), want)
+    assert not np.isin(want, np.arange(16)).any()
+    assert grew["crush.mid_lanes"] > 0
 
 
 def test_uniform_weights_draw_in_full_in_the_exact_stage_only():
@@ -218,7 +369,8 @@ def test_uniform_weights_draw_in_full_in_the_exact_stage_only():
     assert (fast.full_draws, mid.full_draws, slow.full_draws) == (
         0, 0, 3 * (8 + 8))
     assert fast.draws["draw_fast"] == mid.draws["draw_fast"] == 2
-    assert slow.draws == {"draw_fast": 0, "draw_table": 2, "draw_limb": 0}
+    assert slow.draws == {"draw_fast": 0, "draw_class": 0, "draw_table": 2,
+                          "draw_limb": 0}
 
 
 def test_the_mean_weight_map_places_elsewhere():

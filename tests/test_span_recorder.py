@@ -396,8 +396,8 @@ def test_named_scopes_leave_sweep_device_bit_identical():
     assert sweep[COUNTS] == {"ids": 1024, "chunk": 1024, "numrep": 3,
                              "mode": "firstn", "cap": 1024, "cap2": 1024,
                              "budget": 3, "const": 3, "onehot": 3,
-                             "gather": 0, "draw_fast": 4, "draw_table": 2,
-                             "draw_limb": 0}
+                             "gather": 0, "draw_fast": 4, "draw_class": 0,
+                             "draw_table": 2, "draw_limb": 0}
     assert any(r[NAME] == "dev.dispatch" and r[PARENT] == sweep[ID]
                and r[COUNTS]["family"] == "crush_mapper" for r in new)
     # the three stage programs sit under their scopes inside the one
