@@ -32,6 +32,15 @@ row pairs, and the per-layer MDS step collapses into ONE coding-matrix
 matmul over all layers (ceph_tpu.ops.gf256_swar).  The general
 multi-erasure decode runs the intersection-score layer ordering
 host-side with a cached device matmul per IS level.
+
+A call codes one codeword: a chunk of n bytes is q^t sub-chunks of
+n/q^t.  Every step is elementwise over the bytes within a sub-chunk, so
+that axis also carries MANY codewords side by side.  An EC pool codes
+each stripe by itself (stripe_unit bytes a chunk: 64 sub-chunks of 64 B
+for k=8 m=4 d=11 at 4 KiB, as upstream's ECUtil::encode hands the
+plugin stripe_width bytes a call); StripeBatchQueue._dispatch_array
+lays the stripes of a shard, and the jobs of a batch, side by side
+along that axis, so the device programs see one wide codeword.
 """
 
 from __future__ import annotations
@@ -40,6 +49,8 @@ from typing import Dict, Iterable, List, Mapping, Sequence, Tuple
 
 import numpy as np
 
+from ceph_tpu.core import tracing
+from ceph_tpu.core.lockdep import make_lock
 from ceph_tpu.ec import gf, matrices
 from ceph_tpu.ec.interface import (
     SIMD_ALIGN,
@@ -53,6 +64,24 @@ from ceph_tpu.ops import gf256_swar
 
 def _gf_pair(a: int, b: int) -> np.ndarray:
     return np.array([[a, b]], dtype=np.uint32)
+
+
+# calls of the GF(2^8) engine made by every clay codec of the process (a
+# device call each on the chip): the program counter `clay.dev_calls`
+_dev_calls = 0
+_dev_calls_lock = make_lock("clay.dev_calls")
+
+
+def dev_calls() -> int:
+    return _dev_calls
+
+
+def _gf_call(M: np.ndarray, x: np.ndarray, **kw) -> np.ndarray:
+    """`gf_matmul_bytes` in the clay family, counted."""
+    global _dev_calls
+    with _dev_calls_lock:
+        _dev_calls += 1
+    return gf256_swar.gf_matmul_bytes(M, x, family="gf256_clay", **kw)
 
 
 class ClayCodec(ErasureCode):
@@ -170,8 +199,7 @@ class ClayCodec(ErasureCode):
             [np.ascontiguousarray(a).ravel(),
              np.ascontiguousarray(b).ravel()]
         ).astype(np.uint8)
-        out = np.asarray(gf256_swar.gf_matmul_bytes(
-            M, stacked, family="gf256_clay"))
+        out = np.asarray(_gf_call(M, stacked))
         return out.reshape(np.shape(a))
 
     def _uncouple_nodes(self, C: np.ndarray,
@@ -208,20 +236,27 @@ class ClayCodec(ErasureCode):
             )
         s = n // self.sub_count
         Z = self.sub_count
-        C = np.zeros((self.kk + self._m, Z, s), dtype=np.uint8)
-        C[: self._k] = data.reshape(self._k, Z, s)
         dnodes = np.arange(self.kk)
-        U_data = self._uncouple_nodes(C, dnodes)
+        # coupled symbols (node x layer) of the data nodes and of the
+        # parity column: what the two pair transforms count
+        pairs_d = int((~self.dot[: self.kk]).sum())
+        pairs_p = int((~self.dot[self.kk:]).sum())
+        with tracing.span("clay.uncouple", pairs=pairs_d,
+                          bytes=2 * pairs_d * s):
+            C = np.zeros((self.kk + self._m, Z, s), dtype=np.uint8)
+            C[: self._k] = data.reshape(self._k, Z, s)
+            U_data = self._uncouple_nodes(C, dnodes)
         # per-layer MDS: U_parity = coding @ U_data, all layers at once
-        U_flat = U_data.reshape(self.kk, Z * s)
-        U_par = np.asarray(
-            gf256_swar.gf_matmul_bytes(self.coding, U_flat,
-                                       family="gf256_clay")
-        ).reshape(self._m, Z, s)
+        with tracing.span("clay.mds", layers=Z, bytes=self.kk * n):
+            U_flat = U_data.reshape(self.kk, Z * s)
+            U_par = np.asarray(_gf_call(self.coding, U_flat)).reshape(
+                self._m, Z, s)
         # couple the parity column back to stored symbols
-        U_all = np.concatenate([U_data, U_par])
-        pnodes = np.arange(self.kk, self.kk + self._m)
-        C_par = self._couple_nodes(U_all, pnodes)
+        with tracing.span("clay.couple", pairs=pairs_p,
+                          bytes=2 * pairs_p * s):
+            U_all = np.concatenate([U_data, U_par])
+            pnodes = np.arange(self.kk, self.kk + self._m)
+            C_par = self._couple_nodes(U_all, pnodes)
         return C_par.reshape(self._m, n)
 
     # -- repair (single erasure, the MSR bandwidth win) --------------------
@@ -320,66 +355,67 @@ class ClayCodec(ErasureCode):
                 f"clay repair_planes: bad planes {planes.shape} "
                 f"(want ({len(helpers)}, {L}, S))"
             )
-        s = planes.shape[2]
-        n_total = self.kk + self._m
-        # read planes [n_total, L, s], indexed by INTERNAL node id;
-        # virtual nodes stay zero (their reads are free)
-        Cr = np.zeros((n_total, L, s), dtype=np.uint8)
-        for hi, h in enumerate(helpers):
-            Cr[self._node(h)] = planes[hi]
-        # map a global layer index to its position in `layers`
-        lpos = np.full(Z, -1)
-        lpos[layers] = np.arange(L)
+        with tracing.span("clay.repair", layers=L, bytes=planes.nbytes):
+            s = planes.shape[2]
+            n_total = self.kk + self._m
+            # read planes [n_total, L, s], indexed by INTERNAL node id;
+            # virtual nodes stay zero (their reads are free)
+            Cr = np.zeros((n_total, L, s), dtype=np.uint8)
+            for hi, h in enumerate(helpers):
+                Cr[self._node(h)] = planes[hi]
+            # map a global layer index to its position in `layers`
+            lpos = np.full(Z, -1)
+            lpos[layers] = np.arange(L)
 
-        # 1. U of nodes outside column y0: their partners are also in the
-        #    repair layer set (partner layer only changes digit y != y0)
-        nodes_other = np.array([i for i in range(n_total) if i // q != y0])
-        own = Cr[nodes_other]
-        pn = self.pnode[nodes_other][:, layers]
-        pzl = lpos[self.pz[nodes_other][:, layers]]
-        dot = self.dot[nodes_other][:, layers]
-        # dot positions pass C through untouched — gather partners and
-        # run the pair transform ONLY where coupling happens (1/q of
-        # the grid is dot, so this trims the matmul width by ~25% for
-        # q=4 and skips the partner gather at those positions)
-        nd = ~dot
-        U_known = own.copy()
-        if nd.any():
-            U_known[nd] = self._apply_pair(
-                self._uncouple_M, own[nd], Cr[pn[nd], pzl[nd]])
+            # 1. U of nodes outside column y0: their partners are also in the
+            #    repair layer set (partner layer only changes digit y != y0)
+            nodes_other = np.array([i for i in range(n_total) if i // q != y0])
+            own = Cr[nodes_other]
+            pn = self.pnode[nodes_other][:, layers]
+            pzl = lpos[self.pz[nodes_other][:, layers]]
+            dot = self.dot[nodes_other][:, layers]
+            # dot positions pass C through untouched — gather partners and
+            # run the pair transform ONLY where coupling happens (1/q of
+            # the grid is dot, so this trims the matmul width by ~25% for
+            # q=4 and skips the partner gather at those positions)
+            nd = ~dot
+            U_known = own.copy()
+            if nd.any():
+                U_known[nd] = self._apply_pair(
+                    self._uncouple_M, own[nd], Cr[pn[nd], pzl[nd]])
 
-        # 2. MDS-solve the q column-y0 U rows in every repair layer at
-        #    once (q == m unknowns per layer, one cached matrix)
-        col = list(range(y0 * q, y0 * q + q))
-        U_col = self._solve_unknowns(
-            col, nodes_other.tolist(),
-            U_known.reshape(len(nodes_other), -1),
-        ).reshape(q, L, s)
+            # 2. MDS-solve the q column-y0 U rows in every repair layer at
+            #    once (q == m unknowns per layer, one cached matrix)
+            col = list(range(y0 * q, y0 * q + q))
+            U_col = self._solve_unknowns(
+                col, nodes_other.tolist(),
+                U_known.reshape(len(nodes_other), -1),
+            ).reshape(q, L, s)
 
-        # 3a. dot layers of the lost node: C = U
-        out = np.zeros((Z, s), dtype=np.uint8)
-        out[layers] = U_col[x0]
+            # 3a. dot layers of the lost node: C = U
+            out = np.zeros((Z, s), dtype=np.uint8)
+            out[layers] = U_col[x0]
 
-        # 3b. other layers: C(A) = (det*U(B) + C(B)) / g where B is the
-        #     partner (surviving column-y0 node, repair layer)
-        pw_y0 = q ** (self.t - 1 - y0)
-        # one _repair_M transform serves every partner column: batch
-        # the q-1 per-column slices into a single wide matmul instead
-        # of q-1 narrow dispatches
-        zs_cat, ub_cat, cb_cat = [], [], []
-        for xb in range(q):
-            if xb == x0:
-                continue
-            zs_a = np.nonzero(self.digits[y0] == xb)[0]  # lost-node layers
-            zb = lpos[zs_a + (x0 - xb) * pw_y0]
-            assert (zb >= 0).all()
-            zs_cat.append(zs_a)
-            ub_cat.append(U_col[xb, zb])
-            cb_cat.append(Cr[y0 * q + xb, zb])
-        out[np.concatenate(zs_cat)] = self._apply_pair(
-            self._repair_M, np.concatenate(ub_cat),
-            np.concatenate(cb_cat))
-        return out
+            # 3b. other layers: C(A) = (det*U(B) + C(B)) / g where B is the
+            #     partner (surviving column-y0 node, repair layer)
+            pw_y0 = q ** (self.t - 1 - y0)
+            # one _repair_M transform serves every partner column: batch
+            # the q-1 per-column slices into a single wide matmul instead
+            # of q-1 narrow dispatches
+            zs_cat, ub_cat, cb_cat = [], [], []
+            for xb in range(q):
+                if xb == x0:
+                    continue
+                zs_a = np.nonzero(self.digits[y0] == xb)[0]  # lost-node layers
+                zb = lpos[zs_a + (x0 - xb) * pw_y0]
+                assert (zb >= 0).all()
+                zs_cat.append(zs_a)
+                ub_cat.append(U_col[xb, zb])
+                cb_cat.append(Cr[y0 * q + xb, zb])
+            out[np.concatenate(zs_cat)] = self._apply_pair(
+                self._repair_M, np.concatenate(ub_cat),
+                np.concatenate(cb_cat))
+            return out
 
     def _solve_unknowns(self, unknown: List[int], known: List[int],
                         U_known: np.ndarray) -> np.ndarray:
@@ -397,8 +433,10 @@ class ClayCodec(ErasureCode):
             self._solve_cache[key] = M
         # M depends on the erasure signature: passed as data, so one
         # program per width serves every signature
-        return gf256_swar.gf_matmul_bytes(
-            M, U_known[: self.kk], family="gf256_clay", operand=True)
+        known = U_known[: self.kk]
+        with tracing.span("clay.solve", rows=len(unknown),
+                          bytes=known.nbytes):
+            return _gf_call(M, known, operand=True)
 
     # -- general decode (multi-erasure, layered IS ordering) ---------------
     def decode_array(
@@ -506,12 +544,13 @@ class ClayCodec(ErasureCode):
         return np.stack([np.asarray(out[i]) for i in range(self._k)])
 
     def supports_partial_writes(self) -> bool:
-        """False: clay couples layers across the whole chunk.  A byte at
-        sub-chunk z of any data chunk feeds, via the pairwise coupling,
-        the uncoupled symbol at the PARTNER layer z(y->x) of another
-        node — so the only write sets closed under the coupling are
-        full chunks, and extent-local parity deltas cannot exist (the
-        reference likewise refuses ec_overwrites on clay pools)."""
+        """False: clay couples layers across a codeword's whole chunk.
+        A byte at sub-chunk z of any data chunk feeds, via the pairwise
+        coupling, the uncoupled symbol at the PARTNER layer z(y->x) of
+        another node — so the only write sets closed under the coupling
+        are whole codewords (a pool's whole stripes), and extent-local
+        parity deltas cannot exist (the reference likewise refuses
+        ec_overwrites on clay pools)."""
         return False
 
 

@@ -41,6 +41,7 @@ from ceph_tpu.core.crc import crc32c
 from ceph_tpu.core.encoding import Decoder, Encoder
 from ceph_tpu.core import failpoint as fp
 from ceph_tpu.core.lockdep import make_lock
+from ceph_tpu.ec.interface import ErasureCodeError
 from ceph_tpu.osd import messages as m
 from ceph_tpu.osd.types import EVersion, LogEntry, PGId
 from ceph_tpu.store.objectstore import (
@@ -274,10 +275,11 @@ class PGBackend:
             # trop rides the job so the queue can blame a live XLA
             # compile for this op's wait (compile_wait annotation)
             fut = (self.queue.encode_crc_async(self.codec, planes,
-                                               size=size, trop=trop)
+                                               size=size, trop=trop,
+                                               chunk=self.unit)
                    if fused else
                    self.queue.encode_async(self.codec, planes,
-                                           trop=trop))
+                                           trop=trop, chunk=self.unit))
         except BaseException:
             self._fan_run(ticket, lambda: None)  # never park the line
             raise
@@ -569,6 +571,16 @@ class ECBackend(PGBackend):
         self.queue = default_queue()
         prof = getattr(codec, "profile", {}) or {}
         self.unit = int(prof.get("stripe_unit", 4096))
+        # array codecs (clay): every stripe is a codeword of its own, a
+        # chunk of `unit` bytes in sub_chunk_count sub-chunks (upstream
+        # codes stripe_width bytes a call, ECUtil::encode).  Upstream's
+        # mon refuses a pool whose stripe_unit the plugin would pad
+        # (OSDMonitor::prepare_pool_stripe_width); here the backend does
+        Z = int(codec.get_sub_chunk_count())
+        if self.unit % Z:
+            raise ErasureCodeError(
+                f"stripe_unit {self.unit} does not match ec profile "
+                f"alignment: {Z} sub-chunks a chunk")
         self.cache = ExtentCache()
         self._sinfo = None  # lazy StripeInfo (ecutil.py)
 
@@ -608,15 +620,7 @@ class ECBackend(PGBackend):
         single sanctioned upload, not a crossing)."""
         if isinstance(data, DeviceBuf):
             data = data.np1d()
-        planes, S = self._interleave(data)
-        cols = S * self.unit
-        # array codecs (clay) need columns divisible by sub_chunk_count
-        D = self.codec.get_sub_chunk_count()
-        if cols % D:
-            planes = np.concatenate(
-                [planes,
-                 np.zeros((self.k, D - cols % D), dtype=np.uint8)], axis=1)
-        return planes
+        return self._interleave(data)[0]
 
     @staticmethod
     def _chunks_of(planes: np.ndarray, coding, k: int,
@@ -631,7 +635,7 @@ class ECBackend(PGBackend):
         encode_async inside submit() instead, so concurrent writes'
         planes coalesce into one device matmul."""
         planes = self._prep_planes(data)
-        coding = self.queue.encode(self.codec, planes)
+        coding = self.queue.encode(self.codec, planes, chunk=self.unit)
         return (self._chunks_of(planes, coding, self.k, self.m),
                 planes.shape[1])
 
@@ -1071,16 +1075,18 @@ class ECBackend(PGBackend):
     ) -> Tuple[Optional[bytes], int, int]:
         """Sub-chunk runs of a local shard chunk for the clay repair
         plan: (data, code, served).  served=1 -> `data` is the
-        requested runs' bytes concatenated in run order, read through
-        the extent-sealed read_local_chunk_extent2 path (runs arrive
-        in SUB-CHUNK units — the primary does not know this peer's
-        chunk size, so the scaling by the stored chunk length happens
-        here).  served=0 -> the runs could not be mapped onto the
-        stored chunk (absent shard, geometry that does not divide into
-        sub-chunks, out-of-range runs): the caller serves the whole
-        chunk instead, exactly like a legacy peer.  A mapped extent
-        that fails to read returns (None, code, 1) with the usual
-        ECRC/EIO verdict contract."""
+        requested runs' bytes of every stripe of the chunk (a stripe is
+        a codeword of its own: `unit` bytes in Z sub-chunks), stripe
+        after stripe and in run order within one, read through the
+        extent-sealed read_local_chunk_extent2 path (runs arrive in
+        SUB-CHUNK units — the primary does not know this peer's chunk
+        size, so the scaling by the stored chunk length happens here).
+        served=0 -> the runs could not be mapped onto the stored chunk
+        (absent shard, a chunk that is no whole number of stripes,
+        out-of-range runs): the caller serves the whole chunk instead,
+        exactly like a legacy peer.  A mapped extent that fails to read
+        returns (None, code, 1) with the usual ECRC/EIO verdict
+        contract."""
         Z = int(self.codec.get_sub_chunk_count())
         if Z <= 1 or not runs:
             return None, 0, 0
@@ -1089,20 +1095,29 @@ class ECBackend(PGBackend):
             clen = self.store.stat(self.coll, g)
         except Exception:
             return None, 0, 0  # absent: whole-chunk path answers EIO
-        if clen <= 0 or clen % Z:
+        if (clen <= 0 or clen % self.unit
+                or any(so < 0 or cnt <= 0 or so + cnt > Z
+                       for so, cnt in runs)):
             return None, 0, 0
-        sub = clen // Z
-        if any(so < 0 or cnt <= 0 or so + cnt > Z for so, cnt in runs):
-            return None, 0, 0
-        parts: List[bytes] = []
-        for so, cnt in runs:
-            data, code = self.read_local_chunk_extent2(
-                oid, shard, so * sub, cnt * sub)
+        if not (getattr(self.store, "checksums_at_rest", False)
+                or getattr(self.store, "verify_reads", False)):
+            # a store that verifies nothing it serves: the whole chunk
+            # against its hinfo crc ONCE, then the runs out of it
+            data, code = self.read_local_chunk2(oid, shard)
             if data is None:
                 return None, code, 1
-            if len(data) != cnt * sub:
-                return None, 0, 0  # short read: geometry lied
-            parts.append(data)
+            return self.sinfo.sub_chunk_runs(data, Z, runs), 0, 1
+        sub = self.unit // Z
+        parts: List[bytes] = []
+        for base in range(0, clen, self.unit):
+            for so, cnt in runs:
+                data, code = self.read_local_chunk_extent2(
+                    oid, shard, base + so * sub, cnt * sub)
+                if data is None:
+                    return None, code, 1
+                if len(data) != cnt * sub:
+                    return None, 0, 0  # short read: geometry lied
+                parts.append(data)
         return b"".join(parts), 0, 1
 
     def local_size(self, oid: str,
@@ -1185,8 +1200,16 @@ class ECBackend(PGBackend):
             return None
         n = len(next(iter(arrs.values())))
         want = list(range(self.k))
-        data_chunks = self.codec.decode_array(arrs, want, n)
-        planes = np.stack([np.asarray(data_chunks[i]) for i in range(self.k)])
+        if all(i in arrs for i in want):
+            planes = np.stack([arrs[i] for i in want])
+        elif self.codec.get_sub_chunk_count() > 1:
+            # array codec: the queue's array branch lays the stripes'
+            # codewords side by side for the codec
+            planes = np.asarray(self.queue.clay_decode_async(
+                self.codec, arrs, chunk=self.unit).result())
+        else:
+            data_chunks = self.codec.decode_array(arrs, want, n)
+            planes = np.stack([np.asarray(data_chunks[i]) for i in want])
         return self._state_from_planes(oid, planes, avail, meta)
 
     def _note_decode_job(self) -> None:
@@ -1232,7 +1255,8 @@ class ECBackend(PGBackend):
             # "dec" (this replaces the old full-decode-on-a-worker-
             # thread host bypass, the last codec path that dodged the
             # device queue)
-            fut = self.queue.clay_decode_async(self.codec, arrs)
+            fut = self.queue.clay_decode_async(self.codec, arrs,
+                                               chunk=self.unit)
         else:  # pragma: no cover — codec with neither kernel
             spawn(lambda: done(self.reconstruct(oid, avail, meta)))
             return
@@ -1257,9 +1281,10 @@ class ECBackend(PGBackend):
                            layers: Dict[int, bytes],
                            done: Callable[[Optional[bytes]], None]) -> None:
         """Clay single-shard repair from layers-only helper bytes: each
-        ``layers[h]`` holds helper h's repair-layer sub-chunks
-        concatenated in layer order (the sub-chunk read plan's wire
-        payload — d/(k*q) of a whole-chunk gather).  Rides the
+        ``layers[h]`` holds helper h's repair-layer sub-chunks, the
+        chunk's stripes one after another and in layer order within a
+        stripe (the sub-chunk read plan's wire payload — d/(k*q) of a
+        whole-chunk gather).  Rides the
         StripeBatchQueue "crep" kind so concurrent single-shard repairs
         sharing a (lost, helpers) signature coalesce into one batched
         coupled-layer matmul; `done(chunk_bytes)` runs on a fresh
@@ -1270,18 +1295,19 @@ class ECBackend(PGBackend):
 
         codec = self.codec
         helpers = sorted(layers)
-        L = len(codec.repair_layers(lost))
+        # one stripe's repair read of a helper: L sub-chunks
+        per = (len(codec.repair_layers(lost)) * self.unit
+               // codec.get_sub_chunk_count())
         width = len(layers[helpers[0]]) if helpers else 0
-        if (L == 0 or width == 0 or width % L
+        if (per == 0 or width == 0 or width % per
                 or any(len(layers[h]) != width for h in helpers)):
             spawn(lambda: done(None))
             return
-        s = width // L
         planes = np.stack([
-            np.frombuffer(layers[h], dtype=np.uint8).reshape(L, s)
-            for h in helpers])
+            np.frombuffer(layers[h], dtype=np.uint8) for h in helpers])
         self._note_decode_job()
-        fut = self.queue.clay_repair_async(codec, lost, helpers, planes)
+        fut = self.queue.clay_repair_async(codec, lost, helpers, planes,
+                                           chunk=self.unit)
 
         def finish(f) -> None:
             def complete() -> None:

@@ -74,6 +74,17 @@ class StripeInfo:
         """Per-shard (offset, length) holding stripes [s0, s1)."""
         return s0 * self.chunk_size, (s1 - s0) * self.chunk_size
 
+    def sub_chunk_runs(self, chunk: bytes, sub_chunks: int,
+                       runs) -> bytes:
+        """The sub-chunk `runs` ([(first, count)] of the `sub_chunks` a
+        stripe's chunk_size bytes divide into) of every stripe of a
+        shard chunk, stripe after stripe: what a clay repair reads of a
+        helper, each stripe being a codeword of its own."""
+        a = np.frombuffer(chunk, dtype=np.uint8).reshape(
+            -1, sub_chunks, self.chunk_size // sub_chunks)
+        idx = np.concatenate([np.arange(so, so + cnt) for so, cnt in runs])
+        return a[:, idx].tobytes()
+
     # -- planes layout -----------------------------------------------------
     def interleave(self, data: bytes) -> Tuple[np.ndarray, int]:
         """Object bytes -> data planes [k, S*chunk_size] (zero-padded);

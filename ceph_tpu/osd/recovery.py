@@ -143,6 +143,7 @@ class ChunkGather:
         self.sub_avail: Dict[int, bytes] = {}  # helper -> layer bytes
         self.sub_count = int(be.codec.get_sub_chunk_count()) \
             if hasattr(be, "codec") else 1
+        self.sinfo = getattr(be, "sinfo", None)  # the stripes' algebra
         self.wire_bytes = 0  # chunk payload bytes received from peers
         if plan_repair and not self.av_reject:
             self._plan_sub_reads(be, acting)
@@ -225,11 +226,10 @@ class ChunkGather:
                 out[h] = self.sub_avail[h]
             elif h in self.cur_avail:
                 c = self.cur_avail[h]
-                if len(c) % self.sub_count:
+                if len(c) % self.sinfo.chunk_size:
                     return None
-                sub = len(c) // self.sub_count
-                out[h] = b"".join(c[so * sub: (so + cnt) * sub]
-                                  for so, cnt in runs)
+                out[h] = self.sinfo.sub_chunk_runs(
+                    c, self.sub_count, runs)
             else:
                 return None
         widths = {len(b) for b in out.values()}

@@ -504,7 +504,8 @@ class ScrubEngine:
                         # survivor signature makes this a TRUE decode,
                         # and objects sharing a signature still
                         # coalesce into one device pass
-                        fut = queue.clay_decode_async(be.codec, arrs)
+                        fut = queue.clay_decode_async(
+                            be.codec, arrs, chunk=be.unit)
             jobs.append((oid, avail, metas, errs, sig, fut))
         for oid, avail, metas, errs, sig, fut in jobs:
             bad = list(errs)

@@ -34,13 +34,18 @@ from ceph_tpu.tpu.staging import DevPathStats, StagingPool
 
 class _Job:
     __slots__ = ("codec", "planes", "future", "kind", "sig", "size",
-                 "t_enq", "trop")
+                 "t_enq", "trop", "chunk")
 
     def __init__(self, codec, planes: np.ndarray, kind: str = "enc",
                  sig: Tuple[int, ...] = (), size: int = 0,
-                 trop=None) -> None:
+                 trop=None, chunk: int = 0) -> None:
         self.codec = codec
         self.planes = planes
+        # array codecs (clay): the bytes of ONE codeword's chunk.  A row
+        # of `planes` is whole codewords one after another (a shard's
+        # stripes, each coded by itself as upstream's ECUtil::encode
+        # codes stripe_width bytes a call); 0 = the row is one codeword
+        self.chunk = chunk
         # "enc" | "encp" (fused crc) | "dec" (flat recovery matmul) |
         # "cdec" (array-codec decode) | "crep" (clay sub-chunk repair)
         self.kind = kind
@@ -178,19 +183,23 @@ class StripeBatchQueue:
 
     # -- API --------------------------------------------------------------
     def encode_async(self, codec, planes: np.ndarray,
-                     trop=None) -> Future:
-        """planes: uint8 [k, n] -> Future of coding planes [m, n]."""
+                     trop=None, chunk: int = 0) -> Future:
+        """planes: uint8 [k, n] -> Future of coding planes [m, n].
+        `chunk` (here and below) matters to array codecs alone: a row
+        is n/chunk codewords, each coded by itself (see _Job.chunk)."""
         self.start()
         job = _Job(codec, np.ascontiguousarray(planes, dtype=np.uint8),
-                   trop=trop)
+                   trop=trop, chunk=chunk)
         self._q.put(job)
         return job.future
 
-    def encode(self, codec, planes: np.ndarray) -> np.ndarray:
-        return self.encode_async(codec, planes).result()
+    def encode(self, codec, planes: np.ndarray,
+               chunk: int = 0) -> np.ndarray:
+        return self.encode_async(codec, planes, chunk=chunk).result()
 
     def encode_crc_async(self, codec, planes: np.ndarray,
-                         size: int = 0, trop=None) -> Future:
+                         size: int = 0, trop=None,
+                         chunk: int = 0) -> Future:
         """Fused encode + per-shard crc32c: planes uint8 [k, n] ->
         Future of (coding [m, n], crcs u32 [k+m]).
 
@@ -201,7 +210,7 @@ class StripeBatchQueue:
         forcing a d2h fetch (or host re-read) of payload bytes."""
         self.start()
         job = _Job(codec, np.ascontiguousarray(planes, dtype=np.uint8),
-                   kind="encp", size=size, trop=trop)
+                   kind="encp", size=size, trop=trop, chunk=chunk)
         self._q.put(job)
         return job.future
 
@@ -228,10 +237,13 @@ class StripeBatchQueue:
         return self.decode_data_async(codec, available).result()
 
     def clay_repair_async(self, codec, lost: int, helpers,
-                          planes: np.ndarray, trop=None) -> Future:
-        """Layers-only survivor planes [d, L, s] -> Future of the
-        rebuilt chunk bytes [Z*s] (row order = sorted helpers, layer
-        order = codec.repair_layers(lost)).
+                          planes: np.ndarray, trop=None,
+                          chunk: int = 0) -> Future:
+        """Layers-only survivor planes [d, L, s] (or, rows of n/chunk
+        codewords, [d, S*L*s]: each stripe's L repair sub-chunks, stripe
+        after stripe, as a helper reads them off its shard) -> Future of
+        the rebuilt chunk bytes [S*Z*s] (row order = sorted helpers,
+        layer order = codec.repair_layers(lost)).
 
         The MSR-repair twin of encode_async: concurrent single-shard
         repairs of the SAME lost shard (a recovery window draining one
@@ -239,21 +251,21 @@ class StripeBatchQueue:
         byte axis into one set of coupled-layer matmuls."""
         self.start()
         planes = np.ascontiguousarray(planes, dtype=np.uint8)
-        d, L, s = planes.shape
-        job = _Job(codec, planes.reshape(d * L, s), kind="crep",
+        job = _Job(codec, planes.reshape(planes.shape[0], -1),
+                   kind="crep",
                    sig=(int(lost),) + tuple(int(h) for h in helpers),
-                   trop=trop)
+                   trop=trop, chunk=chunk)
         self._q.put(job)
         return job.future
 
     def clay_repair(self, codec, lost: int, helpers,
-                    planes: np.ndarray) -> np.ndarray:
+                    planes: np.ndarray, chunk: int = 0) -> np.ndarray:
         return self.clay_repair_async(codec, lost, helpers,
-                                      planes).result()
+                                      planes, chunk=chunk).result()
 
     def clay_decode_async(self, codec,
                           available: "Dict[int, np.ndarray]",
-                          trop=None) -> Future:
+                          trop=None, chunk: int = 0) -> Future:
         """Survivor chunks {shard: [n]} -> Future of data planes [k, n]
         for an array codec (clay).  Jobs sharing a survivor signature
         coalesce like "dec", but along the intra-sub-chunk byte axis
@@ -262,11 +274,11 @@ class StripeBatchQueue:
         instead of running the general multi-erasure decode."""
         self.start()
         sig = tuple(sorted(available))
-        Z = int(codec.get_sub_chunk_count())
-        stacked = np.ascontiguousarray(np.concatenate(
-            [np.asarray(available[i], dtype=np.uint8).reshape(Z, -1)
+        stacked = np.ascontiguousarray(np.stack(
+            [np.asarray(available[i], dtype=np.uint8).reshape(-1)
              for i in sig]))
-        job = _Job(codec, stacked, kind="cdec", sig=sig, trop=trop)
+        job = _Job(codec, stacked, kind="cdec", sig=sig, trop=trop,
+                   chunk=chunk)
         self._q.put(job)
         return job.future
 
@@ -354,68 +366,71 @@ class StripeBatchQueue:
 
     def _dispatch_array(self, codec, batch: List[_Job],
                         widths: List[int]):
-        """Array-codec (clay) batch: jobs concatenate along the INTRA-
-        sub-chunk byte axis, not the raw column axis — the coupled-
-        layer transforms are elementwise over that axis (each byte
-        position within a sub-chunk is independent), while a raw byte
-        concat (or a raw tail pad) would let the layer axis absorb a
-        neighbour's bytes and corrupt every job in the batch.  The
+        """Array-codec (clay) batch: a job's row is S codewords (the
+        stripes of a shard, `_Job.chunk` bytes each) of Z sub-chunks of
+        s bytes, and codewords concatenate along the INTRA-sub-chunk
+        byte axis — stripes of one job and jobs of one batch alike: the
+        coupled-layer transforms are elementwise over that axis (each
+        byte position within a sub-chunk is independent), while a raw
+        byte concat (or a raw tail pad) would let the layer axis absorb
+        a neighbour's bytes and corrupt every job in the batch.  The
         per-layer width is covering-padded to a pow2 so the flattened
         pair/solve matmul widths inside the codec stay in the declared
-        gf256_clay buckets.  Returns (per-job outputs, per-job crcs or
-        None, the padded width)."""
+        gf256_clay buckets.  Returns (per-job outputs in the rows' own
+        layout, per-job crcs or None, the padded width)."""
         Z = int(codec.get_sub_chunk_count())
         kind = batch[0].kind
         rows = batch[0].planes.shape[0]
-        # enc/encp planes are [k, Z*s]; crep/cdec arrive pre-reshaped
-        # with sub-chunk rows ([d*L, s] / [A*Z, s]), widths already s
-        per_row = Z if kind in ("enc", "encp") else 1
-        svec = [w // per_row for w in widths]
+        # sub-chunks of a codeword a row holds: all Z, or ("crep") the
+        # repair layers of the lost shard alone
+        per = (len(codec.repair_layers(batch[0].sig[0]))
+               if kind == "crep" else Z)
+        geo: List[Tuple[int, int]] = []   # a job's (codewords, s)
+        for j, w in zip(batch, widths):
+            s = j.chunk // Z if j.chunk else w // per
+            if s <= 0 or w % (per * s):
+                raise ValueError(
+                    f"{kind} row of {w} bytes is no whole number of "
+                    f"codewords (chunk {j.chunk}, {per} sub-chunks)")
+            geo.append((w // (per * s), s))
+        svec = [S * s for S, s in geo]
+        offs = list(itertools.accumulate(svec, initial=0))[:-1]
         s_pad = shapebucket.covering(sum(svec), 1)
         with tracing.span("batch.stack"):
-            stacked = np.zeros((rows, per_row, s_pad), dtype=np.uint8)
-            off = 0
-            for j, s in zip(batch, svec):
-                stacked[:, :, off:off + s] = j.planes.reshape(
-                    rows, per_row, s)
-                off += s
-        offs: List[int] = []
-        o = 0
-        for s in svec:
-            offs.append(o)
-            o += s
-        outs: List[np.ndarray] = []
+            stacked = np.zeros((rows, per, s_pad), dtype=np.uint8)
+            for j, o, (S, s) in zip(batch, offs, geo):
+                stacked[:, :, o:o + S * s] = j.planes.reshape(
+                    rows, S, per, s).transpose(0, 2, 1, 3).reshape(
+                        rows, per, S * s)
+
+        def unstack(out: np.ndarray) -> List[np.ndarray]:
+            """[r, Z, s_pad] -> each job's [r, S*Z*s], its codewords
+            one after another again."""
+            r = out.shape[0]
+            return [np.ascontiguousarray(
+                out[:, :, o:o + S * s].reshape(r, Z, S, s).transpose(
+                    0, 2, 1, 3)).reshape(r, S * Z * s)
+                for o, (S, s) in zip(offs, geo)]
+
         crcs = None
         if kind == "crep":
             lost = batch[0].sig[0]
             helpers = list(batch[0].sig[1:])
-            layers = rows // len(helpers)
             with tracing.span("batch.encode"):
                 out = np.asarray(codec.repair_planes(
-                    lost, helpers,
-                    stacked.reshape(len(helpers), layers, s_pad)))
-            outs = [
-                np.ascontiguousarray(out[:, o:o + s]).reshape(-1)
-                for o, s in zip(offs, svec)]
+                    lost, helpers, stacked))
+            outs = [o.reshape(-1) for o in unstack(out[None])]
         elif kind == "cdec":
             avail = list(batch[0].sig)
             with tracing.span("batch.encode"):
                 data = np.asarray(codec.decode_planes(
-                    avail, stacked.reshape(len(avail), Z * s_pad)))
-            d3 = data.reshape(codec.k, Z, s_pad)
-            outs = [
-                np.ascontiguousarray(d3[:, :, o:o + s]).reshape(
-                    codec.k, -1)
-                for o, s in zip(offs, svec)]
+                    avail, stacked.reshape(rows, Z * s_pad)))
+            outs = unstack(data.reshape(codec.k, Z, s_pad))
         else:
             with tracing.span("batch.encode"):
                 coding = np.asarray(codec.encode_array(
-                    stacked.reshape(rows, per_row * s_pad)))
-            c3 = coding.reshape(codec.m, Z, s_pad)
-            outs = [
-                np.ascontiguousarray(c3[:, :, o:o + s]).reshape(
-                    codec.m, -1)
-                for o, s in zip(offs, svec)]
+                    stacked.reshape(rows, Z * s_pad)))
+            outs = unstack(coding.reshape(codec.m, Z, s_pad))
             if kind == "encp":
                 # fused per-shard crc32c over the ORIGINAL per-job
                 # chunk layout (crc is a byte stream over each chunk,
@@ -434,7 +449,7 @@ class StripeBatchQueue:
                     return full
 
                 crcs = self._fused_crc(full_planes, boffs, widths)
-        return outs, crcs, per_row * s_pad
+        return outs, crcs, per * s_pad
 
     @staticmethod
     def _fused_crc(full_planes, offs: List[int],

@@ -141,6 +141,26 @@ def test_clay_pair_matrix(chip):
         _compile_planes(chip, pair, T)
 
 
+@pytest.mark.parametrize("jobs", [1, 2, 4, 8])
+def test_clay_k8m4d11_encode_calls_at_the_cells_widths(chip, jobs):
+    """The three device calls of one clay k=8 m=4 d=11 encode batch of
+    `jobs` coalesced 1 MiB objects (2,048 B a sub-chunk and job, the
+    queue's array branch pads the jobs to a power of two): the data
+    nodes' pair transform over 8 x 48 coupled symbols, the [4, 8] MDS
+    matrix over all 64 layers, the parity column's pair transform over
+    4 x 48."""
+    from ceph_tpu.ec.clay import ClayCodec
+
+    codec = ClayCodec(8, 4, 11)
+    s = 2048 * jobs
+    for matrix, symbols in ((codec._uncouple_M, 8 * 48),
+                            (codec.coding, 64),
+                            (codec._couple_M, 4 * 48)):
+        n = symbols * s
+        assert n % 512 == 0   # what _engine sends to the Pallas kernel
+        _compile_planes(chip, matrix, n // 512)
+
+
 @pytest.mark.parametrize("n", [1536, 3072, 6144, 13824, 513 * 512])
 def test_tile_ladder_regression(chip, n):
     """n % 512 == 0 with T = n/512 lacking a divisor that is a

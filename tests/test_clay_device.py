@@ -156,11 +156,12 @@ def test_crep_jobs_coalesce_into_one_batch():
 CLAY_PROFILE = "plugin=clay k=8 m=4 d=11"
 
 
-def _clay_vec_responder(osd, chunks, Z, src_epoch=7, mute=()):
+def _clay_vec_responder(osd, chunks, Z, src_epoch=7, mute=(), unit=4096):
     """Answer MECSubReadVec honoring the v2 runs tail: a row with runs
-    gets ONLY those sub-chunk extents back (served=1), an empty-runs
-    row gets the whole chunk (served=0) — a peer in `mute` never
-    answers rows that carry runs (plan-failure injection)."""
+    gets ONLY those sub-chunk extents of every stripe back (served=1;
+    a stripe's `unit` bytes are a codeword's chunk of Z sub-chunks), an
+    empty-runs row gets the whole chunk (served=0) — a peer in `mute`
+    never answers rows that carry runs (plan-failure injection)."""
 
     def respond(osd_id, msg):
         if not isinstance(msg, m.MECSubReadVec):
@@ -176,9 +177,11 @@ def _clay_vec_responder(osd, chunks, Z, src_epoch=7, mute=()):
             attrs = {"hinfo": _hinfo(cs[shard], len(data)),
                      "_av": _av_stamp(v)}
             if rr:
-                sub = len(chunk) // Z
-                blob = b"".join(chunk[so * sub:(so + cnt) * sub]
-                                for so, cnt in rr)
+                sub = unit // Z
+                blob = b"".join(
+                    chunk[base + so * sub:base + (so + cnt) * sub]
+                    for base in range(0, len(chunk), unit)
+                    for so, cnt in rr)
                 rows.append((shard, oid, blob, 0, attrs, {}))
                 served.append(1)
             else:

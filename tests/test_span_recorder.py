@@ -331,6 +331,83 @@ def test_every_span_site_uses_a_registered_name():
     assert tracing.OP_RECORD not in tracing.SPANS
 
 
+@pytest.mark.parametrize("kind", ["encp", "crep", "cdec"])
+def test_clay_steps_are_registered_and_nest_under_batch_encode(kind):
+    """The array codec's steps (ec/clay.py) through the queue's array
+    branch: `clay.uncouple`, `clay.mds`, `clay.couple` are the children
+    of an encode batch's `batch.encode` in that order and their self
+    times are in the window the readers take; a repair's are one
+    `clay.repair` with its `clay.solve` below, a layered decode's the
+    `clay.solve` of each level; every step counts its bytes."""
+    from ceph_tpu.ec import clay
+    from ceph_tpu.tpu.queue import StripeBatchQueue
+
+    names = {"clay.uncouple", "clay.mds", "clay.couple", "clay.repair",
+             "clay.solve", "clay.dev_calls"}
+    assert names <= set(tracing.SPANS)
+    assert {tracing.SPANS[n] for n in (
+        "clay.uncouple", "clay.mds", "clay.couple")} == {"clay_host_ms"}
+    assert tracing.SPANS["clay.dev_calls"] == "clay_dev_calls_per_batch"
+    codec = clay.ClayCodec(4, 2)
+    Z, s = codec.get_sub_chunk_count(), 16
+    data = np.random.default_rng(3).integers(
+        0, 256, (4, Z * s), dtype=np.uint8)
+    full = np.concatenate([data, codec.encode_array(data)])
+    q = StripeBatchQueue(window_s=0.001)
+    try:
+        before, calls = q.batches, clay.dev_calls()
+        if kind == "encp":
+            coding, _crcs = q.encode_crc_async(codec, data).result(30.0)
+            np.testing.assert_array_equal(coding, full[4:])
+        elif kind == "crep":
+            layers = codec.repair_layers(1)
+            helpers = [0, 2, 3, 4, 5]
+            got = q.clay_repair(codec, 1, helpers, np.stack(
+                [full[h].reshape(Z, s)[layers] for h in helpers]))
+            np.testing.assert_array_equal(got, full[1])
+        else:
+            got = q.clay_decode_async(
+                codec, {i: full[i] for i in (1, 2, 4, 5)}).result(30.0)
+            np.testing.assert_array_equal(got, data)
+        w = _window_when_closed(before)
+        recs, _ = tracing.recorder().held()
+        batch = [r for r in recs if r[NAME] == "queue.batch"
+                 and r[COUNTS].get("q") == q._span_q][-1]
+        assert batch[COUNTS]["kind"] == kind
+        enc, = [r for r in recs if r[NAME] == "batch.encode"
+                and r[PARENT] == batch[ID]]
+        kids = [r for r in recs if r[PARENT] == enc[ID]]
+        below = {r[ID] for r in kids}
+        steps = [r for r in recs
+                 if r[NAME] in names and (r[ID] in below
+                                          or r[PARENT] in below)]
+        assert all(r[COUNTS]["bytes"] > 0 for r in steps)
+        if kind == "encp":
+            assert [r[NAME] for r in kids] == [
+                "clay.uncouple", "clay.mds", "clay.couple"]
+            assert [r[COUNTS].get("pairs") for r in kids] == [
+                int((~codec.dot[:4]).sum()), None,
+                int((~codec.dot[4:]).sum())]
+            assert kids[1][COUNTS]["layers"] == Z
+            assert clay.dev_calls() - calls == 3
+        elif kind == "crep":
+            assert [r[NAME] for r in kids] == ["clay.repair"]
+            assert [r[NAME] for r in steps] == ["clay.solve", "clay.repair"]
+            assert kids[0][COUNTS]["layers"] == len(layers)
+        else:
+            assert kids and {r[NAME] for r in kids} <= {
+                "clay.solve", "dev.dispatch", "dev.wait"}
+            assert "clay.solve" in {r[NAME] for r in kids}
+        # the window the benchmark's readers take holds the steps' self
+        # times beside the stages', and still adds up to the batch
+        assert {r[NAME] for r in steps} <= set(w.self_ns)
+        under = {n_: v for n_, v in w.self_ns.items()
+                 if not n_.startswith("queue.") or n_ == "queue.batch"}
+        assert sum(under.values()) == batch[T1] - batch[T0]
+    finally:
+        q.stop()
+
+
 # -- named scopes change no result ------------------------------------------
 
 @pytest.mark.parametrize("engine", ["pallas", "xla"])
