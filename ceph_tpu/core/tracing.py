@@ -134,16 +134,21 @@ SPANS: Dict[str, str] = {
     #   operands and enqueue; count: family
     "dev.wait": "dev_wait_ms",            # devwatch.fetch: blocked until the
     #   device is done, fetch included
-    # clay (ec/clay.py): the array codec's steps, children of
-    # `batch.encode` in the queue's array branch; each makes ONE call of
-    # the GF(2^8) engine, so its self time is the host's index gathers,
-    # stacks and scatters around that call
-    "clay.uncouple": "clay_host_ms",      # data nodes C -> U; counts pairs
-    #   (coupled symbols transformed, node x layer) and bytes (the pair
-    #   matmul's two input rows)
-    "clay.mds": "clay_host_ms",           # the scalar MDS code over every
-    #   layer at once; counts layers, bytes (the k input rows)
-    "clay.couple": "clay_host_ms",        # parity column U -> C; pairs, bytes
+    # clay (ec/clay.py): the array codec's encode, below `batch.encode` in
+    # the queue's array branch.  On a device engine the whole encode is
+    # ONE jitted call under `clay.mds` (its `dev.dispatch` and `dev.wait`
+    # below it), whose self time is the host work left around the call;
+    # on the native engine the three steps each make one call of the
+    # GF(2^8) engine, and their self time is the host's index gathers,
+    # stacks and scatters around it
+    "clay.uncouple": "clay_host_ms",      # native engine: data nodes C -> U;
+    #   counts pairs (coupled symbols transformed, node x layer) and bytes
+    #   (the pair matmul's two input rows)
+    "clay.mds": "clay_host_ms",           # the one program of a device
+    #   engine, or the native engine's scalar MDS code over every layer at
+    #   once; counts layers, bytes (the kk input rows)
+    "clay.couple": "clay_host_ms",        # native engine: parity column
+    #   U -> C; pairs, bytes
     "clay.repair": "",                    # repair_planes: one node from the
     #   repair layers of d helpers; counts layers, bytes (the helpers')
     "clay.solve": "",                     # _solve_unknowns: the erased nodes'
@@ -152,7 +157,8 @@ SPANS: Dict[str, str] = {
     # the codec's monotonic total (clay.dev_calls(): a counter, not a
     # span, registered here as the CRUSH totals below are)
     "clay.dev_calls": "clay_dev_calls_per_batch",   # calls of the GF
-    #   engine the codec made: a device call each on the chip
+    #   engine the codec made, a device call each on the chip: 1 an encode
+    #   there (3 on the native engine)
     # CRUSH sweep
     "crush.sweep": "",                    # sweep_device's call; counts ids,
     #   chunk, numrep, mode (firstn/indep), the plan it ran: cap, cap2

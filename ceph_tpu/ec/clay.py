@@ -26,12 +26,24 @@ Construction (k data + m coding, d = k+m-1 helpers):
   RS repair bytes (11/32 for k=8,m=4,d=11).
 
 TPU mapping: because parity nodes fill exactly the last grid column
-(k+nu = q*(t-1)), encode needs no layer ordering — uncoupling and
-re-coupling are wide [[a,b]] 1x2 GF(2^8) matmuls over (chunk, partner)
-row pairs, and the per-layer MDS step collapses into ONE coding-matrix
-matmul over all layers (ceph_tpu.ops.gf256_swar).  The general
-multi-erasure decode runs the intersection-score layer ordering
-host-side with a cached device matmul per IS level.
+(k+nu = q*(t-1)), encode needs no layer ordering.  On a device engine
+(`gf256_swar._engine`: "pallas" / "xla") an encode is ONE jitted program
+of the `gf256_clay` family (`_encode_program`): the data planes go up as
+packed u32 words [k, Z, W], the m stored parity planes come back, and
+uncoupling, the per-layer MDS code and re-coupling are steps inside it.
+The partner map is an axis swap there, not a gather: with the layer axis
+viewed as its t base-q digits and a column's nodes as x, the partner of
+(x, y) at layer z is column y's block with the axes x and z_y exchanged,
+and the dots are the diagonal x == z_y; the GF(2^8) arithmetic is the
+SWAR xor network (ceph_tpu.ops.gf256_swar) over the words, elementwise
+over W, which stays the minor axis throughout.  On the CPU backend's
+native engine (a 2 us ctypes call, where a jit dispatch costs more than
+the gathers) the same three steps run as wide [[a,b]] 1x2 GF(2^8)
+matmuls over (chunk, partner) row pairs gathered by numpy, and ONE
+coding-matrix matmul over all layers.  Both give the same bytes.
+Repair and the general multi-erasure decode keep the host composition:
+the intersection-score layer ordering runs host-side with a cached
+device matmul per IS level.
 
 A call codes one codeword: a chunk of n bytes is q^t sub-chunks of
 n/q^t.  Every step is elementwise over the bytes within a sub-chunk, so
@@ -45,8 +57,10 @@ along that axis, so the device programs see one wide codeword.
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Mapping, Sequence, Tuple
+import functools
+from typing import Callable, Dict, Iterable, List, Mapping, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from ceph_tpu.core import tracing
@@ -60,6 +74,7 @@ from ceph_tpu.ec.interface import (
     to_int,
 )
 from ceph_tpu.ops import gf256_swar
+from ceph_tpu.tpu.devwatch import fetch, instrumented_jit
 
 
 def _gf_pair(a: int, b: int) -> np.ndarray:
@@ -76,12 +91,60 @@ def dev_calls() -> int:
     return _dev_calls
 
 
-def _gf_call(M: np.ndarray, x: np.ndarray, **kw) -> np.ndarray:
-    """`gf_matmul_bytes` in the clay family, counted."""
+def _count_call() -> None:
     global _dev_calls
     with _dev_calls_lock:
         _dev_calls += 1
+
+
+def _gf_call(M: np.ndarray, x: np.ndarray, **kw) -> np.ndarray:
+    """`gf_matmul_bytes` in the clay family, counted."""
+    _count_call()
     return gf256_swar.gf_matmul_bytes(M, x, family="gf256_clay", **kw)
+
+
+@functools.lru_cache(maxsize=16)
+def _encode_program(k: int, q: int, t: int, coding: bytes,
+                    uncouple: bytes, couple: bytes) -> Callable:
+    """The whole encode of one profile as one jitted program:
+    f(words u32[k, Z, W]) -> u32[q, Z, W], the data planes in and the
+    stored parity planes out, four bytes a word (`coding` is the
+    [q, q*(t-1)] MDS matrix, `uncouple` and `couple` the [[a, b]] pair
+    rows, as bytes: a program is a function of its constants alone, and
+    every codec of a profile, one a PG, shares it).
+
+    A column's nodes are a block [x, z_0 .. z_{t-1}, W]; the partner of
+    x at layer z is the block with the axes x and z_y exchanged, the
+    dots its diagonal.  Every swap is on major axes and every GF step
+    elementwise, so W is the minor axis of every array in the program.
+    """
+    kk, Z = q * (t - 1), q ** t
+    nets = [gf256_swar._build_network(
+        np.frombuffer(b, dtype=np.uint8).reshape(shape))
+        for b, shape in ((uncouple, (1, 2)), (coding, (q, kk)),
+                         (couple, (1, 2)))]
+    grid = np.arange(q)
+
+    def pair(net, col, y: int):
+        """where dot: col, else a*col + b*partner, for the column at
+        grid position y given as [x, z_0 .. z_{t-1}, W]."""
+        dot = grid.reshape(-1, *[1] * (t + 1)) == grid.reshape(
+            *[1] * (1 + y), -1, *[1] * (t - y))
+        mixed = net(jnp.stack([col, jnp.swapaxes(col, 0, 1 + y)]))[0]
+        return jnp.where(dot, col, mixed)
+
+    def run(words):
+        W = words.shape[2]
+        if kk > k:   # the virtual nodes: rows of zeros
+            words = jnp.concatenate(
+                [words, jnp.zeros((kk - k, Z, W), words.dtype)])
+        cols = words.reshape(t - 1, q, *[q] * t, W)
+        U = jnp.stack([pair(nets[0], cols[y], y) for y in range(t - 1)])
+        U_par = nets[1](U.reshape(kk, Z, W))
+        return pair(nets[2], U_par.reshape(q, *[q] * t, W),
+                    t - 1).reshape(q, Z, W)
+
+    return instrumented_jit(run, family="gf256_clay")
 
 
 class ClayCodec(ErasureCode):
@@ -145,6 +208,10 @@ class ClayCodec(ErasureCode):
         # recover stored C from own U + KNOWN partner C:
         #   C1 = det*U1 + g*C2  (derived in the module docstring)
         self._c_from_U_M = _gf_pair(det, g)
+        # the constants of the encode's one device program
+        self._program_key = (k, self.q, self.t) + tuple(
+            np.asarray(M, dtype=np.uint8).tobytes()
+            for M in (self.coding, self._uncouple_M, self._couple_M))
         self._pair_tables()
         self._solve_cache: Dict[Tuple[Tuple[int, ...], Tuple[int, ...]],
                                 np.ndarray] = {}
@@ -227,15 +294,42 @@ class ClayCodec(ErasureCode):
 
     # -- encode ------------------------------------------------------------
     def encode_array(self, data: np.ndarray) -> np.ndarray:
-        data = np.asarray(data, dtype=np.uint8)
+        data = np.ascontiguousarray(data, dtype=np.uint8)
         k, n = data.shape
         if k != self._k or n % self.sub_count:
             raise ErasureCodeError(
                 f"clay encode: bad planes {data.shape} (k={self._k}, "
                 f"n must be a multiple of {self.sub_count})"
             )
-        s = n // self.sub_count
+        # by backend and width, as every GF call of the tree chooses
+        if gf256_swar._engine(n) == "native":
+            return self._encode_host(data)
+        return self._encode_device(data)
+
+    def _encode_device(self, data: np.ndarray) -> np.ndarray:
+        """One call of `_encode_program`: one upload (the data words),
+        one fetch (the parity planes); under `clay.mds`, whose self time
+        is the host work left around the call."""
+        Z, m = self.sub_count, self._m
+        k, n = data.shape
+        s = n // Z
+        with tracing.span("clay.mds", layers=Z, bytes=self.kk * n):
+            planes = data.reshape(k, Z, s)
+            if s % 4:   # no whole number of words: no served width
+                planes = np.pad(planes, ((0, 0), (0, 0), (0, -s % 4)))
+            _count_call()
+            # contiguous as gf_matmul_bytes makes its fetch: the chip
+            # hands back a strided array at the narrowest widths
+            par = np.ascontiguousarray(fetch(_encode_program(
+                *self._program_key)(planes.view(np.uint32)))).view(np.uint8)
+            return par[:, :, :s].reshape(m, n)
+
+    def _encode_host(self, data: np.ndarray) -> np.ndarray:
+        """The same three steps around three calls of the native engine,
+        the partners gathered by numpy."""
+        n = data.shape[1]
         Z = self.sub_count
+        s = n // Z
         dnodes = np.arange(self.kk)
         # coupled symbols (node x layer) of the data nodes and of the
         # parity column: what the two pair transforms count

@@ -374,9 +374,10 @@ class StripeBatchQueue:
         byte position within a sub-chunk is independent), while a raw
         byte concat (or a raw tail pad) would let the layer axis absorb
         a neighbour's bytes and corrupt every job in the batch.  The
-        per-layer width is covering-padded to a pow2 so the flattened
-        pair/solve matmul widths inside the codec stay in the declared
-        gf256_clay buckets.  Returns (per-job outputs in the rows' own
+        per-layer width is covering-padded to a pow2 so the encode's one
+        program (words u32[k, Z, W]) and the flattened pair/solve matmul
+        widths of repair and decode stay in the declared gf256_clay
+        buckets.  Returns (per-job outputs in the rows' own
         layout, per-job crcs or None, the padded width)."""
         Z = int(codec.get_sub_chunk_count())
         kind = batch[0].kind

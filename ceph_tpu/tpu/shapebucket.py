@@ -180,12 +180,15 @@ declare("gf256_pallas",
 declare("gf2_matmul",
         note="bit-matrix tiles: tile_n static, batch cols queue-padded")
 declare("gf256_clay",
-        note="coupled-layer pair/solve matmuls: rows are 1x2 pair "
-             "transforms or q x kk solve matrices (static geometry); "
-             "cols = (pairs or layers) * S with S the per-layer byte "
-             "width, covering-padded at sub-chunk granularity by the "
-             "StripeBatchQueue clay kinds — odd parts bounded by the "
-             "grid constants (<= q^t <= 63 for supported profiles)")
+        note="the encode's one program: words u32[k, Z, W] -> "
+             "u32[m, Z, W], k/m and Z = q^t static geometry of the "
+             "profile, W = the per-layer byte width / 4, covering-padded "
+             "to a pow2 by the StripeBatchQueue's array branch; and the "
+             "pair/solve matmuls of repair and decode: rows are 1x2 pair "
+             "transforms or q x kk solve matrices (static geometry), "
+             "cols = (pairs or layers) * S with S that per-layer byte "
+             "width — odd parts bounded by the grid constants "
+             "(<= q^t <= 63 for supported profiles)")
 declare("crc32c_device",
         note="(J, C) row batches: J pow2, C pow2 with 64 floor "
              "(crc32c_rows/_round_up_pow2)")

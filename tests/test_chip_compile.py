@@ -142,23 +142,53 @@ def test_clay_pair_matrix(chip):
 
 
 @pytest.mark.parametrize("jobs", [1, 2, 4, 8])
-def test_clay_k8m4d11_encode_calls_at_the_cells_widths(chip, jobs):
-    """The three device calls of one clay k=8 m=4 d=11 encode batch of
+def test_clay_k8m4d11_encode_program_at_the_cells_widths(chip, jobs):
+    """The ONE device program of a clay k=8 m=4 d=11 encode batch of
     `jobs` coalesced 1 MiB objects (2,048 B a sub-chunk and job, the
-    queue's array branch pads the jobs to a power of two): the data
-    nodes' pair transform over 8 x 48 coupled symbols, the [4, 8] MDS
-    matrix over all 64 layers, the parity column's pair transform over
-    4 x 48."""
+    queue's array branch pads the jobs to a power of two): data words
+    u32[8, 64, 512 * jobs] in, parity words u32[4, 64, 512 * jobs] out,
+    uncoupling, the [4, 8] MDS code and re-coupling inside.  u32 alone
+    crosses the program's boundary, and its op metadata names the
+    family and the scope."""
+    from ceph_tpu.ec import clay
+
+    codec = clay.ClayCodec(8, 4, 11)
+    W = 512 * jobs
+    compiled = clay._encode_program(*codec._program_key).jitted.lower(
+        _spec(chip, (8, 64, W), jnp.uint32)).compile()
+    text = compiled.as_text()
+    assert "gf256_clay/ec.encode" in text
+    entry = [ln for ln in text.splitlines() if ln.startswith("ENTRY")]
+    assert entry and "u8[" not in entry[0]
+    assert f"-> u32[4,64,{W}]" in entry[0]
+    # a batch's operands and temps are a few MiB of the chip's 16 GB
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("jobs", [1, 2, 4, 8])
+def test_clay_k8m4d11_pair_and_solve_calls_at_the_cells_widths(chip, jobs):
+    """What repair and the layered decode still call, at `jobs` coalesced
+    1 MiB objects: the 1x2 pair transform over the raveled (node x
+    layer) subsets they gather (a repair's 8 x 16 x 3/4 coupled symbols
+    of the other columns, a decode level's 8 x 48), and the solve of q
+    unknown nodes from the 8 known, the matrix an operand."""
     from ceph_tpu.ec.clay import ClayCodec
 
     codec = ClayCodec(8, 4, 11)
     s = 2048 * jobs
-    for matrix, symbols in ((codec._uncouple_M, 8 * 48),
-                            (codec.coding, 64),
-                            (codec._couple_M, 4 * 48)):
+    for matrix, symbols in ((codec._uncouple_M, 8 * 12),
+                            (codec._uncouple_M, 8 * 48),
+                            (codec._repair_M, 3 * 16)):
         n = symbols * s
         assert n % 512 == 0   # what _engine sends to the Pallas kernel
         _compile_planes(chip, matrix, n // 512)
+    tile, T_pad = gf256_swar.pallas_tile(16 * s // 512)
+    fn = gf256_pallas._compiled_operand(4, 8, tile, False, False)
+    compiled = fn.jitted.lower(
+        _spec(chip, (4 * 8 * 8,), jnp.uint32),
+        _spec(chip, (8, T_pad, LANES), jnp.uint32)).compile()
+    assert "%ec_decode" in compiled.as_text()
 
 
 @pytest.mark.parametrize("n", [1536, 3072, 6144, 13824, 513 * 512])

@@ -23,6 +23,7 @@ import reference_clay  # noqa: E402
 from ceph_tpu.core import tracing  # noqa: E402
 from ceph_tpu.ec import clay, codec_from_profile  # noqa: E402
 from ceph_tpu.ec.clay import ClayCodec  # noqa: E402
+from ceph_tpu.ops import gf256_swar  # noqa: E402
 from ceph_tpu.tpu.queue import StripeBatchQueue  # noqa: E402
 
 with open(os.path.join(ROOT, "benchmarks", "configs",
@@ -64,6 +65,142 @@ def test_encode_array_is_the_references_code(s):
     # the coupling is in it: the scalar MDS code alone gives other bytes
     assert not np.array_equal(got, reference.gf_matmul(
         reference_clay.Clay(CFG).G[K:], data))
+
+
+@pytest.fixture
+def device_engine(monkeypatch):
+    """The engine choice a chip makes, on the CPU backend: every GF call
+    takes the jitted XLA network, and an encode its one program."""
+    monkeypatch.setattr(gf256_swar, "_engine", lambda n: "xla")
+
+
+def _cfg(k: int, m: int) -> dict:
+    return {"k": k, "m": m, "d": k + m - 1, "gamma": 2}
+
+
+# (k, m): the cell's q = 4; q = 3 and q = 2; and two shortened codes whose
+# grid holds nu = 1 virtual zero node (q = 3, and q = 4 with t = 4: 256
+# layers), which the reference does not run: held to the host composition
+@pytest.mark.parametrize("k,m", [(8, 4), (6, 3), (4, 2), (5, 3), (11, 4)])
+@pytest.mark.parametrize("s", [1, 6, 64])
+def test_the_one_program_is_the_host_composition_and_the_reference(
+        k, m, s, device_engine):
+    """`s` bytes a sub-chunk: under a word (padded on the host), no whole
+    number of words, and the cell's 64."""
+    codec = ClayCodec(k, m)
+    Z = codec.get_sub_chunk_count()
+    assert (codec.q != 4, codec.nu) == {
+        (8, 4): (False, 0), (6, 3): (True, 0), (4, 2): (True, 0),
+        (5, 3): (True, 1), (11, 4): (False, 1)}[k, m]
+    data = np.random.default_rng([38, k, m, s]).integers(
+        0, 256, size=(k, Z * s), dtype=np.uint8)
+    before = clay.dev_calls()
+    got = codec.encode_array(data)
+    assert clay.dev_calls() - before == 1
+    assert got.shape == (m, Z * s) and got.dtype == np.uint8
+    before = clay.dev_calls()
+    assert np.array_equal(got, codec._encode_host(data))
+    assert clay.dev_calls() - before == 3
+    if not codec.nu:
+        assert np.array_equal(got, reference_clay.Clay(_cfg(k, m)).encode(
+            data.reshape(k, Z, s)).reshape(m, -1))
+
+
+@pytest.mark.parametrize("jobs", [1, 2, 4, 8])
+def test_the_one_program_codes_the_cells_batches(jobs, device_engine):
+    """`jobs` 1 MiB objects of the cell (32 stripes of 64 sub-chunks of
+    64 B a shard) coalesced by the queue's array branch, which lays
+    stripes and jobs side by side along the intra-sub-chunk axis: ONE
+    call of the engine a batch, every job's coding planes the
+    reference's of each stripe by itself, and the program's output at
+    that width the host composition's byte for byte."""
+    codec = ClayCodec(8, 4, 11)
+    ref = reference_clay.Clay(CFG)
+    S, s = 32, 64
+    planes = [_data(S * s, 100 + j) for j in range(jobs)]
+    q = StripeBatchQueue()
+    q._started = True     # hold the worker back until all are queued
+    futs = [q.encode_async(codec, p, chunk=Z * s) for p in planes]
+    q._started = False
+    before = clay.dev_calls()
+    q.start()
+    try:
+        got = [np.asarray(f.result(timeout=120)) for f in futs]
+    finally:
+        q.stop()
+    assert q.batch_jobs == {jobs: 1} and clay.dev_calls() - before == 1
+    for p, coding in zip(planes, got):
+        assert np.array_equal(coding, reference_clay.shards_of(
+            ref.encode(_stripes(p, S))))
+    # the batch as the codec saw it: [8, 64, 2048 * jobs], no padding
+    wide = np.concatenate([_stripes(p, S).reshape(K, Z, S * s)
+                           for p in planes], axis=2).reshape(K, -1)
+    assert wide.shape[1] == Z * 2048 * jobs
+    dev = codec.encode_array(wide)
+    assert np.array_equal(dev, codec._encode_host(wide))
+    assert np.array_equal(dev, _ref_parity(wide))
+
+
+@pytest.mark.parametrize("k,m", [(8, 4), (5, 3), (6, 3)])
+def test_what_the_one_program_stores_is_repaired_and_decoded(
+        k, m, device_engine):
+    """Encode on the device path, then every single lost shard through
+    `repair_planes` and m lost shards through `decode_array`: the data
+    and the stored parity come back."""
+    codec = ClayCodec(k, m)
+    Z, s, n = codec.get_sub_chunk_count(), 8, k + m
+    data = np.random.default_rng([38, k, m]).integers(
+        0, 256, size=(k, Z * s), dtype=np.uint8)
+    full = np.concatenate([data, codec.encode_array(data)])
+    for lost in range(n):
+        layers = codec.repair_layers(lost)
+        helpers = [i for i in range(n) if i != lost]
+        got = codec.repair_planes(lost, helpers, np.stack(
+            [full[h].reshape(Z, s)[layers] for h in helpers]))
+        assert np.array_equal(np.asarray(got).reshape(-1), full[lost]), lost
+    erased = list(range(1, 2 * m, 2))     # data and parity among them
+    out = codec.decode_array(
+        {i: full[i] for i in range(n) if i not in erased},
+        list(range(n)), Z * s)
+    for i in range(n):
+        assert np.array_equal(np.asarray(out[i]).reshape(-1), full[i]), i
+
+
+def test_a_strided_fetch_is_laid_out_before_it_is_viewed_as_bytes(
+        device_engine, monkeypatch):
+    """At the narrowest widths the chip hands the parity words back as
+    an array whose last axis is not contiguous (PR 38's first chip call:
+    the pool's warm-up of 4,096 columns, W = 16); a u8 view of that
+    raises.  The same shape of array here, from a stand-in fetch."""
+    def strided(out):
+        host = np.asarray(out)
+        wide = np.zeros(host.shape[:-1] + (2 * host.shape[-1],), host.dtype)
+        wide[..., ::2] = host
+        return wide[..., ::2]
+
+    monkeypatch.setattr(clay, "fetch", strided)
+    codec = ClayCodec(8, 4, 11)
+    data = _data(64, 7)
+    assert np.array_equal(codec.encode_array(data), _ref_parity(data))
+
+
+def test_codecs_of_one_profile_share_the_one_program(device_engine):
+    """Each PG has a codec of its own: the second one's encode at a
+    width the first has run traces and compiles nothing."""
+    from ceph_tpu.tpu import devwatch
+
+    a, b = ClayCodec(4, 2), ClayCodec(4, 2)
+    assert clay._encode_program(*a._program_key) is clay._encode_program(
+        *b._program_key)
+    assert clay._encode_program(*a._program_key) is not (
+        clay._encode_program(*ClayCodec(4, 2, gamma=3)._program_key))
+    data = np.random.default_rng(38).integers(
+        0, 256, size=(4, 8 * 16), dtype=np.uint8)
+    want = a.encode_array(data)
+    compiles = devwatch.watch().family_stats("gf256_clay")["compiles"]
+    assert np.array_equal(b.encode_array(data), want)
+    assert devwatch.watch().family_stats(
+        "gf256_clay")["compiles"] == compiles
 
 
 def _stripes(planes: np.ndarray, S: int) -> np.ndarray:

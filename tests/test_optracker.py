@@ -234,8 +234,18 @@ def test_slow_ring_and_dump_commands_on_minicluster(tmp_path):
             fp.disarm("backend.subwrite.fanout")
         pgid, _acting, primary = c.primary_of(EC_POOL, "slowme")
         # over the admin socket, per-daemon prefixed like `ceph daemon`
-        d = admin_command(sock, f"osd.{primary} dump_historic_slow_ops")
-        ops = [o for o in d["ops"] if "slowme" in o["description"]]
+        # the primary sends the reply, then concludes the op and files
+        # it into the ring (`daemon.py` `reply`: `conn.send` before
+        # `top.finish`); under load the client's dump came between the
+        # two (D15 ii), so ask until the op is there
+        deadline = time.monotonic() + 10.0
+        while True:
+            d = admin_command(
+                sock, f"osd.{primary} dump_historic_slow_ops")
+            ops = [o for o in d["ops"] if "slowme" in o["description"]]
+            if ops or time.monotonic() > deadline:
+                break
+            time.sleep(0.05)
         assert ops, d
         events = [e["event"] for e in ops[-1]["events"]]
         for stage in ("initiated", "queued_for_pg", "reached_pg",

@@ -331,16 +331,26 @@ def test_every_span_site_uses_a_registered_name():
     assert tracing.OP_RECORD not in tracing.SPANS
 
 
-@pytest.mark.parametrize("kind", ["encp", "crep", "cdec"])
-def test_clay_steps_are_registered_and_nest_under_batch_encode(kind):
+@pytest.mark.parametrize("kind,engine", [
+    ("encp", "native"), ("encp", "xla"), ("crep", "native"),
+    ("cdec", "native")])
+def test_clay_steps_are_registered_and_nest_under_batch_encode(
+        kind, engine, monkeypatch):
     """The array codec's steps (ec/clay.py) through the queue's array
-    branch: `clay.uncouple`, `clay.mds`, `clay.couple` are the children
-    of an encode batch's `batch.encode` in that order and their self
-    times are in the window the readers take; a repair's are one
-    `clay.repair` with its `clay.solve` below, a layered decode's the
-    `clay.solve` of each level; every step counts its bytes."""
+    branch.  On the native engine `clay.uncouple`, `clay.mds`,
+    `clay.couple` are the children of an encode batch's `batch.encode`
+    in that order, a call of the engine each; on a device engine (the
+    XLA network here, as on a chip) the batch is ONE call under
+    `clay.mds` alone, with its `dev.dispatch` and `dev.wait` below.
+    Their self times are in the window the readers take; a repair's are
+    one `clay.repair` with its `clay.solve` below, a layered decode's
+    the `clay.solve` of each level; every step counts its bytes."""
     from ceph_tpu.ec import clay
+    from ceph_tpu.ops import gf256_swar
     from ceph_tpu.tpu.queue import StripeBatchQueue
+
+    if engine != "native":
+        monkeypatch.setattr(gf256_swar, "_engine", lambda n: engine)
 
     names = {"clay.uncouple", "clay.mds", "clay.couple", "clay.repair",
              "clay.solve", "clay.dev_calls"}
@@ -382,7 +392,16 @@ def test_clay_steps_are_registered_and_nest_under_batch_encode(kind):
                  if r[NAME] in names and (r[ID] in below
                                           or r[PARENT] in below)]
         assert all(r[COUNTS]["bytes"] > 0 for r in steps)
-        if kind == "encp":
+        if kind == "encp" and engine != "native":
+            assert [r[NAME] for r in kids] == ["clay.mds"]
+            assert kids[0][COUNTS]["layers"] == Z
+            assert [r[NAME] for r in recs if r[PARENT] == kids[0][ID]] == [
+                "dev.dispatch", "dev.wait"]
+            assert clay.dev_calls() - calls == 1
+            # what `clay_host_ms` reads: the host work around the call
+            assert 0 < w.self_ns["clay.mds"] < kids[0][T1] - kids[0][T0]
+            assert not {"clay.uncouple", "clay.couple"} & set(w.self_ns)
+        elif kind == "encp":
             assert [r[NAME] for r in kids] == [
                 "clay.uncouple", "clay.mds", "clay.couple"]
             assert [r[COUNTS].get("pairs") for r in kids] == [
