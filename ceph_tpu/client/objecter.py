@@ -30,6 +30,7 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from ceph_tpu.core import tracing
 from ceph_tpu.core.context import Context
 from ceph_tpu.core.lockdep import make_lock
 from ceph_tpu.msg.messenger import Dispatcher, Messenger
@@ -44,12 +45,19 @@ ETIMEDOUT = -110
 
 
 class ObjecterOp:
-    """One tracked client op (reference Objecter::Op)."""
+    """One tracked client op (reference Objecter::Op).
+
+    Its timeline (`events`: (ns on the recorder's clock, stage)) holds
+    `created`, a `sent` for every send, `reply_recv` when the reply that
+    concludes it is handled and `returned` when the caller's thread has
+    it (`result()` returns); that first return files the timeline into
+    the span ring as one CLIENT_RECORD, which `tracing.joined` lays
+    beside the primary's op record of the same reqid."""
 
     __slots__ = ("tid", "pool", "oid", "ops", "reqid", "reply", "event",
                  "attempts", "last_send", "retry_at", "target",
                  "on_complete", "timeout_at", "snap_seq", "snaps",
-                 "snapid", "pgid_override", "span")
+                 "snapid", "pgid_override", "span", "events")
 
     def __init__(self, tid: int, pool: int, oid: str, ops: List[OSDOp],
                  reqid: str, timeout: float,
@@ -59,6 +67,8 @@ class ObjecterOp:
         self.oid = oid
         self.ops = ops
         self.reqid = reqid
+        created = tracing.clock()
+        self.events: List[Tuple[int, str]] = [(created, "created")]
         self.reply: Optional[m.MOSDOpReply] = None
         self.event = threading.Event()
         self.attempts = 0
@@ -66,7 +76,7 @@ class ObjecterOp:
         self.retry_at = 0.0  # backoff gate; 0 = send immediately
         self.target: Tuple[Tuple[int, int], int] = ((0, 0), -1)
         self.on_complete = on_complete
-        self.timeout_at = time.monotonic() + timeout
+        self.timeout_at = created / 1e9 + timeout  # time.monotonic()'s
         self.snap_seq = 0
         self.snaps: List[int] = []
         self.snapid = 0
@@ -81,6 +91,9 @@ class ObjecterOp:
         if not self.event.wait(timeout):
             raise TimeoutError(f"op tid={self.tid} oid={self.oid!r}")
         assert self.reply is not None
+        if self.events[-1][1] != "returned":
+            self.events.append((tracing.clock(), "returned"))
+            tracing.recorder().client(self.reqid, tuple(self.events))
         return self.reply
 
 
@@ -193,9 +206,8 @@ class Objecter(Dispatcher):
                 # the root of the cross-daemon tree: the context rides
                 # the MOSDOp wire tail, so the primary's do_op span —
                 # and every peer child under it — parents back here
-                op.span = tr.start_span("client.op")
-                op.span.annotate(f"sent pool={pool} oid={oid} "
-                                 f"reqid={op.reqid}")
+                op.span = tr.start_span("client.op",
+                                        at=op.events[0][0])
             self.ops[tid] = op
         self._send_op(op)
         return op
@@ -218,13 +230,17 @@ class Objecter(Dispatcher):
                 return
             epoch = self.osdmap.epoch
             op.attempts += 1
-            op.last_send = time.monotonic()
+            sent = tracing.clock()
+            op.events.append((sent, "sent"))
+            op.last_send = sent / 1e9   # time.monotonic()'s
         msg = m.MOSDOp(pgid, epoch, op.oid, op.ops)
         msg.tid = op.tid
         msg.reqid = op.reqid
         msg.snap_seq, msg.snaps, msg.snapid = (op.snap_seq, op.snaps,
                                                op.snapid)
         if op.span is not None:
+            op.span.annotate(f"sent pool={op.pool} oid={op.oid} "
+                             f"reqid={op.reqid}", at=sent)
             msg.set_trace(op.span.context())  # wire-propagated context
         self.msgr.send_message(msg, addr)
 
@@ -314,9 +330,11 @@ class Objecter(Dispatcher):
                     op.attempts, 10)
                 return True
             del self.ops[op.tid]
+        recv = tracing.clock()
+        op.events.append((recv, "reply_recv"))
         if op.span is not None:
-            op.span.annotate(f"reply result={msg.result}")
-            op.span.finish()
+            op.span.annotate(f"reply result={msg.result}", at=recv)
+            op.span.finish(at=recv)
         op.reply = msg
         op.event.set()
         if op.on_complete is not None:

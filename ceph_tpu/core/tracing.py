@@ -5,11 +5,14 @@ always on, no option) keeps one bounded in-memory ring of records on
 `time.monotonic_ns()`, the clock `OpTracker` and the stripe batch queue
 already use:
 
-- `with tracing.span(name, **counts):` records name, start, end,
-  thread, the span open on this thread when it started (its parent),
-  the ids of what caused it where that is not the parent (`causes=`: a
-  batch lists its jobs' tracked-op ids), and integer counts or short
-  tags.  The same `with` opens a `jax.profiler.TraceAnnotation(name)`,
+- `with tracing.span(name, **counts):` records name, start, end, the
+  thread's CPU time inside it (`time.thread_time_ns()`: wall minus CPU
+  is the time the thread was off the CPU, waiting for the interpreter
+  lock or blocked), thread, the span open on this thread when it
+  started (its parent), the ids of what caused it where that is not the
+  parent (`causes=`: a batch lists its jobs' tracked-op ids), and
+  integer counts or short tags.  The same `with` opens a
+  `jax.profiler.TraceAnnotation(name)`,
   inert unless a profiler trace is being taken, so in a traced run the
   span also lies on the xplane's host plane beside the device ops.  The
   xplane counts from its session's start at the rate of this clock:
@@ -17,7 +20,10 @@ already use:
   between the two.  Names come from `SPANS` below.
 - `OpTracker.unregister` files every concluded op's timeline as one
   `OP_RECORD`, so the stage times the `lat_*_us` histograms sum can be
-  taken over a window.
+  taken over a window; the objecter files each client op's as one
+  `CLIENT_RECORD` when its caller has the reply, and `joined` lays the
+  two of one `reqid` on one line from the client's creation of the op
+  to its return.
 - The ring counts what it overwrote; `batch_window` (what the
   benchmark's readers call) gives nothing for a range that reaches
   back to where records were lost.
@@ -62,9 +68,21 @@ TraceContext = Tuple[int, int]  # (trace_id, span_id)
 #   initiated -> queued_for_pg -> qos_admitted -> reached_pg ->
 #   [staged] -> admitted -> submitted -> commit -> [ack_gated]
 #   -> commit_sent
+# A served write end to end (`joined`; the per-layer metric that reads
+# each stretch in brackets, op_* ms of `op_joined_mean`):
+#   created -[op_send]-> initiated -[op_pre_encode]-> admitted
+#   -[op_exec]-> encode_queued -[queue_wait + the cycle]-> encoded
+#   -[op_fanout_wait]-> fanout_begun -[op_fanout]-> submitted
+#   -[op_commit_wait]-> commit -[op_reply]-> commit_sent
+#   -[op_reply_back]-> returned
 STAGES: Dict[str, str] = {
-    # client / generic
-    "sent": "",                # client: op handed to the messenger
+    # client (objecter): CLIENT_RECORD's marks
+    "created": "",             # the ObjecterOp is built (op_send_ms's
+    #   start)
+    "sent": "",                # op handed to the messenger, every send
+    "reply_recv": "",          # the concluding MOSDOpReply handled
+    "returned": "",            # the caller's thread has the reply
+    #   (`result()` returns; op_reply_back_ms's end)
     "initiated": "",           # tracker entry created (messenger receive)
     # daemon dispatch
     "queued_for_pg": "lat_recv_us",      # decode -> sharded-queue entry
@@ -77,6 +95,12 @@ STAGES: Dict[str, str] = {
     # write pipeline
     "staged": "lat_staging_us",          # pinned staging-pool acquire
     "admitted": "lat_admission_us",      # _OidPipe admission FIFO grant
+    # three annotations between admitted and submitted (mark_event(...,
+    # annotation=True): lat_encode_fanout_us keeps its meaning)
+    "encode_queued": "",       # encode_async returned (op_exec_ms's end)
+    "encoded": "",             # the batch's result handed to this op
+    "fanout_begun": "",        # the per-PG sequencer runs the fan-out
+    #   (op_fanout_wait_ms's end, op_fanout_ms's start)
     "submitted": "lat_encode_fanout_us",  # exec+encode queued+fan-out sent
     "commit": "lat_commit_wait_us",      # last shard ack arrived
     "ack_gated": "lat_ack_gate_us",      # durable-ack gate released
@@ -115,7 +139,10 @@ STAGES: Dict[str, str] = {
 # STAGES, the name is the contract between the site and its readers;
 # cephlint's `span-discipline` check holds literal call-site names to
 # this table.  A metric is the SELF time of its spans (duration minus
-# what direct children cover), summed over a window's batches.
+# what direct children cover), summed over a window's batches.  Besides,
+# `worker_offcpu_ms` reads the self time OFF the CPU (self wall minus
+# self CPU) of every span below but `queue.idle`, `queue.coalesce` and
+# `dev.wait`, whose waits are by design.
 SPANS: Dict[str, str] = {
     # stripe batch queue: the worker thread's whole cycle
     "queue.idle": "worker_idle_ms",       # blocked in get(), nothing queued
@@ -184,21 +211,28 @@ SPANS: Dict[str, str] = {
 }
 
 # a concluded op's timeline, filed by OpTracker.unregister: read by
-# op_pre_encode_ms, op_commit_wait_ms and op_reply_ms
+# op_pre_encode_ms, op_commit_wait_ms and op_reply_ms, and joined
 OP_RECORD = "op"
+# a client op's timeline, filed by the objecter when its caller has the
+# reply: joined to its primary's OP_RECORD by reqid, read by the op_*
+# metrics of `op_joined_mean`
+CLIENT_RECORD = "client_op"
 
 # -- the recorder --------------------------------------------------------------
 
 clock = time.monotonic_ns
+cpu_clock = time.thread_time_ns
 
 # a record: one tuple, indexed by these (SEQ: its place in the order
-# in which records were filed, i.e. closed)
-ID, NAME, T0, T1, THREAD, PARENT, CAUSES, COUNTS, SEQ = range(9)
+# in which records were filed, i.e. closed; CPU: the thread's CPU
+# nanoseconds between T0 and T1, None where not read)
+ID, NAME, T0, T1, THREAD, PARENT, CAUSES, COUNTS, SEQ, CPU = range(10)
 
 # Ring size.  Today's write cell makes 11 batches a second of 12 spans
 # (idle, coalesce, batch, five stages, two dispatches, two waits) and
-# 13 op records: 7,400 records in a 51 s window, and 640 op records in
-# the 13 s check after it.  Four times that is 32,200.
+# 13 op and 13 client records: 8,700 records in a 51 s window, and
+# 1,280 op and client records in the 13 s check after it.  Six times
+# that is 60,000.
 RING = 1 << 16
 
 _annotation = None
@@ -230,7 +264,7 @@ class _OpenSpan:
     the module's docstring.  `name` is a literal from SPANS; `counts`
     may be added to until the block ends (`sp.counts`)."""
     __slots__ = ("rec", "name", "counts", "causes", "id", "t0", "t1",
-                 "_ann", "_th")
+                 "_cpu0", "_ann", "_th")
 
     def __init__(self, name: str, causes: Tuple = (), **counts) -> None:
         self.rec = _recorder
@@ -256,10 +290,14 @@ class _OpenSpan:
             ann.__enter__()
         else:
             self._ann = None
+        # the CPU reads lie inside the wall ones: a span that never
+        # leaves the CPU reads CPU <= wall
         self.t0 = clock()
+        self._cpu0 = cpu_clock()
         return self
 
     def __exit__(self, *exc) -> None:
+        cpu = cpu_clock() - self._cpu0
         self.t1 = t1 = clock()
         if self._ann is not None:
             self._ann.__exit__(*exc)
@@ -270,7 +308,8 @@ class _OpenSpan:
         seq = next(rec._seqs)   # Recorder._file, in line: the hot path
         rec._ring[seq % rec.capacity] = (
             self.id, self.name, self.t0, t1, th.tid,
-            opened[-1] if opened else 0, self.causes, self.counts, seq)
+            opened[-1] if opened else 0, self.causes, self.counts, seq,
+            cpu)
 
     @property
     def seconds(self) -> float:
@@ -318,11 +357,20 @@ class Recorder:
                    {"desc": op.desc, "reqid": op.reqid,
                     "events": tuple(op.events)})
 
+    def client(self, reqid: str, events: Tuple[Tuple[int, str], ...]) -> None:
+        """A client op's timeline as one CLIENT_RECORD, from its first
+        event (`created`) to its last; its events are (ns on this
+        clock, stage)."""
+        self._file(self.next_id(), CLIENT_RECORD, events[0][0],
+                   events[-1][0], threading.get_ident(), 0, (),
+                   {"reqid": reqid, "events": events})
+
     def _file(self, id_: int, name: str, t0: int, t1: int, thread: int,
-              parent: int, causes: Tuple, counts: Dict[str, Any]) -> None:
+              parent: int, causes: Tuple, counts: Dict[str, Any],
+              cpu: Optional[int] = None) -> None:
         seq = next(self._seqs)
         self._ring[seq % self.capacity] = (
-            id_, name, t0, t1, thread, parent, causes, counts, seq)
+            id_, name, t0, t1, thread, parent, causes, counts, seq, cpu)
 
     def held(self) -> Tuple[List[Tuple], int]:
         """(the records the ring holds, in the order they were filed;
@@ -361,8 +409,11 @@ class Recorder:
             "held": len(recs),
             "overwritten": recs[0][SEQ] if recs else 0,
             "lost_until_ns": lost_until,
-            "spans": [row(r) for r in tail if r[NAME] != OP_RECORD],
+            "spans": [row(r) for r in tail
+                      if r[NAME] not in (OP_RECORD, CLIENT_RECORD)],
             "ops": [row(r) for r in tail if r[NAME] == OP_RECORD],
+            "client_ops": [row(r) for r in tail
+                           if r[NAME] == CLIENT_RECORD],
         }
 
 
@@ -393,12 +444,60 @@ def self_ns(records: Sequence[Tuple]) -> Dict[int, int]:
     return out
 
 
+def self_cpu_ns(records: Sequence[Tuple]) -> Dict[int, int]:
+    """id -> a span's CPU time minus its direct children's, which ran
+    on its thread inside it: `self_ns` on the CPU clock, for the
+    records that carry one."""
+    kids: Dict[int, int] = {}
+    for r in records:
+        if r[PARENT] and r[CPU] is not None:
+            kids[r[PARENT]] = kids.get(r[PARENT], 0) + r[CPU]
+    return {r[ID]: r[CPU] - kids.get(r[ID], 0)
+            for r in records if r[CPU] is not None}
+
+
+def joined(clients: Sequence[Tuple],
+           recs: Sequence[Tuple]) -> List[List[Tuple[int, str]]]:
+    """Each CLIENT_RECORD of `clients` laid on one line, in time order,
+    with its primary's OP_RECORD of the same reqid among `recs` (in
+    filing order, i.e. of conclusion): the first one that concluded
+    with `commit_sent`, whose reply the client took, else the last one.
+    A resend makes several: after an EAGAIN the attempt that committed
+    is the later one; a resend of an op that was slow to answer (the
+    objecter resends after `resend_interval`) is answered from the log
+    after the original committed, and did none of its work.  Both
+    records are on this clock, which is system-wide, so the line holds
+    across processes of one host.  A client record with no op record
+    is left out."""
+    pick: Dict[str, Tuple] = {}
+    for r in recs:
+        if r[NAME] != OP_RECORD or not r[COUNTS].get("reqid"):
+            continue
+        prev = pick.get(r[COUNTS]["reqid"])
+        if prev is None or prev[COUNTS]["events"][-1][1] != "commit_sent":
+            pick[r[COUNTS]["reqid"]] = r
+    out = []
+    for c in clients:
+        p = pick.get(c[COUNTS]["reqid"])
+        if p is not None:
+            line = list(c[COUNTS]["events"]) + [
+                (p[T0] + round(s * 1e9), stage)
+                for s, stage, _detail in p[COUNTS]["events"]]
+            line.sort(key=lambda e: e[0])
+            out.append(line)
+    return out
+
+
 class BatchWindow(NamedTuple):
     """What the ring holds of the batches `seq_lo < seq <= seq_hi`."""
     batches: int               # `queue.batch` spans in the range
     self_ns: Dict[str, int]    # self time by span name, over those
     #   spans, the idle and coalesce before each, and all below them
     ops: List[Tuple]           # op records concluded while they ran
+    self_cpu_ns: Dict[str, int]   # self CPU time by span name, as
+    #   self_ns, over the spans that carry a CPU time
+    joined: List[List[Tuple[int, str]]]   # `joined` lines of the client
+    #   records concluded while they ran
 
 
 def batch_window(seq_lo: int, seq_hi: int,
@@ -427,12 +526,20 @@ def batch_window(seq_lo: int, seq_hi: int,
     for r in reversed(recs):
         if r[PARENT] in tree:
             tree[r[ID]] = r
-    own = self_ns(list(tree.values()))
+    spans = list(tree.values())
+    own, own_cpu = self_ns(spans), self_cpu_ns(spans)
     by_name: Dict[str, int] = {}
-    for r in tree.values():
+    cpu_by_name: Dict[str, int] = {}
+    for r in spans:
         by_name[r[NAME]] = by_name.get(r[NAME], 0) + own[r[ID]]
+        if r[ID] in own_cpu:
+            cpu_by_name[r[NAME]] = (cpu_by_name.get(r[NAME], 0)
+                                    + own_cpu[r[ID]])
     ops = [r for r in recs if r[NAME] == OP_RECORD and t0 <= r[T1] <= t1]
-    return BatchWindow(len(roots), by_name, ops)
+    clients = [r for r in recs
+               if r[NAME] == CLIENT_RECORD and t0 <= r[T1] <= t1]
+    return BatchWindow(len(roots), by_name, ops, cpu_by_name,
+                       joined(clients, recs))
 
 
 # -- blkin spans (behind the `tracing` option) -----------------------------------
@@ -442,26 +549,28 @@ class Span:
                  "start", "end", "annotations")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: int,
-                 span_id: int, parent_id: int) -> None:
+                 span_id: int, parent_id: int, at: int = 0) -> None:
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
         self.span_id = span_id
         self.parent_id = parent_id
-        self.start = clock()
+        self.start = at or clock()
         self.end = 0
         self.annotations: List[Tuple[int, str]] = []
 
-    def annotate(self, what: str) -> None:
-        self.annotations.append((clock(), what))
+    # `at`: an instant on this clock that the caller has already read
+    # (the objecter's client timeline), so the span reads none itself
+    def annotate(self, what: str, at: int = 0) -> None:
+        self.annotations.append((at or clock(), what))
 
     def context(self) -> TraceContext:
         """The wire-propagatable identity of this span."""
         return (self.trace_id, self.span_id)
 
-    def finish(self) -> None:
+    def finish(self, at: int = 0) -> None:
         if not self.end:
-            self.end = clock()
+            self.end = at or clock()
             self.tracer._archive(self)
 
     def __enter__(self) -> "Span":
@@ -499,12 +608,14 @@ class Tracer:
         self._key = _recorder.next_id()   # marks this tracer's records
 
     def start_span(self, name: str,
-                   parent: Optional[TraceContext] = None) -> Span:
+                   parent: Optional[TraceContext] = None,
+                   at: int = 0) -> Span:
         if parent is not None:
             trace_id, parent_id = parent
         else:
             trace_id, parent_id = _recorder.wire_id(), 0
-        return Span(self, name, trace_id, _recorder.wire_id(), parent_id)
+        return Span(self, name, trace_id, _recorder.wire_id(), parent_id,
+                    at)
 
     def _archive(self, span: Span) -> None:
         if self.enabled:

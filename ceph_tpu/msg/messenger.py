@@ -280,9 +280,6 @@ class Messenger:
                                "dedicated MAck frames sent")
             pc.add_u64_counter("acks_piggybacked",
                                "dispatch acks that rode outgoing data")
-            pc.add_u64_counter("loop_stalls",
-                               "fast-dispatch handlers that blocked the "
-                               "event loop past ms_loop_stall_ms")
             pc.add_u64_counter("throttle_stall",
                                "dispatch-gate waits: a peer connection "
                                "stopped reading because its in-flight "
@@ -996,8 +993,6 @@ class Messenger:
         self._log(0, f"LOOP STALL: fast dispatch of {type(msg).__name__} "
                      f"held the event loop {elapsed * 1e3:.1f}ms "
                      f"(threshold {self._stall_s * 1e3:.0f}ms)")
-        if self.perf is not None:
-            self.perf.inc("loop_stalls")
 
     def _dispatch_sync(self, conn: Connection, msg: Message) -> bool:
         for d in self._dispatchers:

@@ -76,6 +76,24 @@ _fanout_exec = None
 _fanout_exec_lock = make_lock("backend.fanout_exec_init")
 
 
+def _op_stage(msg, stage: str, detail: str = "",
+              annotation: bool = False) -> None:
+    """Mark one pipeline stage on a client op's timeline (the TrackedOp
+    riding `msg` feeds the stage's osd.N.op latency histogram unless
+    `annotation`) and, when the op is traced, annotate its span.  Stage
+    names are literals from tracing.STAGES (cephlint span-discipline
+    enforces it); `PG._op_stage` is this function."""
+    trop = getattr(msg, "trop", None)
+    if trop is not None:
+        # cephlint: disable=span-discipline — the forwarding
+        # helper itself; callers pass registry literals and the
+        # check validates THEM (the _op_stage arg rule)
+        trop.mark_event(stage, detail, annotation=annotation)
+    span = getattr(msg, "span", None)
+    if span is not None:
+        span.annotate(f"{stage} {detail}" if detail else stage)
+
+
 def _fanout_executor():
     global _fanout_exec
     with _fanout_exec_lock:
@@ -255,7 +273,7 @@ class PGBackend:
 
     def _encode_then_fanout(self, planes, fanout, on_error,
                             fused: bool = False, size: int = 0,
-                            trop=None) -> None:
+                            msg=None) -> None:
         """Shared async-encode scaffold: queue the planes, then run
         `fanout(coding)` through the per-PG sequencer on the fan-out
         executor — NOT on the StripeBatchQueue's device worker, which
@@ -267,13 +285,17 @@ class PGBackend:
         its bookkeeping (in-flight op, gauge, projected state).
         `fused=True` rides encode_crc_async (device-resident path):
         fanout receives `(coding, crcs)` — per-shard crc32c computed
-        in the same device batch as the matmul."""
+        in the same device batch as the matmul.  `msg`, the client op
+        this write serves, gets three annotations on its timeline:
+        `encode_queued`, `encoded` (the batch's result handed to it, on
+        the queue's worker) and `fanout_begun` (on the fan-out lane)."""
         ticket = self._fan_ticket()
         if self.perf is not None:
             self.perf.inc("encode_batch_jobs")
+        # trop rides the job so the queue can blame a live XLA compile
+        # for this op's wait (compile_wait annotation)
+        trop = getattr(msg, "trop", None)
         try:
-            # trop rides the job so the queue can blame a live XLA
-            # compile for this op's wait (compile_wait annotation)
             fut = (self.queue.encode_crc_async(self.codec, planes,
                                                size=size, trop=trop,
                                                chunk=self.unit)
@@ -283,8 +305,10 @@ class PGBackend:
         except BaseException:
             self._fan_run(ticket, lambda: None)  # never park the line
             raise
+        _op_stage(msg, "encode_queued", annotation=True)
 
         def finish(f) -> None:
+            _op_stage(msg, "fanout_begun", annotation=True)
             try:
                 coding = f.result()
             except Exception as e:  # noqa: BLE001 — device/codec error
@@ -293,8 +317,12 @@ class PGBackend:
                 return
             fanout(coding)
 
-        fut.add_done_callback(lambda f: _fanout_executor().submit(
-            lambda: self._fan_run(ticket, lambda: finish(f))))
+        def encoded(f) -> None:
+            _op_stage(msg, "encoded", annotation=True)
+            _fanout_executor().submit(
+                lambda: self._fan_run(ticket, lambda: finish(f)))
+
+        fut.add_done_callback(encoded)
 
     def _fan_run(self, ticket: int, fn: Callable[[], None]) -> None:
         """Run `fn` once every earlier ticket's fn has run; an earlier
@@ -384,7 +412,7 @@ class ReplicatedBackend(PGBackend):
 
     def submit(self, oid, state, entries, log_omap, acting, on_commit,
                log_rm=None, pre_txn=None, on_submitted=None,
-               trace=None, trop=None):
+               trace=None, msg=None):
         txn = self._object_txn(oid, state, log_omap, log_rm)
         if pre_txn is not None:
             # snapshot clone-on-write rides the SAME transaction: the
@@ -405,10 +433,10 @@ class ReplicatedBackend(PGBackend):
                     and fp.failpoint("backend.subwrite.fanout",
                                      peer=peer, oid=oid) is fp.DROP):
                 continue  # modeled kill-boundary loss: never sent
-            msg = m.MOSDRepOp(self.pgid, self.epoch_fn(), body, entries)
-            msg.tid = tid
+            sub = m.MOSDRepOp(self.pgid, self.epoch_fn(), body, entries)
+            sub.tid = tid
             op.sent_at[peer] = time.monotonic()  # fan-out RTT stamp
-            self.osd_send(peer, msg)
+            self.osd_send(peer, sub)
         # local apply last: the store raises on real corruption, and
         # the self-ack fires from the store's COMMIT callback (not
         # inline) so the local fsync batches with every other write in
@@ -823,7 +851,7 @@ class ECBackend(PGBackend):
 
     def submit(self, oid, state, entries, log_omap, acting, on_commit,
                log_rm=None, on_submitted=None, on_error=None,
-               trace=None, trop=None):
+               trace=None, msg=None):
         # full-object rewrite/delete supersedes any cached stripes
         self.cache.invalidate(oid)
         n = self.k + self.m
@@ -875,18 +903,18 @@ class ECBackend(PGBackend):
                                     "backend.subwrite.fanout",
                                     peer=osd, oid=oid) is fp.DROP):
                             continue  # modeled loss: never sent
-                        msg = m.MECSubWriteVec(
+                        sub = m.MECSubWriteVec(
                             self.pgid, epoch, oid,
                             txn.to_bytes(), entries,
                             rb=[(shard, rb_kind, 0, 0)
                                 for shard in shards],
                             committed_to=committed_to)
-                        msg.tid = tid
+                        sub.tid = tid
                         # the client op's span context rides the wire;
                         # the peer opens its store-commit child off it
-                        msg.set_trace(trace)
+                        sub.set_trace(trace)
                         op.sent_at[osd] = time.monotonic()
-                        self.osd_send(osd, msg)
+                        self.osd_send(osd, sub)
                         msgs += 1
                 self._note_fanout(msgs)
             finally:
@@ -917,14 +945,14 @@ class ECBackend(PGBackend):
                                    crcs=res[1]),
                 self._encode_error_fn(tid, on_submitted, on_error,
                                       state),
-                fused=True, size=len(state.data), trop=trop)
+                fused=True, size=len(state.data), msg=msg)
             return
         self._encode_then_fanout(
             planes,
             lambda coding: fanout(
                 self._chunks_of(planes, coding, self.k, self.m)),
             self._encode_error_fn(tid, on_submitted, on_error),
-            trop=trop)
+            msg=msg)
 
     def _chunks_dev(self, planes: np.ndarray, coding) -> List[DeviceBuf]:
         """k+m chunk payload HANDLES for the fan-out: data chunks view
@@ -1398,7 +1426,7 @@ class ECBackend(PGBackend):
                        log_rm: Optional[List[str]] = None,
                        on_submitted: Optional[Callable[[], None]] = None,
                        on_error: Optional[Callable[[], None]] = None,
-                       trop=None) -> None:
+                       msg=None) -> None:
         """Write merged stripes [s0, s0+len) as per-shard EXTENTS — only
         the touched stripes move (reference three-stage RMW,
         ECBackend.cc:1791 start_rmw / :1892 try_reads_to_commit).
@@ -1480,14 +1508,14 @@ class ECBackend(PGBackend):
                                     "backend.subwrite.fanout",
                                     peer=osd, oid=oid) is fp.DROP):
                             continue  # modeled loss: never sent
-                        msg = m.MECSubWriteVec(
+                        sub = m.MECSubWriteVec(
                             self.pgid, epoch, oid,
                             txn.to_bytes(), entries,
                             rb=[(shard, RB_EXTENT, ext_off, ext_len)
                                 for shard in shards],
                             committed_to=committed_to)
-                        msg.tid = tid
-                        self.osd_send(osd, msg)
+                        sub.tid = tid
+                        self.osd_send(osd, sub)
                         msgs += 1
                 self._note_fanout(msgs)
             finally:
@@ -1505,4 +1533,4 @@ class ECBackend(PGBackend):
 
         self._encode_then_fanout(
             planes, lambda coding: fanout(np.asarray(coding)),
-            unwind_with_cache, trop=trop)
+            unwind_with_cache, msg=msg)

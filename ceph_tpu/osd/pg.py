@@ -44,6 +44,7 @@ from ceph_tpu.osd.backend import (
     ObjectState,
     PGBackend,
     ReplicatedBackend,
+    _op_stage,
     pg_meta_txn,
 )
 from ceph_tpu.osd.pglog import PGLog
@@ -455,21 +456,8 @@ class PG:
                     self._note_gates.pop(tid, None)
 
     # -- op execution (primary) -------------------------------------------
-    @staticmethod
-    def _op_stage(msg, stage: str, detail: str = "") -> None:
-        """Mark one pipeline stage on the op's timeline (TrackedOp —
-        feeds the stage's osd.N.op latency histogram) and, when the op
-        is traced, annotate its span.  Stage names are literals from
-        tracing.STAGES (cephlint span-discipline enforces it)."""
-        trop = getattr(msg, "trop", None)
-        if trop is not None:
-            # cephlint: disable=span-discipline — the forwarding
-            # helper itself; callers pass registry literals and the
-            # check validates THEM (the _op_stage arg rule)
-            trop.mark_event(stage, detail)
-        span = getattr(msg, "span", None)
-        if span is not None:
-            span.annotate(f"{stage} {detail}" if detail else stage)
+    # mark a stage on the client op's timeline and its span (backend.py)
+    _op_stage = staticmethod(_op_stage)
 
     def do_op(self, msg: m.MOSDOp, reply: Callable[[m.MOSDOpReply], None],
               conn=None):
@@ -1910,7 +1898,7 @@ class PG:
                               log_rm=log_rm, on_submitted=on_submitted,
                               on_error=self._write_unwind_fn(
                                   msg.oid, entry),
-                              trop=getattr(msg, "trop", None))
+                              msg=msg)
         self._arm_write_deadline(_replied, lambda: reply_once(
             m.MOSDOpReply(self.pgid, self.osd.epoch(), msg.oid,
                           msg.ops, result=EAGAIN)))
@@ -1977,10 +1965,11 @@ class PG:
             # peer sub-writes inherit this op's span context on the
             # wire, so each peer's store-commit batch opens a child
             kw["trace"] = span.context()
-        # the tracked op rides to the encode queue so a live XLA
-        # compile overlapping the batch gets blamed on ITS timeline
-        # (compile_wait annotation + lat_compile_wait_us)
-        kw["trop"] = getattr(msg, "trop", None)
+        # the client op rides to the encode queue: its tracked op so a
+        # live XLA compile overlapping the batch gets blamed on ITS
+        # timeline (compile_wait annotation + lat_compile_wait_us), and
+        # the stages around the queue are marked on it
+        kw["msg"] = msg
         # the queued write IS the newest state (published BEFORE the
         # backend submit, so a same-object successor admitted at
         # on_submitted reads its predecessor's projected state):
