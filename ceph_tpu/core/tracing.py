@@ -212,6 +212,34 @@ SPANS: Dict[str, str] = {
     #   a stage program's full draws a lane, from static counts, times the
     #   lanes that entered the stage; the exact stage's rolled retry loop
     #   counts at one pass of its body, so its part is a lower bound
+    # OSDMap epoch (osd/map_inc.py, osd/osdmap.py): the per-epoch remap,
+    # an incremental applied and then every PG of a pool mapped
+    "osdmap.apply": "remap_apply_ms",     # Incremental.apply: the codec
+    #   clone of the base map and the delta; counts epoch and apply (the
+    #   value `osdmap.applies` took with the call: the window's join)
+    "osdmap.map_pgs": "remap_host_ms",    # OSDMap.map_pgs; counts mapping
+    #   (the value `osdmap.mappings` took with the call), pool, pgs,
+    #   upmap_rows and temp_rows (the pool's PGs with a pg_upmap /
+    #   pg_upmap_items entry, with a pg_temp entry); self time = the
+    #   interpreter around its six steps
+    "osdmap.flatten": "remap_host_ms",    # crush flatten and the rule's
+    #   digest, only where the map's cache of rule programs was empty
+    "osdmap.pps": "remap_host_ms",        # ps -> pps (ceph_stable_mod,
+    #   crush_hash32_2 under HASHPSPOOL) of every PG
+    "osdmap.rule": "remap_rule_ms",       # the rule program's call and its
+    #   fetch (devwatch.fetch): `dev.dispatch` and `dev.wait` lie below it
+    "osdmap.exceptions": "remap_host_ms",  # pg_upmap / pg_upmap_items,
+    #   and OSDs that do not exist dropped first where there are any
+    "osdmap.up": "remap_host_ms",         # the up filter, primary affinity
+    "osdmap.temp": "remap_host_ms",       # pg_temp rows, primary_temp
+    # the epoch's monotonic totals (counters, not spans: map_inc's
+    # apply_totals(), osdmap's mapping_totals())
+    "osdmap.applies": "remap_apply_ms",   # Incremental.apply calls: the
+    #   join of `osdmap.apply` spans to a window
+    "osdmap.mappings": "remap_host_ms",   # map_pgs calls: the divisor of
+    #   the remap_* metrics and the join of their spans to a window
+    "osdmap.pgs_mapped": "",              # PGs mapped
+    "osdmap.temp_rows": "",               # pg_temp rows taken
 }
 
 # a concluded op's timeline, filed by OpTracker.unregister: read by

@@ -19,7 +19,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ceph_tpu.core import tracing
 from ceph_tpu.core.encoding import Decoder, Encoder
+from ceph_tpu.core.lockdep import make_lock
 from ceph_tpu.osd import map_codec
 from ceph_tpu.osd.osdmap import OSDMap, PGPool
 
@@ -29,6 +31,17 @@ Addr = Tuple[str, int]
 # committed-value / wire tags
 FULL_TAG = 0
 INC_TAG = 1
+
+# Incremental.apply's monotonic total over the process (a counter,
+# registered in tracing.SPANS beside the spans)
+_totals = {"osdmap.applies": 0}
+_totals_lock = make_lock("osdmap.applies")
+
+
+def apply_totals() -> Dict[str, int]:
+    """{"osdmap.applies"}: every Incremental.apply of the process so far."""
+    with _totals_lock:
+        return dict(_totals)
 
 
 @dataclasses.dataclass
@@ -123,12 +136,21 @@ class Incremental:
 
     # -- application -------------------------------------------------------
     def apply(self, base: OSDMap) -> OSDMap:
-        """base (at prev_epoch) -> a NEW map at self.epoch."""
+        """base (at prev_epoch) -> a NEW map at self.epoch, under one
+        `osdmap.apply` span that counts `apply`: the value the
+        `osdmap.applies` total took with the call."""
         if base.epoch != self.prev_epoch:
             raise ValueError(
                 f"incremental for e{self.prev_epoch}->e{self.epoch} "
                 f"cannot apply to e{base.epoch}"
             )
+        with _totals_lock:
+            _totals["osdmap.applies"] += 1
+            n = _totals["osdmap.applies"]
+        with tracing.span("osdmap.apply", epoch=self.epoch, apply=n):
+            return self._apply(base)
+
+    def _apply(self, base: OSDMap) -> OSDMap:
         m = clone_map(base)
         if self.crush:
             m.crush = map_codec.decode_crush(Decoder(self.crush))

@@ -21,7 +21,10 @@ Two execution paths share these semantics:
 - ``map_pgs`` — the TPU-native replacement for OSDMapMapping /
   ParallelPGMapper (reference: src/osd/OSDMapMapping.h:17): every PG of
   a pool mapped in ONE vmapped sweep, with the up-filter, primary
-  affinity and exception tables applied vectorized on top.
+  affinity and exception tables applied vectorized on top.  Each call
+  is one `osdmap.map_pgs` span (counts `mapping`, the value the
+  `osdmap.mappings` total took) with a child a step, and adds to the
+  totals `mapping_totals()` gives.
 """
 
 from __future__ import annotations
@@ -32,9 +35,12 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ceph_tpu import _native
+from ceph_tpu.core import tracing
+from ceph_tpu.core.lockdep import make_lock
 from ceph_tpu.crush import hashes
 from ceph_tpu.crush import map as cmap
 from ceph_tpu.crush import mapper as cmapper
+from ceph_tpu.tpu import devwatch
 
 CRUSH_ITEM_NONE = 0x7FFFFFFF
 DEFAULT_PRIMARY_AFFINITY = 0x10000
@@ -44,6 +50,19 @@ POOL_REPLICATED = 1
 POOL_ERASURE = 3
 
 FLAG_HASHPSPOOL = 1
+
+# map_pgs' monotonic totals over the process (counters, registered in
+# tracing.SPANS beside the spans): calls, PGs mapped, pg_temp rows taken
+_totals = {"osdmap.mappings": 0, "osdmap.pgs_mapped": 0,
+           "osdmap.temp_rows": 0}
+_totals_lock = make_lock("osdmap.totals")
+
+
+def mapping_totals() -> Dict[str, int]:
+    """{"osdmap.mappings", "osdmap.pgs_mapped", "osdmap.temp_rows"}
+    over every map_pgs of the process so far."""
+    with _totals_lock:
+        return dict(_totals)
 
 
 def stable_mod(x: int, b: int, bmask: int) -> int:
@@ -230,22 +249,25 @@ class OSDMap:
         rule = self.crush.rules[pool.crush_rule]
         steps = np.asarray(rule.steps, dtype=np.int32).ravel()
         out = _native.do_rule(flat, steps, pps, pool.size, self.osd_weight)
-        return list(out)
+        # _remove_nonexistent_osds (OSDMap.cc), before any upmap applies
+        if pool.can_shift_osds():
+            return [o for o in out if self.exists(o)]
+        return [o if self.exists(o) else CRUSH_ITEM_NONE for o in out]
 
     def _apply_upmap(self, pool: PGPool, pgid, raw: List[int]) -> List[int]:
         p = self.pg_upmap.get(pgid)
         if p is not None:
-            ok = True
             for osd in p:
                 if (
                     osd != CRUSH_ITEM_NONE
                     and 0 <= osd < self.max_osd
                     and self.osd_weight[osd] == 0
                 ):
-                    ok = False
-                    break
-            if ok:
-                raw = list(p)
+                    # an explicit mapping onto an out OSD is rejected,
+                    # and the PG's upmap items with it (OSDMap.cc
+                    # _apply_upmap returns here)
+                    return raw
+            raw = list(p)
         q = self.pg_upmap_items.get(pgid)
         if q is not None:
             for frm, to in q:
@@ -355,8 +377,10 @@ class OSDMap:
         key = (pool.crush_rule, pool.size)
         fn = self._rule_fns.get(key)
         if fn is None:
-            rule = self.crush.rules[pool.crush_rule]
-            fn = cmapper.compile_rule(self._flatten(), rule.steps, pool.size)
+            with tracing.span("osdmap.flatten"):
+                rule = self.crush.rules[pool.crush_rule]
+                fn = cmapper.compile_rule(self._flatten(), rule.steps,
+                                          pool.size)
             self._rule_fns[key] = fn
         return fn
 
@@ -367,25 +391,28 @@ class OSDMap:
         arrays — the OSDMapMapping product, minus the thread pool.
         """
         pool = self.pools[pool_id]
-        ps = np.arange(pool.pg_num, dtype=np.int64)
-        pps = pool.pps_vector(ps)
-        fn = self._rule_fn(pool)
-        raw = np.asarray(fn(pps.astype(np.int32), self.osd_weight))
-        raw = self._sweep_apply_exceptions(pool, raw)
-        up, up_primary = self._sweep_up(pool, raw, pps)
-        acting = up.copy()
-        acting_primary = up_primary.copy()
-        for pgid, temp in self.pg_temp.items():
-            if pgid[0] != pool_id or pgid[1] >= pool.pg_num:
-                continue
-            _, _, act, actp = self.pg_to_up_acting(pgid)
-            row = np.full(acting.shape[1], CRUSH_ITEM_NONE, dtype=np.int32)
-            row[: len(act)] = act
-            acting[pgid[1]] = row
-            acting_primary[pgid[1]] = actp
-        for pgid, p in self.primary_temp.items():
-            if pgid[0] == pool_id and pgid[1] < pool.pg_num:
-                acting_primary[pgid[1]] = p
+        with _totals_lock:
+            _totals["osdmap.mappings"] += 1
+            n = _totals["osdmap.mappings"]
+        with tracing.span("osdmap.map_pgs", mapping=n, pool=pool_id,
+                          pgs=pool.pg_num) as sp:
+            with tracing.span("osdmap.pps"):
+                pps = pool.pps_vector(np.arange(pool.pg_num, dtype=np.int64))
+            fn = self._rule_fn(pool)
+            with tracing.span("osdmap.rule"):
+                raw = devwatch.fetch(fn(pps.astype(np.int32), self.osd_weight))
+            with tracing.span("osdmap.exceptions"):
+                raw, sp.counts["upmap_rows"] = self._sweep_apply_exceptions(
+                    pool, raw)
+            with tracing.span("osdmap.up"):
+                up, up_primary = self._sweep_up(pool, raw, pps)
+            with tracing.span("osdmap.temp"):
+                acting, acting_primary, temp_rows = self._sweep_temp(
+                    pool, up, up_primary)
+            sp.counts["temp_rows"] = temp_rows
+        with _totals_lock:
+            _totals["osdmap.pgs_mapped"] += pool.pg_num
+            _totals["osdmap.temp_rows"] += temp_rows
         return {
             "raw": raw,
             "up": up,
@@ -394,40 +421,71 @@ class OSDMap:
             "acting_primary": acting_primary,
         }
 
-    def _sweep_apply_exceptions(self, pool, raw: np.ndarray) -> np.ndarray:
+    def _sweep_temp(self, pool: PGPool, up: np.ndarray,
+                    up_primary: np.ndarray):
+        """pg_temp rows through the scalar path, primary_temp on top:
+        (acting, acting_primary, pg_temp rows of the pool)."""
+        acting = up.copy()
+        acting_primary = up_primary.copy()
+        rows = 0
+        for pgid in self.pg_temp:
+            if pgid[0] != pool.pool_id or pgid[1] >= pool.pg_num:
+                continue
+            _, _, act, actp = self.pg_to_up_acting(pgid)
+            row = np.full(acting.shape[1], CRUSH_ITEM_NONE, dtype=np.int32)
+            row[: len(act)] = act
+            acting[pgid[1]] = row
+            acting_primary[pgid[1]] = actp
+            rows += 1
+        for pgid, p in self.primary_temp.items():
+            if pgid[0] == pool.pool_id and pgid[1] < pool.pg_num:
+                acting_primary[pgid[1]] = p
+        return acting, acting_primary, rows
+
+    def _sweep_apply_exceptions(self, pool, raw: np.ndarray):
+        """(raw with the pool's pg_upmap and pg_upmap_items applied, the
+        number of the pool's PGs that have either)."""
+        if not self.osd_state_exists.all():
+            # _remove_nonexistent_osds (OSDMap.cc), before any upmap
+            raw = self._sweep_keep(pool, raw, self._sweep_osd_has(
+                raw, self.osd_state_exists))
         if not self.pg_upmap and not self.pg_upmap_items:
-            return raw
+            return raw, 0
         raw = raw.copy()
-        for pgid in list(self.pg_upmap) + list(self.pg_upmap_items):
+        rows = 0
+        # a PG in both tables is one row: _apply_upmap takes both
+        for pgid in dict.fromkeys([*self.pg_upmap, *self.pg_upmap_items]):
             if pgid[0] != pool.pool_id or pgid[1] >= pool.pg_num:
                 continue
             row = self._apply_upmap(pool, pgid, list(raw[pgid[1]]))
             out = np.full(raw.shape[1], CRUSH_ITEM_NONE, dtype=np.int32)
             out[: len(row)] = row
             raw[pgid[1]] = out
-        return raw
+            rows += 1
+        return raw, rows
+
+    def _sweep_osd_has(self, raw: np.ndarray, state: np.ndarray):
+        """Where an entry of `raw` is an OSD id whose `state` is set."""
+        inrange = (raw != CRUSH_ITEM_NONE) & (raw >= 0) & (raw < self.max_osd)
+        return inrange & state[np.clip(raw, 0, self.max_osd - 1)]
+
+    @staticmethod
+    def _sweep_keep(pool: PGPool, raw: np.ndarray, keep: np.ndarray):
+        """The entries `keep` marks: a pool that can shift moves them
+        left in order, another holds NONE where one went."""
+        if pool.can_shift_osds():
+            order = np.argsort(~keep, axis=1, kind="stable")
+            raw = np.take_along_axis(raw, order, axis=1)
+            keep = np.take_along_axis(keep, order, axis=1)
+        return np.where(keep, raw, CRUSH_ITEM_NONE)
 
     def _sweep_up(
         self, pool: PGPool, raw: np.ndarray, pps: np.ndarray
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Vectorized _raw_to_up_osds + primary affinity."""
         npgs, width = raw.shape
-        valid = raw != CRUSH_ITEM_NONE
-        inrange = valid & (raw >= 0) & (raw < self.max_osd)
-        alive = np.zeros_like(valid)
-        idx = np.clip(raw, 0, self.max_osd - 1)
-        alive[inrange] = (
-            self.osd_state_up[idx] & self.osd_state_exists[idx]
-        )[inrange]
-        keep = valid & alive
-        if pool.can_shift_osds():
-            # stable shift-left of kept entries
-            order = np.argsort(~keep, axis=1, kind="stable")
-            up = np.take_along_axis(raw, order, axis=1)
-            kept_sorted = np.take_along_axis(keep, order, axis=1)
-            up = np.where(kept_sorted, up, CRUSH_ITEM_NONE)
-        else:
-            up = np.where(keep, raw, CRUSH_ITEM_NONE)
+        up = self._sweep_keep(pool, raw, self._sweep_osd_has(
+            raw, self.osd_state_up & self.osd_state_exists))
 
         up_valid = up != CRUSH_ITEM_NONE
         first_valid = np.argmax(up_valid, axis=1)
