@@ -228,6 +228,8 @@ SPANS: Dict[str, str] = {
     #   crush_hash32_2 under HASHPSPOOL) of every PG
     "osdmap.rule": "remap_rule_ms",       # the rule program's call and its
     #   fetch (devwatch.fetch): `dev.dispatch` and `dev.wait` lie below it
+    #   (under `crush.sweep` where it counts staged 1: the pool went
+    #   through sweep_device's staged plan)
     "osdmap.exceptions": "remap_host_ms",  # pg_upmap / pg_upmap_items,
     #   and OSDs that do not exist dropped first where there are any
     "osdmap.up": "remap_host_ms",         # the up filter, primary affinity
@@ -240,6 +242,10 @@ SPANS: Dict[str, str] = {
     #   the remap_* metrics and the join of their spans to a window
     "osdmap.pgs_mapped": "",              # PGs mapped
     "osdmap.temp_rows": "",               # pg_temp rows taken
+    "osdmap.staged_mappings": "",         # map_pgs calls that placed the
+    #   pool through the staged sweep (more PGs than its exact stage takes)
+    "osdmap.rule_overflows": "",          # staged mappings whose sweep
+    #   overflowed a capacity, placed again by the exact program
 }
 
 # a concluded op's timeline, filed by OpTracker.unregister: read by

@@ -3,12 +3,15 @@
 Bit-exact port of the reference's crush_hash32* functions
 (reference: src/crush/hash.c:12-90).  Written against an array-namespace
 parameter ``xp`` so the identical code serves as the numpy oracle and the
-jax.numpy device kernel (uint32 wraparound semantics match in both).
+jax.numpy device kernel (uint32 wraparound semantics match in both); the
+mix round takes its five operations from ``_ops(xp)``.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import operator
 
 import numpy as np
 
@@ -23,37 +26,42 @@ def _quiet(xp):
     return contextlib.nullcontext()
 
 
+@functools.lru_cache(maxsize=None)
+def _ops(xp):
+    """(uint32 cast, subtract, xor, shift right, shift left) over the
+    namespace: numpy's operators, or jax.lax's primitives, which trace
+    to the same operations as jax.numpy's operators without their
+    per-call dispatch (a CRUSH rule program holds thousands of them);
+    lax takes operands of one shape, or a scalar beside an array."""
+    if xp is np:
+        return (lambda v: v.astype(np.uint32) if hasattr(v, "astype")
+                else np.uint32(v),
+                operator.sub, operator.xor, operator.rshift, operator.lshift)
+    from jax import lax
+
+    def u32(v):
+        if not hasattr(v, "astype"):
+            return xp.uint32(v)
+        return v if v.dtype == np.uint32 else v.astype(np.uint32)
+
+    return (u32, lax.sub, lax.bitwise_xor,
+            lambda v, s: lax.shift_right_logical(v, np.uint32(s)),
+            lambda v, s: lax.shift_left(v, np.uint32(s)))
+
+
 def _mix(a, b, c, xp):
     """One crush_hashmix round (reference: src/crush/hash.c:12-22)."""
-    u32 = lambda v: v.astype(xp.uint32) if hasattr(v, "astype") else xp.uint32(v)
+    u32, sub, xor, shr, shl = _ops(xp)
     a, b, c = u32(a), u32(b), u32(c)
-    a = a - b
-    a = a - c
-    a = a ^ (c >> 13)
-    b = b - c
-    b = b - a
-    b = b ^ (a << 8)
-    c = c - a
-    c = c - b
-    c = c ^ (b >> 13)
-    a = a - b
-    a = a - c
-    a = a ^ (c >> 12)
-    b = b - c
-    b = b - a
-    b = b ^ (a << 16)
-    c = c - a
-    c = c - b
-    c = c ^ (b >> 5)
-    a = a - b
-    a = a - c
-    a = a ^ (c >> 3)
-    b = b - c
-    b = b - a
-    b = b ^ (a << 10)
-    c = c - a
-    c = c - b
-    c = c ^ (b >> 15)
+    a = xor(sub(sub(a, b), c), shr(c, 13))
+    b = xor(sub(sub(b, c), a), shl(a, 8))
+    c = xor(sub(sub(c, a), b), shr(b, 13))
+    a = xor(sub(sub(a, b), c), shr(c, 12))
+    b = xor(sub(sub(b, c), a), shl(a, 16))
+    c = xor(sub(sub(c, a), b), shr(b, 5))
+    a = xor(sub(sub(a, b), c), shr(c, 3))
+    b = xor(sub(sub(b, c), a), shl(a, 10))
+    c = xor(sub(sub(c, a), b), shr(b, 15))
     return a, b, c
 
 

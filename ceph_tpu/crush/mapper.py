@@ -1929,7 +1929,8 @@ class SweepPlan(NamedTuple):
 
     bad_div: int   # stage-2 capacity is chunk // bad_div lanes; 1: no
     #                one-shot pass, every lane takes the budgeted stage
-    bad2_div: int  # stage-3 capacity is n // bad2_div lanes (floor 2048)
+    bad2_div: int  # stage-3 capacity is n // bad2_div lanes (floor:
+    #                stage_capacities)
     budget: int    # attempts a choose gets in the budgeted stage
     rounds: Optional[tuple]  # an indep choose's budgeted rounds,
     #                (slots, leaf retries) each (_choose_indep); None for
@@ -2222,6 +2223,19 @@ def _rule_shape(steps, result_max: int):
     return "", 0
 
 
+def stage_capacities(plan: SweepPlan, n: int, chunk: int) -> tuple:
+    """(lanes a chunk's budgeted stage takes, lanes the exact stage
+    takes) of a sweep_device of n ids in chunks of `chunk` under
+    `plan`.  The exact stage's residue is ~0.006% on healthy maps; its
+    floor, a 64th of the ids but 512 to 2048 lanes, keeps small sweeps
+    from starving it.  The exact program's cost grows with its lanes
+    (a replicated rule on a 1024-OSD map: 3, 7, 23 and 403 ms at 128,
+    512, 2,048 and 32,768 lanes on one TPU v5e), so the floor is less
+    than 2048 where the ids are few."""
+    return (max(1, chunk // plan.bad_div),
+            min(n, max(n // plan.bad2_div, min(2048, max(512, n // 64)))))
+
+
 def _stage_programs(flat, steps, result_max, choose_args, plan: SweepPlan,
                     fast: bool):
     """The three stage programs of a plan: (one-shot or None, budgeted,
@@ -2448,17 +2462,17 @@ def sweep_device(
        indices are dropped);
     3. lanes still unclean after the budget re-run through the exact
        full-retry program in ONE global batch after the scan (capacity
-       max(n/bad2_div, 2048)) — the full program's while_loop overhead
-       is paid once per sweep, not once per chunk.
+       n/bad2_div over a floor, stage_capacities) — the full program's
+       while_loop overhead is paid once per sweep, not once per chunk.
 
     The plan (which stages, both capacities, the budgeted stage's
     budget and shape) is sweep_plan()'s for this rule, map and device
     weights: a healthy replicated map (three replicas over 64 hosts of
     16) runs ~5.4% unclean after stage 1, 4.7% collisions and 0.7%
     contested draws, and ~0.006% after stage 2 and gets capacities of
-    12.5% and 0.05% (floor 2048 lanes) at a budget of 3; a very wide
-    bucket (1024 OSDs straight under the root: 9% contested) gets 25%
-    for stage 2; the erasure-coded pool's
+    12.5% and 0.05% (floor 2048 lanes from 131,072 ids up) at a budget
+    of 3; a very wide bucket (1024 OSDs straight under the root: 9%
+    contested) gets 25% for stage 2; the erasure-coded pool's
     `chooseleaf indep 12` over 64 hosts with one host out leaves three
     lanes in four unclean after one attempt, so its plan drops stage 1,
     runs the budgeted program over every lane and sizes stage 3 from
@@ -2488,10 +2502,7 @@ def sweep_device(
         plan = plan._replace(bad_div=bad_div)
     if bad2_div is not None:
         plan = plan._replace(bad2_div=bad2_div)
-    cap = max(1, chunk // plan.bad_div)
-    # global stage-3 capacity: residue is ~0.006% on healthy maps; the
-    # floor keeps small sweeps from starving the exact stage
-    cap2 = min(n, max(n // plan.bad2_div, 2048))
+    cap, cap2 = stage_capacities(plan, n, chunk)
 
     run = _device_runner(flat, steps, result_max, choose_args, n, chunk,
                          cap, cap2, plan, with_fast)

@@ -20,11 +20,15 @@ Two execution paths share these semantics:
   the per-op client path;
 - ``map_pgs`` — the TPU-native replacement for OSDMapMapping /
   ParallelPGMapper (reference: src/osd/OSDMapMapping.h:17): every PG of
-  a pool mapped in ONE vmapped sweep, with the up-filter, primary
-  affinity and exception tables applied vectorized on top.  Each call
-  is one `osdmap.map_pgs` span (counts `mapping`, the value the
-  `osdmap.mappings` total took) with a child a step, and adds to the
-  totals `mapping_totals()` gives.
+  a pool mapped in ONE device dispatch, with the up-filter, primary
+  affinity and exception tables applied vectorized on top.  A pool with
+  more PGs than the staged sweep's exact stage takes goes through
+  ``mapper.sweep_device``'s plan (one-shot pass, budgeted stage, the
+  exact program on the residue), a smaller one through the exact
+  program on every lane; both give the exact program's placements.
+  Each call is one `osdmap.map_pgs` span (counts `mapping`, the value
+  the `osdmap.mappings` total took) with a child a step, and adds to
+  the totals `mapping_totals()` gives.
 """
 
 from __future__ import annotations
@@ -52,15 +56,19 @@ POOL_ERASURE = 3
 FLAG_HASHPSPOOL = 1
 
 # map_pgs' monotonic totals over the process (counters, registered in
-# tracing.SPANS beside the spans): calls, PGs mapped, pg_temp rows taken
+# tracing.SPANS beside the spans): calls, PGs mapped, pg_temp rows taken,
+# calls that placed the pool through the staged sweep, and those of them
+# whose staged sweep overflowed and were placed again by the exact program
 _totals = {"osdmap.mappings": 0, "osdmap.pgs_mapped": 0,
-           "osdmap.temp_rows": 0}
+           "osdmap.temp_rows": 0, "osdmap.staged_mappings": 0,
+           "osdmap.rule_overflows": 0}
 _totals_lock = make_lock("osdmap.totals")
 
 
 def mapping_totals() -> Dict[str, int]:
-    """{"osdmap.mappings", "osdmap.pgs_mapped", "osdmap.temp_rows"}
-    over every map_pgs of the process so far."""
+    """{"osdmap.mappings", "osdmap.pgs_mapped", "osdmap.temp_rows",
+    "osdmap.staged_mappings", "osdmap.rule_overflows"} over every
+    map_pgs of the process so far."""
     with _totals_lock:
         return dict(_totals)
 
@@ -384,6 +392,33 @@ class OSDMap:
             self._rule_fns[key] = fn
         return fn
 
+    def _staged(self, pool: PGPool) -> bool:
+        """Whether the pool's PGs outnumber the lanes the staged sweep's
+        exact stage would take on this map (mapper.sweep_plan, from the
+        rule, the map and the device weights): only then do the one-shot
+        and budgeted stages spare the exact program any lane."""
+        rule = self.crush.rules[pool.crush_rule]
+        plan = cmapper.sweep_plan(self._flatten(), rule.steps, pool.size,
+                                  self.osd_weight)
+        n = pool.pg_num
+        return cmapper.stage_capacities(plan, n, n)[1] < n
+
+    def _rule_raw(self, pool: PGPool, fn, xs: np.ndarray, staged: bool):
+        """The rule's raw placement of every PG: a large pool through the
+        staged sweep in one dispatch, its placements and overflow flag
+        fetched together; a pool whose sweep overflowed, or a small one,
+        through the exact program `fn` on every lane."""
+        if staged:
+            rule = self.crush.rules[pool.crush_rule]
+            raw, overflow = devwatch.fetch(cmapper.sweep_device(
+                self._flatten(), rule.steps, pool.size, xs,
+                self.osd_weight, chunk=len(xs)))
+            if not overflow:
+                return raw
+            with _totals_lock:
+                _totals["osdmap.rule_overflows"] += 1
+        return devwatch.fetch(fn(xs, self.osd_weight))
+
     def map_pgs(self, pool_id: int) -> Dict[str, np.ndarray]:
         """Map ALL pgs of a pool in one jitted sweep.
 
@@ -399,8 +434,9 @@ class OSDMap:
             with tracing.span("osdmap.pps"):
                 pps = pool.pps_vector(np.arange(pool.pg_num, dtype=np.int64))
             fn = self._rule_fn(pool)
-            with tracing.span("osdmap.rule"):
-                raw = devwatch.fetch(fn(pps.astype(np.int32), self.osd_weight))
+            staged = self._staged(pool)
+            with tracing.span("osdmap.rule", staged=int(staged)):
+                raw = self._rule_raw(pool, fn, pps.astype(np.int32), staged)
             with tracing.span("osdmap.exceptions"):
                 raw, sp.counts["upmap_rows"] = self._sweep_apply_exceptions(
                     pool, raw)
@@ -413,6 +449,7 @@ class OSDMap:
         with _totals_lock:
             _totals["osdmap.pgs_mapped"] += pool.pg_num
             _totals["osdmap.temp_rows"] += temp_rows
+            _totals["osdmap.staged_mappings"] += staged
         return {
             "raw": raw,
             "up": up,

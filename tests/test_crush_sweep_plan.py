@@ -268,10 +268,11 @@ def test_the_sweep_span_says_what_ran():
     # the root (one bucket: constants) and the racks and the hosts
     # (read by their place in the level's frontier); nothing gathers.
     # Uniform weights: the budgeted program's three levels draw by
-    # fastcmp, the exact program's three through the draw tables
+    # fastcmp, the exact program's three through the draw tables.  The
+    # exact stage's floor is 512 lanes for a sweep of 2048 ids
     assert span[COUNTS] == {
         "ids": 2048, "chunk": 1024, "numrep": 6, "mode": "indep",
-        "cap": 1024, "cap2": 2048, "budget": plan.budget,
+        "cap": 1024, "cap2": 512, "budget": plan.budget,
         "const": 2, "onehot": 4, "gather": 0,
         "draw_fast": 3, "draw_class": 0, "draw_table": 3, "draw_limb": 0}
     assert {"crush.sweep", "crush.ids", "crush.mid_lanes",

@@ -16,6 +16,7 @@ import pytest
 
 from ceph_tpu.core import tracing
 from ceph_tpu.crush import map as cmap
+from ceph_tpu.crush import mapper as cmapper
 from ceph_tpu.osd import map_inc
 from ceph_tpu.osd import osdmap as om
 from ceph_tpu.osd.map_inc import Incremental
@@ -32,26 +33,28 @@ SEED = 4_100_000_041
 TRAFFIC = {"check_pgs": 32, "check_upmapped": 8}
 
 
-def _cfg() -> dict:
-    """The benchmark's configuration at 64 OSDs in 4 hosts, 256 PGs."""
+def _cfg(hosts: int = 4, pg_num: int = 256) -> dict:
+    """The benchmark's configuration at 64 OSDs in `hosts` hosts, with
+    `pg_num` PGs and an upmap item for one PG in 16."""
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            "osdmap-rep3-32768pg-1024osd.json")) as f:
         cfg = json.load(f)
-    cfg["num_osds"], cfg["hosts"] = 64, 4
+    cfg["num_osds"], cfg["hosts"] = 64, hosts
     cfg["osd_weights"] = [0x10000] * 64
-    cfg["layers"][0]["bucket_ids"] = [-1, -2, -3, -4]
-    cfg["layers"][1]["bucket_ids"] = [-5]
-    cfg["pool"].update(pg_num=256, pgp_num=256)
-    cfg["upmap_items"] = 16
+    cfg["layers"][0].update(size=64 // hosts,
+                            bucket_ids=list(range(-1, -hosts - 1, -1)))
+    cfg["layers"][1]["bucket_ids"] = [-hosts - 1]
+    cfg["pool"].update(pg_num=pg_num, pgp_num=pg_num)
+    cfg["upmap_items"] = pg_num // 16
     return cfg
 
 
-def _driver(control: bool = False):
+def _driver(control: bool = False, cfg=None):
     """The driver's set-up (the tables and one whole cycle), with A the
     target of an upmap item and B one of another item's PG's OSDs, and
     the map of each epoch kept."""
     make = osdmap_remap.control if control else osdmap_remap.Driver
-    d = make(_cfg(), TRAFFIC, SEED)
+    d = make(cfg or _cfg(), TRAFFIC, SEED)
     maps = []
     apply = d._apply
 
@@ -64,7 +67,10 @@ def _driver(control: bool = False):
         return items[0][0][1], items[1][0][0]
 
     d._apply, d._draw_ab = keep, ab
+    before = om.mapping_totals()
     d.setup()
+    # what map_pgs' totals moved by in the set-up
+    d.moved = {k: v - before[k] for k, v in om.mapping_totals().items()}
     return d, maps[1:]
 
 
@@ -118,6 +124,92 @@ def test_the_controls_mapping_differs(cycle):
     assert wrong[[ps for _p, ps in d.tables["new_pg_upmap_items"]]].any()
 
 
+@pytest.fixture(scope="module")
+def staged_cycle():
+    """(driver, the four epochs' maps) on 64 OSDs in 8 hosts with 4,096
+    PGs: more than the 2,048 lanes the staged sweep's exact stage takes,
+    so map_pgs places them through the staged plan."""
+    return _driver(cfg=_cfg(hosts=8, pg_num=4096))
+
+
+def _pps(m, d) -> np.ndarray:
+    return m.pools[d.pool_id].pps_vector(
+        np.arange(d.pg_num, dtype=np.int64)).astype(np.int32)
+
+
+def _equals_the_scalar_path(m, d, got) -> None:
+    for ps in range(d.pg_num):
+        up, upp, act, actp = m.pg_to_up_acting((d.pool_id, ps))
+        assert [o for o in got["up"][ps] if o != NONE] == up
+        assert [o for o in got["acting"][ps] if o != NONE] == act
+        assert (got["up_primary"][ps], got["acting_primary"][ps]) == (
+            upp, actp)
+
+
+@pytest.mark.parametrize("pool,staged", [("cycle", False),
+                                         ("staged_cycle", True)])
+def test_map_pgs_stages_a_pool_only_above_the_exact_stages_capacity(
+        pool, staged, request):
+    """Every mapping of the set-up (the plain map, the tables, the four
+    epochs) took the staged plan for 4,096 PGs and none for the 256-PG
+    rehearsal pool; none overflowed."""
+    d, maps = request.getfixturevalue(pool)[:2]
+    n = 2 + len(osdmap_remap.KINDS)
+    assert d.moved["osdmap.mappings"] == n
+    assert d.moved["osdmap.staged_mappings"] == (n if staged else 0)
+    assert d.moved["osdmap.rule_overflows"] == 0
+    assert all(m._staged(m.pools[d.pool_id]) == staged for m in maps)
+
+
+@pytest.mark.parametrize("epoch", osdmap_remap.KINDS)
+def test_staged_map_pgs_equals_the_exact_program_and_the_scalar_path(
+        epoch, staged_cycle):
+    """The staged sweep's raw rows are the exact program's on every lane,
+    and the epoch's four results (upmap items, affinity, pg_temp on top)
+    the scalar path's on every PG."""
+    d, maps = staged_cycle
+    i = osdmap_remap.KINDS.index(epoch)
+    m, got = maps[i], d.epochs[i][3]
+    assert (m.osd_weight[d.a] == 0) == (epoch in ("out", "down"))
+    assert bool(m.pg_temp) == (epoch in ("out", "down"))
+    pool, pps = m.pools[d.pool_id], _pps(m, d)
+    fn = m._rule_fn(pool)
+    np.testing.assert_array_equal(m._rule_raw(pool, fn, pps, True),
+                                  np.asarray(fn(pps, m.osd_weight)))
+    _equals_the_scalar_path(m, d, got)
+
+
+def test_an_overflowing_staged_sweep_is_mapped_again_by_the_exact_program(
+        staged_cycle, monkeypatch):
+    """With five hosts of eight out the plan gives the exact stage every
+    lane, so the pool is not staged.  A plan that misjudges the map,
+    pinned here to the healthy map's, sends it through the staged sweep,
+    which overflows: map_pgs places the pool again by the exact program
+    and counts it."""
+    d, maps = staged_cycle
+    m = map_inc.clone_map(maps[-1])
+    pool = m.pools[d.pool_id]
+    healthy = cmapper.sweep_plan(m._flatten(), m.crush.rules[
+        pool.crush_rule].steps, pool.size, m.osd_weight)
+    m.osd_weight[24:] = 0
+    assert not m._staged(pool)
+    monkeypatch.setattr(cmapper, "sweep_plan", lambda *a, **k: healthy)
+    assert m._staged(pool)
+    before = om.mapping_totals()
+    got = m.map_pgs(d.pool_id)
+    moved = {k: v - before[k] for k, v in om.mapping_totals().items()}
+    assert (moved["osdmap.mappings"], moved["osdmap.staged_mappings"],
+            moved["osdmap.rule_overflows"]) == (1, 1, 1)
+    pps = _pps(m, d)
+    raw = np.asarray(m._rule_fn(pool)(pps, m.osd_weight))
+    assert (raw[:, 1:] == NONE).any()   # some PGs lack a replica
+    np.testing.assert_array_equal(
+        m._rule_raw(pool, m._rule_fn(pool), pps, True), raw)
+    assert om.mapping_totals()["osdmap.rule_overflows"] == (
+        before["osdmap.rule_overflows"] + 2)
+    _equals_the_scalar_path(m, d, got)
+
+
 def _small_map():
     m, root = cmap.build_flat_cluster(32, hosts=8)
     rule = m.add_simple_rule("replicated_rule", root, 1, "firstn")
@@ -168,9 +260,13 @@ def test_map_pgs_files_its_spans_and_counters_with_seq():
     before = om.mapping_totals()
     osdmap.map_pgs(1)   # the cache is warm: no flatten
     after = om.mapping_totals()
+    # 32 PGs: fewer than the staged sweep's exact stage takes
     assert after == {"osdmap.mappings": before["osdmap.mappings"] + 1,
                      "osdmap.pgs_mapped": before["osdmap.pgs_mapped"] + 32,
-                     "osdmap.temp_rows": before["osdmap.temp_rows"] + 1}
+                     "osdmap.temp_rows": before["osdmap.temp_rows"] + 1,
+                     "osdmap.staged_mappings":
+                         before["osdmap.staged_mappings"],
+                     "osdmap.rule_overflows": before["osdmap.rule_overflows"]}
     applies = map_inc.apply_totals()["osdmap.applies"]
     nxt = Incremental(epoch=osdmap.epoch + 1,
                       prev_epoch=osdmap.epoch).apply(osdmap)
@@ -205,10 +301,12 @@ def test_map_pgs_files_its_spans_and_counters_with_seq():
     rule, = [k for k in recs if k[tracing.PARENT] == warm[tracing.ID]
              and k[tracing.NAME] == "osdmap.rule"]
     assert kids(rule) == ["dev.dispatch", "dev.wait"]
+    assert rule[tracing.COUNTS] == {"staged": 0}
     assert all(tracing.SPANS[n] for n in steps + ["osdmap.map_pgs",
                                                   "osdmap.apply"])
     assert {"osdmap.applies", "osdmap.mappings", "osdmap.pgs_mapped",
-            "osdmap.temp_rows"} <= set(tracing.SPANS)
+            "osdmap.temp_rows", "osdmap.staged_mappings",
+            "osdmap.rule_overflows"} <= set(tracing.SPANS)
 
 
 def test_a_pg_upmap_onto_an_out_osd_drops_the_pgs_items_too():
